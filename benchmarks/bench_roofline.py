@@ -75,7 +75,6 @@ def _kernel_cases():
     theta = jnp.zeros((q,), jnp.uint32)
     iq = jnp.full((q,), 1 << 16, jnp.uint32)
     margin = jnp.zeros((q,), jnp.int32)
-    hits = jnp.zeros((p, ow), jnp.uint32)
     dense_words = jnp.zeros((p, 128), jnp.uint32)
     dense_tiles = jnp.zeros((p, 1024), jnp.uint32)
     w0 = jnp.zeros((p,), jnp.int32)
@@ -88,8 +87,6 @@ def _kernel_cases():
         ("score_round_gated", topk.score_round,
          (acc, bm, ids, qslot, codes, ns, bm, ub, theta, iq),
          {"gated": True}),
-        ("score_round_masked", topk.score_round_masked,
-         (acc, bm, ids, qslot, codes, hits, ub, theta, iq), {}),
         ("dense_score_round", topk.dense_score_round,
          (acc, bm, dense_tiles, dense_words, qslot, w0, ub, theta, iq, bm),
          {"gated": True}),
@@ -99,8 +96,6 @@ def _kernel_cases():
          (acc, bm, theta, margin, iq), {}),
         ("round_accumulate", ir.round_accumulate,
          (bm, ids, qslot, ns, bm), {}),
-        ("round_accumulate_masked", ir.round_accumulate_masked,
-         (bm, ids, qslot, hits), {}),
         ("dense_round_accumulate", ir.dense_round_accumulate,
          (bm, dense_words, qslot, w0, act, bm), {}),
         ("round_commit", ir.round_commit, (bm, bm, active), {}),

@@ -13,8 +13,8 @@ tiles), and each cell reports p50/p99/p999 latency, goodput (on-time served
 qps), shed rate, and the achieved batch-size histogram.
 
 Every cell is also *audited*: each batch the server formed is replayed
-through the offline ``plan()/execute()`` oracle at the same placement and
-the served results must be bitwise identical (``parity_ok``).  Under the
+through the host numpy oracle (``plan(..., placement="host")``) and the
+served results must be bitwise identical (``parity_ok``).  Under the
 Poisson smoke load the shed rate must be exactly 0 — the CI-tracked
 guarantee that admission + batching never drops a request the engine had
 budget for.
@@ -43,29 +43,32 @@ from .bench_query import git_sha, make_queries
 from .util import emit
 
 
-def _bitwise_equal(a, b) -> bool:
+def bitwise_equal(a, b) -> bool:
     """Recursive exact comparison: nested lists/tuples of arrays, or bare
     arrays — the shapes the engine's per-mode results take."""
     if isinstance(a, (tuple, list)) and isinstance(b, (tuple, list)):
         return (len(a) == len(b)
-                and all(_bitwise_equal(x, y) for x, y in zip(a, b)))
+                and all(bitwise_equal(x, y) for x, y in zip(a, b)))
     return np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def audit_parity(engine: QueryEngine, stats, results: list) -> bool:
-    """Replay every batch the server formed through the offline
-    ``plan()/execute()`` oracle at the same placement and check the served
-    results bitwise.  ``results[rid]`` must be the stream's result for
-    request ``rid`` (true for ``serve_stream``'s submission-order list)."""
+def audit_parity(engine: QueryEngine, stats, results: list) -> list:
+    """Replay every batch the server formed through the host numpy oracle
+    (``plan(..., placement="host")`` / ``execute``) and return ``(mode,
+    batch_id, rid)`` for every served result that is not bitwise the
+    oracle's — empty when all match.  Replaying at the batch's own
+    placement would let a device path that is wrong the same way every time
+    pass.  ``results[rid]`` must be the stream's result for request ``rid``
+    (true for ``serve_stream``'s submission-order list)."""
+    bad = []
     for b in stats.batches:
         plan = engine.plan(QueryBatch([list(q) for q in b.queries],
                                       mode=b.mode, k=b.k),
-                           placement=b.placement)
-        oracle = engine.execute(plan)
-        for off, rid in zip(oracle, b.rids):
-            if not _bitwise_equal(off, results[rid]):
-                return False
-    return True
+                           placement="host")
+        for want, rid in zip(engine.execute(plan), b.rids):
+            if not bitwise_equal(want, results[rid]):
+                bad.append((b.mode, b.batch_id, rid))
+    return bad
 
 
 def _drive(engine: QueryEngine, queries: list, offsets, deadline_ms: float,
@@ -95,7 +98,7 @@ def _drive(engine: QueryEngine, queries: list, offsets, deadline_ms: float,
     serve_stream(engine, reqs, offsets, cfg)          # unrecorded warm pass
     results, stats = serve_stream(engine, reqs, offsets, cfg)
     served = [r for r in results if not isinstance(r, Rejected)]
-    parity = audit_parity(engine, stats, results) if served else True
+    parity = not audit_parity(engine, stats, results) if served else True
     return stats.snapshot(), parity
 
 
@@ -144,8 +147,8 @@ def run(n_requests: int = 192, dataset: str = "gov2",
                  f"mean_batch={snap['mean_batch']:.1f}")
             if not parity:
                 raise AssertionError(
-                    f"served results diverged from the offline plan/execute "
-                    f"oracle ({arrival}/{placement})")
+                    f"served results diverged from the host oracle "
+                    f"({arrival}/{placement})")
             if smoke and arrival == "poisson" and snap["shed_rate"] != 0.0:
                 raise AssertionError(
                     f"Poisson smoke load shed {snap['shed_rate']:.3f} of "

@@ -39,6 +39,8 @@ def main() -> None:
                     help="workload seed for the query suite (fixed default "
                          "keeps --smoke deterministic)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n = 1 << 21 if args.full else (1 << 14 if args.smoke else 1 << 18)
     suites = {
         "ratio": lambda: __import__("benchmarks.bench_ratio", fromlist=["run"]).run(),

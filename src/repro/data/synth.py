@@ -40,9 +40,13 @@ class PostingList:
         return out
 
 
-def make_dataset(name: str, seed: int = 0, n_lists: int = 200) -> list:
-    """Posting lists for the n_lists most frequent sampled terms."""
-    n_docs, n_terms, avg_len, s = DATASETS[name]
+def make_dataset(name: str, seed: int = 0, n_lists: int = 200,
+                 n_docs: int | None = None) -> list:
+    """Posting lists for the n_lists most frequent sampled terms.  ``n_docs``
+    overrides the dataset's corpus size (the Zipf df curve scales with it,
+    so the shape — df per term rank — stays the dataset's)."""
+    default_docs, n_terms, avg_len, s = DATASETS[name]
+    n_docs = default_docs if n_docs is None else int(n_docs)
     # crc32, NOT hash(): str hashing is randomized per process, which made
     # every benchmark run draw a different corpus — the same (name, seed)
     # must yield the same dataset in every process for the committed
@@ -81,10 +85,11 @@ def concat_tfs(lists) -> np.ndarray:
     return np.concatenate([pl.tfs for pl in lists]).astype(np.uint32)
 
 
-def make_corpus(name: str, seed: int = 0):
+def make_corpus(name: str, seed: int = 0, n_docs: int | None = None):
     """Token-level corpus for the query-processing benchmark: returns
-    (doc_lengths, postings dict term -> (docids, tfs))."""
-    lists = make_dataset(name, seed)
-    n_docs = DATASETS[name][0]
+    (doc_lengths, postings dict term -> (docids, tfs)).  ``n_docs`` overrides
+    the dataset's corpus size (default: the ``DATASETS`` entry)."""
+    n_docs = DATASETS[name][0] if n_docs is None else int(n_docs)
+    lists = make_dataset(name, seed, n_docs=n_docs)
     doclen = np.full(n_docs, DATASETS[name][2], np.int64)
     return doclen, {pl.term: (pl.docids, pl.tfs) for pl in lists}

@@ -126,6 +126,23 @@ def compressed_allreduce_flat(x: jnp.ndarray, axis_names, bits: int = 8):
 # --------------------------------------------------------------------------- #
 
 
+@functools.lru_cache(maxsize=None)
+def topk_merge_fn(mesh, axis_name: str = "shards"):
+    """The merge collective itself, jitted once per (mesh, axis): per-shard
+    (theta, count) rows sharded over ``axis_name`` in, one ``all_gather``
+    each, theta maxed over shards; both outputs replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    def gather_max(ts, cs):
+        g = jax.lax.all_gather(ts, axis_name, tiled=True)
+        gc = jax.lax.all_gather(cs, axis_name, tiled=True)
+        return g.max(axis=0), gc
+
+    return jax.jit(jax.shard_map(
+        gather_max, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
+        out_specs=(P(), P()), check_vma=False))
+
+
 def merge_topk_stats(theta_parts, count_parts, mesh=None,
                      axis_name: str = "shards"):
     """Merge per-shard (k-th sum, candidate-count) statistics into the global
@@ -146,21 +163,12 @@ def merge_topk_stats(theta_parts, count_parts, mesh=None,
     nqp = int(theta_parts[0].shape[0])
     wire_bytes = s * nqp * 4 * 2                 # u32 theta + i32 count
     if mesh is not None and mesh.devices.size == s and s > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         t = jax.device_put(jnp.stack([jnp.asarray(p) for p in theta_parts]),
                            NamedSharding(mesh, P(axis_name)))
         c = jax.device_put(jnp.stack([jnp.asarray(p) for p in count_parts]),
                            NamedSharding(mesh, P(axis_name)))
-
-        def gather_max(ts, cs):
-            g = jax.lax.all_gather(ts, axis_name, tiled=True)
-            gc = jax.lax.all_gather(cs, axis_name, tiled=True)
-            return g.max(axis=0), gc
-
-        theta, counts = jax.jit(shard_map(
-            gather_max, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
-            out_specs=(P(), P()), check_rep=False))(t, c)
+        theta, counts = topk_merge_fn(mesh, axis_name)(t, c)
         return (np.asarray(theta).astype(np.int64),
                 np.asarray(counts), wire_bytes)
     thetas = np.stack([np.asarray(p) for p in theta_parts])

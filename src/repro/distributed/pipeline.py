@@ -53,22 +53,13 @@ def pipeline_2stage(layer_fn, params_stacked, x_micro, mesh, *, pod_axis="pod"):
         return outs[1:]
 
     pspecs = jax.tree.map(lambda _: PS(pod_axis), params_stacked)
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(pspecs, PS()),
-            out_specs=PS(pod_axis),       # (2*n_micro, ...) stacked by pod
-            axis_names=frozenset({pod_axis}),
-            check_vma=False,
-        )
-    else:  # jax < 0.5: shard_map lives in experimental, no axis_names/check_vma
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(
-            local, mesh=mesh,
-            in_specs=(pspecs, PS()),
-            out_specs=PS(pod_axis),
-            check_rep=False,
-        )
+    mapped = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(pspecs, PS()),
+        out_specs=PS(pod_axis),       # (2*n_micro, ...) stacked by pod
+        axis_names=frozenset({pod_axis}),
+        check_vma=False,
+    )
     out = mapped(params_stacked, x_micro)
     # pod 1's block holds the completed microbatches
     return out[n_micro:]

@@ -28,7 +28,8 @@ Layers (bottom up):
     call per codec per AND round, deduped across the batch); AND candidates
     then stay in a device-resident segmented bitmap across rounds
     (``repro.kernels.intersect_rounds`` — only the final result is copied to
-    host), optionally through the segmented fused decode+probe Pallas kernel.
+    host), optionally decoding through the fused Pallas tile kernel
+    (``repro.kernels.decode_fused``).
   * ``scores`` — the ranked-retrieval subsystem: per-(term, doc) BM25
     impacts quantized to u8 and packed as an additional score column per
     posting block (``ScoreArena``, same padded-``ArenaColumn`` contract as

@@ -31,11 +31,12 @@ On top sit two batched execution paths:
     power-of-two buckets so jit variants stay bounded.  Blocks whose codec
     declares no arena capability (and empty blocks) fall back to the numpy
     decoder per block, preserving exact results for every registered codec.
-  * ``fused_and`` — the ``kernels/decode_fused`` Pallas path: block gaps
-    re-packed into fixed (rows, 128) tiles at the block's own bit width
-    rounded up to ``decode_fused.BW_BUCKETS``, decoded *and* intersected
-    against a query's candidate bitmap inside VMEM, with the skip-selected
-    next block's DMA double-buffered via scalar-prefetched work-list indices.
+  * ``fused_and`` / ``fused_round`` — the ``kernels/decode_fused`` Pallas
+    path: block gaps re-packed into fixed (rows, 128) tiles at the block's
+    own bit width rounded up to ``decode_fused.BW_BUCKETS``, decoded in
+    VMEM with the next work-list block's DMA double-buffered via
+    scalar-prefetched work-list indices; the candidate probe runs in XLA on
+    the decoded rows.
 
 ``stats`` counts device calls and blocks decoded per path; the engine's
 work-list dedup guarantees <= 1 decode per hot (term, block) per batch, which
@@ -267,14 +268,13 @@ class DeviceArena:
     def ensure_fused(self) -> "DeviceArena":
         """Build the fused-kernel tile arenas if absent: every block's d-gaps
         re-packed into the fixed (rows, 128) tiles ``kernels/decode_fused``
-        consumes, grouped into per-bit-width buckets."""
+        consumes, grouped into per-bit-width buckets, one (S, rows, 128)
+        arena each."""
         if self._pk is not None:
             return self
         idx = self.idx
         self._pk = {}
         self._pk_slot = {}
-        # one source of truth with the engine's segmented-bitmap geometry
-        self._cand_rows = intersect_rounds.bitmap_geometry(self.n_docs)[1]
         staged: dict = {bw: [] for bw in decode_fused.BW_BUCKETS}
         for t, tp in idx.terms.items():
             for bi in range(len(tp.blocks)):
@@ -288,13 +288,13 @@ class DeviceArena:
             if not items:
                 continue
             rpb = decode_fused.rows_per_block(bw)
-            tiles = np.zeros((len(items) * rpb, LANES), np.uint32)
+            tiles = np.zeros((len(items), rpb, LANES), np.uint32)
             firsts, ns = [], []
             for s, (key, first, g) in enumerate(items):
                 self._pk_slot[key] = (bw, s)
                 firsts.append(first)
                 ns.append(len(g))
-                tiles[s * rpb:(s + 1) * rpb] = decode_fused.pack_gaps(g, bw)
+                tiles[s] = decode_fused.pack_gaps(g, bw)
             self._pk[bw] = {"tiles": jnp.asarray(tiles),
                             "first": np.asarray(firsts, np.uint32),
                             "n": np.asarray(ns, np.int32)}
@@ -401,7 +401,7 @@ class DeviceArena:
 
     def fused_and(self, t, blocks, cand: np.ndarray) -> np.ndarray:
         """Intersect sorted candidates with term t's skip-selected blocks
-        through the fused decode+AND kernel (one call per bit-width bucket
+        through the fused tile decode + probe (one call per bit-width bucket
         present in the work-list); exact ``intersect_sorted`` parity."""
         k = len(blocks)
         if k == 0 or len(cand) == 0:
@@ -410,8 +410,8 @@ class DeviceArena:
         for j, bi in enumerate(blocks):
             bw, row = self._pk_slot[(t, int(bi))]
             groups.setdefault(bw, []).append((j, row))
-        words = bitmap_build_np(cand, 0, self._cand_rows * LANES * 32)
-        cand_rows = jnp.asarray(words.reshape(self._cand_rows, LANES))
+        words = intersect_rounds.bitmap_geometry(self.n_docs)[0]
+        cand_words = jnp.asarray(bitmap_build_np(cand, 0, words * 32))
         parts: list = [None] * k
         for bw, items in groups.items():
             pk = self._pk[bw]
@@ -426,26 +426,26 @@ class DeviceArena:
                 ns = np.concatenate([ns, np.zeros(w - len(items), np.int32)])
             ids, hits = decode_fused.fused_decode_and(
                 pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts),
-                jnp.asarray(ns), cand_rows, bw=bw)
-            ids = np.asarray(ids).reshape(w, -1)
-            hits = np.asarray(hits).reshape(w, -1).astype(bool)
+                jnp.asarray(ns), cand_words, bw=bw)
+            ids = np.asarray(ids)
+            hits = np.asarray(hits).astype(bool)
             for g, (j, _) in enumerate(items):
                 parts[j] = ids[g][hits[g]]
             self.stats["fused_calls"] += 1
             self.stats["fused_blocks"] += len(items)
         return np.concatenate(parts)
 
-    def _fused_rounds(self, pairs: list, cand_tiles, with_scores: bool,
-                      ubs=None):
-        """One ``segmented_decode_and`` call per bit-width bucket present in
-        the work-list (plus, with scores, one ``topk.unpack_codes`` call for
-        the bucket's packed score column): the shared body of the AND and
-        ranked fused rounds — grouping, n=0 bucket padding, and stats live
-        here exactly once.  ``ubs`` (optional, aligned with ``pairs``) are
-        per-entry quantized upper bounds the ranked caller threads through to
-        the adaptive-theta masking; they ride the same grouping/padding so
-        the returned array aligns with the output rows (padded rows have
-        n=0 and hit nothing, so their ub value is irrelevant)."""
+    def _fused_rounds(self, pairs: list, with_scores: bool, ubs=None):
+        """One ``decode_fused.decode_tiles`` call per bit-width bucket
+        present in the work-list (plus, with scores, one
+        ``topk.unpack_codes`` call for the bucket's packed score column):
+        the shared body of the AND and ranked fused rounds — grouping, n=0
+        bucket padding, and stats live here exactly once.  ``ubs``
+        (optional, aligned with ``pairs``) are per-entry quantized upper
+        bounds the ranked caller threads through to the adaptive-theta
+        masking; they ride the same grouping/padding so the returned array
+        aligns with the output rows (padded rows have n=0 and scatter
+        nothing, so their ub value is irrelevant)."""
         sa = self.ensure_scores().scores if with_scores else None
         if ubs is None:
             ubs = [0] * len(pairs)
@@ -454,7 +454,7 @@ class DeviceArena:
             bw, row = self._pk_slot[(t, int(bi))]
             groups.setdefault(bw, []).append(
                 (qs, row, sa.slot[(t, int(bi))] if with_scores else 0, ub))
-        parts: list = [[] for _ in range(5)]   # ids, hits, codes, qs, ubs
+        parts: list = [[] for _ in range(5)]   # ids, codes, qs, ns, ubs
         for bw, items in groups.items():
             pk = self._pk[bw]
             rows = np.asarray([r for _, r, _, _ in items], np.int64)
@@ -469,45 +469,40 @@ class DeviceArena:
                 cols = [np.concatenate([c, np.repeat(c[:1], pad)]) for c in cols]
                 cols[4][-pad:] = 0
             slots, qs, sslots, firsts, ns, ub = cols
-            ids, hits = intersect_rounds.segmented_decode_and(
-                pk["tiles"], jnp.asarray(slots), jnp.asarray(qs),
-                jnp.asarray(firsts), jnp.asarray(ns), cand_tiles,
-                bw=bw, crows=self._cand_rows)
-            parts[0].append(ids.reshape(w, -1))
-            parts[1].append(hits.reshape(w, -1))
+            parts[0].append(decode_fused.decode_tiles(
+                pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts), bw=bw))
             if with_scores:
-                codes = topk.unpack_codes(sa.tiles, jnp.asarray(sslots))
-                parts[2].append(codes.reshape(w, -1))
-            parts[3].append(qs)
+                parts[1].append(topk.unpack_codes(sa.tiles,
+                                                  jnp.asarray(sslots)))
+            parts[2].append(qs)
+            parts[3].append(ns)
             parts[4].append(ub)
             self.stats["fused_calls"] += 1
             self.stats["fused_blocks"] += len(items)
         cat = (lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs))
         ncat = (lambda xs: xs[0] if len(xs) == 1 else np.concatenate(xs))
-        return (cat(parts[0]), cat(parts[1]),
-                cat(parts[2]) if with_scores else None,
-                ncat(parts[3]), ncat(parts[4]))
+        return (cat(parts[0]), cat(parts[1]) if with_scores else None,
+                ncat(parts[2]), ncat(parts[3]), ncat(parts[4]))
 
-    def fused_round(self, pairs: list, cand_tiles):
-        """Segmented fused decode + probe for one device-resident AND round.
+    def fused_round(self, pairs: list):
+        """Fused Pallas decode for one device-resident AND round.
 
-        pairs: [(qslot, t, bi), ...] — this round's work-list, every entry
-            probing its own query's candidate tile block.
-        cand_tiles: (Q * _cand_rows, 128) uint32 — the segmented bitmap.
+        pairs: [(qslot, t, bi), ...] — this round's work-list.
 
-        Returns (ids, hits, qslots) device/host arrays of matching leading
-        length, ready for the survivor scatter.  The decoded ids and hit
-        masks never touch the host.
+        Returns (ids, qslots, ns): (P, 512) device docid rows plus the
+        aligned owning-query and posting-count columns, ready for the
+        probe-and-scatter of ``intersect_rounds.round_accumulate``.  The
+        decoded ids never touch the host.
         """
-        ids, hits, _, qs, _ = self._fused_rounds(pairs, cand_tiles, False)
-        return ids, hits, qs
+        ids, _, qs, ns, _ = self._fused_rounds(pairs, False)
+        return ids, qs, ns
 
-    def fused_round_scored(self, pairs: list, cand_tiles, ubs=None):
-        """Segmented fused decode + probe + score-unpack for one ranked
-        round: like :meth:`fused_round` but each work-list entry also runs
-        its block's packed score words through the ``kernels/topk`` Pallas
-        unpack tile, so the engine can scatter ``codes * hits`` straight into
-        the segmented accumulator.  Returns (ids, hits, codes, qslots, ubs);
-        the decoded ids, hit masks, and codes never touch the host.
+    def fused_round_scored(self, pairs: list, ubs=None):
+        """Fused Pallas decode + score-unpack for one ranked round: like
+        :meth:`fused_round` but each work-list entry also runs its block's
+        packed score words through the ``kernels/topk`` Pallas unpack tile,
+        so the engine can scatter the codes straight into the segmented
+        accumulator with ``topk.score_round``.  Returns (ids, codes, qslots,
+        ns, ubs); the decoded ids and codes never touch the host.
         """
-        return self._fused_rounds(pairs, cand_tiles, True, ubs)
+        return self._fused_rounds(pairs, True, ubs)

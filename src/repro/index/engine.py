@@ -27,10 +27,11 @@ hardware-speed along three axes:
      probes the old bitmap and scatters the survivors on device, block
      selection uses only static skip metadata (block first/last docids), and
      the only candidate download is the final result — zero host candidate
-     syncs between rounds.  Under the ``fused`` placement the rounds run the
-     segmented Pallas kernel instead: unpack + d-gap prefix sum + per-query
-     bitmap probe in VMEM, with both the gap tile and the query's candidate
-     tile DMA double-buffered.  Results are bit-identical to the host path.
+     syncs between rounds.  Under the ``fused`` placement the rounds decode
+     with the Pallas kernel instead: unpack + d-gap prefix sum of packed gap
+     tiles in VMEM, with the next work-list tile's DMA double-buffered; the
+     probe and scatter are the same XLA round as the ``device`` placement.
+     Results are bit-identical to the host path.
   5. **Device-resident ranked top-k** — ``or`` / ``and_scored`` batches
      accumulate u8-quantized BM25 impact codes (``repro.index.scores``: one
      packed score column per posting block, next to the docid streams) into
@@ -194,13 +195,17 @@ def _repo_root() -> str:
 def _load_crossover() -> Optional[CrossoverTable]:
     """The crossover table from the committed benchmark baseline
     (``BENCH_QUERY_JSON`` env override, else ``BENCH_query.json`` at the
-    repo root), or None when the file is absent/unreadable — ``plan()``
-    then applies the static ``HOST_BATCH_MAX`` rule."""
+    repo root), or None when the file is absent/unreadable or was measured
+    on another backend than the one running (a CPU curve says nothing about
+    where a TPU batch should go) — ``plan()`` then applies the static
+    ``HOST_BATCH_MAX`` rule."""
     path = (os.environ.get("BENCH_QUERY_JSON")
             or os.path.join(_repo_root(), "BENCH_query.json"))
     try:
         with open(path) as f:
             report = json.load(f)
+        if report.get("backend") != jax.default_backend():
+            return None
         return CrossoverTable.from_bench(report, source=os.path.basename(path))
     except (OSError, ValueError, TypeError, AttributeError):
         return None
@@ -366,7 +371,7 @@ class TermCaps:
     arena: the codec declares an ``ArenaLayout`` — its blocks decode natively
         in the batched device work-list (otherwise they fall back to the
         per-block numpy oracle inside the arena).
-    fused: the arena's fused decode+AND tiles cover every block of the term.
+    fused: the arena's fused decode tiles cover every block of the term.
     """
     codec: Optional[str]
     arena: bool
@@ -436,8 +441,8 @@ class ExecutionPlan:
 
     placement: where the batch runs — "host" (numpy per query, grouped by
         term signature), "device" (round-batched arena work-list decode with
-        device-resident candidates), or "fused" (device + the segmented fused
-        decode+probe kernel for covered terms).  Tiny batches (<=
+        device-resident candidates), or "fused" (device, with covered terms'
+        blocks decoded by the fused Pallas tile kernel).  Tiny batches (<=
         ``HOST_BATCH_MAX`` queries) are auto-placed on the host even when
         arenas exist; ``note`` records that decision in the plan's repr.
     terms: per distinct referenced term, its :class:`TermCaps`.  Unknown
@@ -580,9 +585,10 @@ class QueryEngine:
         """Switch the engine onto the device-resident arenas: all subsequent
         decodes go through batched lane-parallel device calls (with numpy
         fallback per block for codecs the arena doesn't cover).  ``fused``
-        additionally routes eligible AND rounds through the fused
-        decode+bitmap-AND Pallas kernel; its tile arenas are only built (or
-        upgraded onto a cached arena) when actually requested.
+        additionally decodes eligible rounds' blocks through the fused
+        Pallas tile kernel (``kernels/decode_fused``); its tile arenas are
+        only built (or upgraded onto a cached arena) when actually
+        requested.
 
         Doc-range sharded serving: any of ``shards`` (a count — boundaries
         derived from build metadata, :meth:`repro.index.shards.ShardSpec
@@ -1039,8 +1045,8 @@ class QueryEngine:
         (``kernels/intersect_rounds``).  Block selection is conservative and
         static (seed-term coverage intervals from the skip tables), so no
         candidate ever returns to the host until the single final copy.
-        Under ``use_fused`` the rounds run the segmented Pallas
-        decode+probe kernel over the packed gap tiles instead.
+        Under ``use_fused`` the rounds decode the packed gap tiles with the
+        Pallas kernel instead of the codec arenas.
 
         Under a mutation epoch the seed bitmap is ANDed with the epoch's
         packed live row right after round 0 (one upload, zero downloads):
@@ -1061,7 +1067,7 @@ class QueryEngine:
         idx = ctx.gen
         ar = self._arena_ctx(ctx)
         nq = len(queries)
-        words, crows = intersect_rounds.bitmap_geometry(idx.n_docs)
+        words, _ = intersect_rounds.bitmap_geometry(idx.n_docs)
         if nq == 0:
             return jnp.zeros((0, words), jnp.uint32), [], {}
         if qterms is None:
@@ -1086,11 +1092,10 @@ class QueryEngine:
                     new, rows, jnp.asarray(qs), jnp.asarray(ns), bm,
                     probe=probe)
             if fused_pairs:
-                ids, hits, qs = ar.fused_round(
-                    fused_pairs, bm.reshape(nqp * crows, -1))
-                new = intersect_rounds.round_accumulate_masked(
-                    new, ids.reshape(len(qs), -1), jnp.asarray(qs),
-                    hits.reshape(len(qs), -1))
+                ids, qs, ns = ar.fused_round(fused_pairs)
+                new = intersect_rounds.round_accumulate(
+                    new, ids, jnp.asarray(qs), jnp.asarray(ns), bm,
+                    probe=probe)
             if dense:
                 dw, _, dqs, dw0, dact, _ = self._stack_dense(dense)
                 new = intersect_rounds.dense_round_accumulate(
@@ -1555,7 +1560,7 @@ class QueryEngine:
         nq = len(queries)
         self.arena.ensure_scores()
         sa = self.arena.scores
-        words, crows = intersect_rounds.bitmap_geometry(idx.n_docs)
+        words, _ = intersect_rounds.bitmap_geometry(idx.n_docs)
         nqp = _bucket(nq)
         width = topk.accum_width(idx.n_docs)
         acc = jnp.zeros((nqp, width), jnp.uint32)
@@ -1572,13 +1577,6 @@ class QueryEngine:
                 eff_gate = jnp.broadcast_to(ctx.live_dev(words),
                                             (nqp, words))
             self.metrics.inc("tomb_gates")
-        gate_tiles = None
-        if use_fused:       # the probe target of the fused rounds: the AND
-            # bitmap (live-gated under mutation), the live row, or (OR mode,
-            # no deletes) all-ones so only lane validity gates
-            gate_tiles = (eff_gate if eff_gate is not None else
-                          jnp.full((nqp, words), jnp.uint32(0xFFFFFFFF))
-                          ).reshape(nqp * crows, -1)
         ar = self.arena
         order = [sorted(ts, key=lambda t: -sa.term_max[t]) for ts in base_ts]
         margins = np.zeros(nqp, np.int32)
@@ -1648,12 +1646,14 @@ class QueryEngine:
                     jnp.asarray(ubp), theta_dev, iq_dev,
                     gated=eff_gate is not None)
             if fused_pairs:
-                ids, hits, codes, qs, ubf = ar.fused_round_scored(
-                    fused_pairs, gate_tiles, fused_ub)
-                acc, member = topk.score_round_masked(
-                    acc, member, ids.reshape(len(qs), -1), jnp.asarray(qs),
-                    codes.reshape(len(qs), -1), hits.reshape(len(qs), -1),
-                    jnp.asarray(ubf), theta_dev, iq_dev)
+                ids, codes, qs, ns, ubf = ar.fused_round_scored(
+                    fused_pairs, fused_ub)
+                acc, member = topk.score_round(
+                    acc, member, ids, jnp.asarray(qs), codes,
+                    jnp.asarray(ns),
+                    eff_gate if eff_gate is not None else member,
+                    jnp.asarray(ubf), theta_dev, iq_dev,
+                    gated=eff_gate is not None)
             if dense:
                 dw, dtiles, dqs, dw0, _, dub = self._stack_dense(
                     dense, dense_ub, with_codes=True)

@@ -17,7 +17,7 @@ block-max metadata a WAND/BMW-style top-k needs:
     fixed 128-word uint32 stream (:data:`SCORE_COLUMN`, the same padded
     ``ArenaColumn`` contract the codec arenas declare: value ``i`` lives in
     word ``i % 128``, bits ``8 * (i // 128)`` — the bw=8 case of
-    ``decode_fused.pack_gaps``), concatenated into one ``(S, 128)`` device
+    ``decode_fused.pack_gaps``), stacked into one ``(S, 1, 128)`` device
     arena aligned with the block slots.
   * **block-max / term-max / top-impact tables** — per (term, block) the max
     code, per term the max code and its top-:data:`TOP_TABLE` codes sorted
@@ -147,7 +147,7 @@ def topk_select(docs: np.ndarray, scores: np.ndarray, k: int) -> list:
 def _unpack_rows(tiles: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
     """Gather + unpack packed score words: (P,) slots -> (P, 512) uint32
     codes (value i of a block at word i % 128, bits 8 * (i // 128))."""
-    w = tiles[slots]                                    # (P, 128)
+    w = tiles[slots, 0]                                 # (P, 128)
     parts = [(w >> jnp.uint32(8 * r)) & jnp.uint32(0xFF) for r in range(4)]
     return jnp.stack(parts, axis=1).reshape(slots.shape[0], -1)
 
@@ -163,8 +163,10 @@ def unpack_words_np(words: np.ndarray, n: int) -> np.ndarray:
 class ScoreArena:
     """Device-resident quantized impact scores for one ``InvertedIndex``.
 
-    tiles:     (S, 128) uint32 device arena — slot s holds block s's packed
-               codes (:data:`SCORE_COLUMN` layout).
+    tiles:     (S, 1, 128) uint32 device arena — slot s holds block s's
+               packed codes (:data:`SCORE_COLUMN` layout) as one whole
+               trailing (1, 128) block, the shape ``topk.unpack_codes``
+               DMAs per work-list entry.
     block_max: (S,) int32 — max code per slot (== max of the stored codes).
     slot:      {(term, block) -> s}.
     term_max:  {term -> int} max code over the term.
@@ -267,8 +269,8 @@ class ScoreArena:
             self.term_top_ids[t] = ids_cat[order].astype(np.uint32)
             self.stripes[t] = stripe
         self.block_max = np.asarray(bmax, np.int32)
-        self.tiles = (jnp.asarray(np.stack(tiles)) if tiles
-                      else jnp.zeros((1, SCORE_WORDS), jnp.uint32))
+        self.tiles = (jnp.asarray(np.stack(tiles)[:, None, :]) if tiles
+                      else jnp.zeros((1, 1, SCORE_WORDS), jnp.uint32))
         self.dense_w0 = np.asarray(dense_w0, np.int32)
         self.dense_tiles = (jnp.asarray(np.stack(dense_tiles)) if dense_tiles
                             else None)

@@ -7,20 +7,11 @@ survivor bits into the (Q, words) candidate/membership bitmaps.  This module
 is the single home for that step, in three shapes:
 
 * :func:`scatter_add` / :func:`scatter_bits` — the *sparse* form: per-lane
-  docids address arbitrary columns.  On TPU these lower to a segmented Pallas
-  kernel that pins the owning query's row in VMEM while the next entry's
-  contribution tile DMAs in (scalar-prefetched work-list indices, the
-  ``decode_fused`` double-buffering pattern); elsewhere they stay the XLA
-  scatter — compiled Mosaic only exists on TPU, and interpreter-mode Pallas
-  would be strictly slower than the scatter it replaces (the same policy as
-  ``bitpack.auto_interpret``, decided in :func:`use_pallas`).
+  docids address arbitrary columns, one XLA scatter-add per call.
 * :func:`dense_add` — the *dense window* form for bitmap blocks
   (``repro.core.dense_bitmap``): each entry adds a contiguous 4096-column
-  window at a 128-aligned offset, so on TPU the kernel is one aliased
-  VMEM row load/store per entry with no gather at all; the fallback is a
-  sequential ``fori_loop`` of ``dynamic_update_slice`` adds, which beats the
-  general scatter by an order of magnitude on CPU because the window is
-  contiguous.
+  window at a 128-aligned offset, a sequential ``fori_loop`` of
+  ``dynamic_update_slice`` adds with no gather at all.
 * :func:`dense_window_gather` / :func:`dense_window_add` — 128-word window
   probe/commit for the dense AND rounds.
 
@@ -32,80 +23,16 @@ same state the contributing docid sets are disjoint, so add still equals OR.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from .bitpack import LANES
 
 DENSE_WINDOW = 4096          # dense score window: 128 words * 32 bits
 WINDOW_WORDS = 128
 
 
-def use_pallas(flag=None) -> bool:
-    """Route the accumulate step to compiled Pallas only where compiled
-    Pallas exists (TPU); everywhere else the XLA scatter / fori_loop
-    fallbacks are the faster lowering of the same contract."""
-    if flag is not None:
-        return bool(flag)
-    return jax.default_backend() == "tpu"
-
-
-# --------------------------------------------------------------------------- #
-# sparse segmented accumulate
-# --------------------------------------------------------------------------- #
-
-_SPARSE_CHUNK = 2048         # columns of the one-hot tile kept in VMEM
-
-
-def _sparse_kernel(qs_ref, ids_ref, contrib_ref, acc_ref, *, width: int):
-    """Accumulate one entry's (lane,) contributions into its query row.
-
-    The row block is selected by the scalar-prefetched ``qslot`` array and
-    aliased in place; entries arrive sorted by qslot so revisits of the same
-    row are consecutive grid steps and the block stays resident in VMEM.
-    The per-lane docids are expanded chunk-by-chunk as a one-hot
-    compare-and-reduce — 512 x 2048 stays well inside VMEM and the reduce is
-    a plain VPU sum (contributions are u8-bounded, far below f32 precision).
-    """
-    ids = ids_ref[0, :]
-    contrib = contrib_ref[0, :]
-    for c in range(width // _SPARSE_CHUNK):
-        cols = (jnp.arange(_SPARSE_CHUNK, dtype=jnp.uint32)
-                + jnp.uint32(c * _SPARSE_CHUNK))
-        onehot = (ids[:, None] == cols[None, :]).astype(jnp.uint32)
-        add = jnp.sum(onehot * contrib[:, None], axis=0, dtype=jnp.uint32)
-        sl = pl.ds(c * _SPARSE_CHUNK, _SPARSE_CHUNK)
-        acc_ref[0, sl] = acc_ref[0, sl] + add
-
-
-def _sparse_pallas(acc, ids, qslot, contrib):
-    p = ids.shape[0]
-    width = acc.shape[1]
-    order = jnp.argsort(qslot)            # same-row entries -> consecutive
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(p,),
-        in_specs=[pl.BlockSpec((1, ids.shape[1]), lambda i, q: (i, 0)),
-                  pl.BlockSpec((1, ids.shape[1]), lambda i, q: (i, 0))],
-        out_specs=pl.BlockSpec((1, width), lambda i, q: (q[i], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_sparse_kernel, width=width),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
-        input_output_aliases={3: 0},
-    )(qslot[order].astype(jnp.int32), ids[order], contrib[order], acc)
-
-
 def scatter_add(acc, ids, qslot, contrib):
     """acc[qslot[j], ids[j, l]] += contrib[j, l] — exact (docids distinct per
     entry; masked lanes carry contrib == 0)."""
-    if use_pallas():
-        return _sparse_pallas(acc, ids, qslot, contrib)
     return acc.at[qslot[:, None], ids].add(contrib)
 
 
@@ -114,9 +41,6 @@ def scatter_bits(bm, ids, qslot, surv):
     sparse accumulate instantiated for packed bitmap words."""
     word = (ids >> 5).astype(jnp.int32)
     contrib = jnp.where(surv, jnp.uint32(1) << (ids & 31), jnp.uint32(0))
-    if use_pallas():
-        return _sparse_pallas(jnp.zeros_like(bm), word.astype(jnp.uint32),
-                              qslot, contrib)
     return jnp.zeros_like(bm).at[qslot[:, None], word].add(contrib)
 
 
@@ -125,53 +49,18 @@ def scatter_bits(bm, ids, qslot, surv):
 # --------------------------------------------------------------------------- #
 
 
-def _dense_kernel(qs_ref, col_ref, act_ref, codes_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(act_ref[i] != 0)
-    def _():
-        sl = (0, pl.ds(col_ref[i], DENSE_WINDOW))
-        pl.store(acc_ref, sl, pl.load(acc_ref, sl) + codes_ref[0, :])
-
-
-def _dense_pallas(acc, codes, qslot, col0, act):
-    p = codes.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(p,),
-        in_specs=[pl.BlockSpec((1, DENSE_WINDOW), lambda i, q, c, a: (i, 0))],
-        out_specs=pl.BlockSpec((1, acc.shape[1]), lambda i, q, c, a: (q[i], 0)),
-    )
-    return pl.pallas_call(
-        _dense_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
-        input_output_aliases={4: 0},
-    )(qslot.astype(jnp.int32), col0.astype(jnp.int32),
-      act.astype(jnp.int32), codes, acc)
-
-
 @jax.jit
-def _dense_loop(acc, codes, qslot, col0, act):
+def dense_add(acc, codes, qslot, col0, act):
+    """acc[qslot[j], col0[j] : col0[j] + 4096] += codes[j] where act[j].
+
+    ``col0`` is 128-aligned (the arena aligns dense windows at build time so
+    the lane-dimension dynamic slice is tile-aligned on TPU).
+    """
     def body(i, a):
         row = jax.lax.dynamic_slice(a, (qslot[i], col0[i]), (1, DENSE_WINDOW))
         add = jnp.where(act[i], codes[i], jnp.uint32(0))[None, :]
         return jax.lax.dynamic_update_slice(a, row + add, (qslot[i], col0[i]))
     return jax.lax.fori_loop(0, codes.shape[0], body, acc)
-
-
-def dense_add(acc, codes, qslot, col0, act):
-    """acc[qslot[j], col0[j] : col0[j] + 4096] += codes[j] where act[j].
-
-    ``col0`` is 128-aligned (the arena aligns dense windows at build time so
-    the lane-dimension dynamic slice is tile-aligned on TPU).  Entries must
-    arrive sorted by qslot: the TPU row block stays write-resident across
-    consecutive same-row grid steps, and the fallback loop is sequential
-    either way.
-    """
-    if use_pallas():
-        return _dense_pallas(acc, codes, qslot, col0, act)
-    return _dense_loop(acc, codes, qslot, col0, act)
 
 
 # --------------------------------------------------------------------------- #

@@ -75,7 +75,7 @@ def _unpack_kernel(p_ref, o_ref, *, bw: int, frames: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "interpret", "frames_per_block"))
-def pack_frames(x: jnp.ndarray, bw: int, interpret: bool = True, frames_per_block: int = 4) -> jnp.ndarray:
+def pack_frames(x: jnp.ndarray, bw: int, interpret=None, frames_per_block: int = 4) -> jnp.ndarray:
     """(F*32, 128) uint32 -> (F*bw, 128) uint32; F must be a multiple of frames_per_block."""
     f = x.shape[0] // FRAME_ROWS
     fpb = min(frames_per_block, f)
@@ -88,12 +88,12 @@ def pack_frames(x: jnp.ndarray, bw: int, interpret: bool = True, frames_per_bloc
         in_specs=[pl.BlockSpec((fpb * FRAME_ROWS, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((fpb * bw, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((f * bw, LANES), jnp.uint32),
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(x)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "interpret", "frames_per_block"))
-def unpack_frames(packed: jnp.ndarray, bw: int, interpret: bool = True, frames_per_block: int = 4) -> jnp.ndarray:
+def unpack_frames(packed: jnp.ndarray, bw: int, interpret=None, frames_per_block: int = 4) -> jnp.ndarray:
     """(F*bw, 128) uint32 -> (F*32, 128) uint32."""
     f = packed.shape[0] // bw
     fpb = min(frames_per_block, f)
@@ -106,5 +106,5 @@ def unpack_frames(packed: jnp.ndarray, bw: int, interpret: bool = True, frames_p
         in_specs=[pl.BlockSpec((fpb * bw, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((fpb * FRAME_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((f * FRAME_ROWS, LANES), jnp.uint32),
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(packed)

@@ -11,28 +11,22 @@ vectorized; this module is that pipeline's state + kernels:
   * **segmented candidate bitmap** — the whole batch's candidate sets as ONE
     device array of shape (n_queries, words): query q owns row q, a packed
     LSB-first bitmap over [0, n_docs) (``intersect.bitmap_build_np`` order,
-    padded to whole (rows, 128) tiles so the Pallas path can treat row q as a
-    (rows, 128) tile block).
-  * ``bitmap_round`` — one jitted call per AND round: every work-list lane
-    probes its query's segment of the *old* bitmap (decode results feed in
-    directly as padded (out_width,) docid rows), and survivors are scattered
-    into the *new* bitmap.  Distinct docids per (query, term) guarantee the
-    scatter-add is an exact bitwise OR.  Inactive queries carry their segment
-    forward untouched.  When one round mixes representations (sparse arena
-    decode, fused Pallas decode, dense bitmap windows), the round splits into
-    ``round_accumulate*`` calls that all probe the *old* bitmap and OR
-    survivors into one shared *new* bitmap — sound because a block is served
-    by exactly one representation, so the calls' docid sets are disjoint —
-    followed by a single ``round_commit``.
+    padded to whole 128-word rows).
+  * ``round_accumulate`` / ``round_commit`` — one AND round over the whole
+    batch: every work-list lane probes its query's segment of the *old*
+    bitmap (decode results feed in directly as padded (out_width,) docid
+    rows) and survivors are scattered into the *new* bitmap.  Distinct
+    docids per (query, term) guarantee the scatter-add is an exact bitwise
+    OR.  When one round mixes representations (sparse arena decode, fused
+    Pallas decode, dense bitmap windows), every split probes the *old*
+    bitmap and ORs survivors into one shared *new* bitmap — sound because a
+    block is served by exactly one representation, so the calls' docid sets
+    are disjoint — followed by a single ``round_commit`` in which inactive
+    queries carry their segment forward untouched.
   * ``dense_round_accumulate`` — the density-adaptive representation's round
     (``repro.core.dense_bitmap``): a dense block arrives as its raw 128-word
     window, is ANDed word-parallel against the query's old-bitmap window and
     committed back — no unpack, no prefix-sum, no per-posting lanes at all.
-  * ``segmented_decode_and`` — the Pallas form for the fused placement: the
-    ``kernels/decode_fused`` unpack + prefix-sum + bitmap-probe kernel,
-    generalized so every work-list entry selects *its own query's* candidate
-    tile block via a scalar-prefetched query-slot array (the candidate DMA is
-    double-buffered exactly like the gap-tile DMA).
   * ``extract_ids`` — the single final host copy: bitmap rows back to sorted
     uint32 docid arrays, once per batch, after the last round.
 
@@ -55,12 +49,9 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import accumulate
-from .bitpack import LANES, _mask, auto_interpret
-from .decode_fused import BLOCK_ROWS, rows_per_block
+from .bitpack import LANES
 
 
 def bitmap_geometry(n_docs: int) -> tuple[int, int]:
@@ -103,7 +94,7 @@ def pack_live_words_range(dead: np.ndarray, lo: int, hi: int,
 
 
 # --------------------------------------------------------------------------- #
-# probe + scatter round (jnp; the generic-arena placement)
+# probe + scatter round (both device placements)
 # --------------------------------------------------------------------------- #
 
 
@@ -133,13 +124,6 @@ def round_accumulate(new, ids, qslot, ns, bm_old, *, probe: bool = True):
     return new | _scatter_survivors(new, ids, qslot, surv)
 
 
-@jax.jit
-def round_accumulate_masked(new, ids, qslot, hits):
-    """:func:`round_accumulate` with the probe already applied — ``hits`` is
-    the per-lane survivor mask a fused kernel produced."""
-    return new | _scatter_survivors(new, ids, qslot, hits != 0)
-
-
 @functools.partial(jax.jit, static_argnames=("probe",))
 def dense_round_accumulate(new, words, qslot, w0, act, bm_old, *,
                            probe: bool = True):
@@ -164,106 +148,6 @@ def round_commit(bm_old, new, active):
     """Fold a round's accumulated ``new`` bitmap back into the batch state:
     active queries take their new segment, inactive rows keep the old one."""
     return jnp.where(active[:, None], new, bm_old)
-
-
-@functools.partial(jax.jit, static_argnames=("probe",))
-def bitmap_round(bm, ids, qslot, ns, active, *, probe: bool = True):
-    """One single-call device-resident AND round over the whole batch.
-
-    bm:     (Q, words) uint32 — segmented candidate bitmap (old state).
-    ids:    (P, out_width) uint32 — decoded docid rows, one per work-list
-            (query, block) pair, zero-padded past ``ns``.
-    qslot:  (P,) int32 — owning query row per pair.
-    ns:     (P,) int32 — valid posting count per pair (0 for jit padding).
-    active: (Q,) bool — queries intersecting this round; inactive rows keep
-            their old segment.
-    probe:  False builds the seed bitmap (round 0: no old candidates yet).
-
-    Returns the new (Q, words) bitmap, still on device.  (The accumulate /
-    commit split above is the multi-call generalization of this.)
-    """
-    new = round_accumulate(jnp.zeros_like(bm), ids, qslot, ns, bm,
-                           probe=probe)
-    return round_commit(bm, new, active)
-
-
-@jax.jit
-def bitmap_round_masked(bm, ids, qslot, hits, active):
-    """Like :func:`bitmap_round` but with the probe already applied — ``hits``
-    is the per-lane survivor mask a fused kernel produced."""
-    new = round_accumulate_masked(jnp.zeros_like(bm), ids, qslot, hits)
-    return round_commit(bm, new, active)
-
-
-# --------------------------------------------------------------------------- #
-# segmented fused decode + probe (Pallas; the fused placement)
-# --------------------------------------------------------------------------- #
-
-
-def _seg_kernel(slot_ref, qs_ref, first_ref, n_ref, tile_ref, cand_ref,
-                ids_ref, hit_ref, *, bw: int, cand_words: int):
-    """decode_fused's unpack + d-gap prefix sum + bitmap probe, against the
-    candidate tile block of *this entry's query* (both the gap tile and the
-    candidate block are selected by scalar-prefetched work-list arrays, so
-    the next entry's DMAs pipeline while the current one computes)."""
-    i = pl.program_id(0)
-    m = _mask(bw)
-    base = first_ref[i]
-    nn = n_ref[i]
-    cand = cand_ref[...].reshape(-1)
-    lane = jnp.arange(LANES, dtype=jnp.int32)
-    for r in range(BLOCK_ROWS):
-        start = r * bw
-        w, off = start // 32, start % 32
-        v = tile_ref[w, :] >> jnp.uint32(off)
-        if off + bw > 32:
-            v = v | (tile_ref[w + 1, :] << jnp.uint32(32 - off))
-        v = v & m
-        c = jnp.cumsum(v, dtype=jnp.uint32)
-        d = c + base
-        base = base + c[-1]
-        word = cand[jnp.minimum(d >> 5, jnp.uint32(cand_words - 1)).astype(jnp.int32)]
-        hit = (word >> (d & 31)) & jnp.uint32(1)
-        valid = (lane + r * LANES) < nn
-        ids_ref[r, :] = d
-        hit_ref[r, :] = jnp.where(valid, hit, jnp.uint32(0))
-
-
-@functools.partial(jax.jit, static_argnames=("bw", "crows", "interpret"))
-def segmented_decode_and(tiles, slots, qslots, firsts, ns, cand_tiles,
-                         bw: int, crows: int,
-                         interpret=None) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Decode + probe a round's work-list against per-query bitmap segments.
-
-    tiles:      (S * rows_per_block(bw), 128) uint32 packed gap arena.
-    slots:      (W,) int32 arena tile index per entry.
-    qslots:     (W,) int32 owning query row per entry — selects the entry's
-                candidate tile block.
-    firsts:     (W,) uint32 first docid per entry (skip-table value).
-    ns:         (W,) int32 posting count per entry (0 entries hit nothing).
-    cand_tiles: (Q * crows, 128) uint32 — the segmented bitmap, query q
-                owning rows [q * crows, (q + 1) * crows).
-
-    Returns (docids, hits), each (W * 4, 128) uint32; entry j owns rows
-    [4j, 4j + 4) in linear order.
-    """
-    w = slots.shape[0]
-    rpb = rows_per_block(bw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(w,),
-        in_specs=[pl.BlockSpec((rpb, LANES), lambda i, s, q, f, n: (s[i], 0)),
-                  pl.BlockSpec((crows, LANES), lambda i, s, q, f, n: (q[i], 0))],
-        out_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, s, q, f, n: (i, 0)),
-                   pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, s, q, f, n: (i, 0))],
-    )
-    return pl.pallas_call(
-        functools.partial(_seg_kernel, bw=bw, cand_words=crows * LANES),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((w * BLOCK_ROWS, LANES), jnp.uint32),
-                   jax.ShapeDtypeStruct((w * BLOCK_ROWS, LANES), jnp.uint32)],
-        interpret=auto_interpret(interpret),
-    )(slots, qslots, firsts, ns, tiles, cand_tiles)
 
 
 # --------------------------------------------------------------------------- #

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitpack import FRAME_ROWS, LANES
+from .bitpack import FRAME_ROWS, LANES, auto_interpret
 
 
 def _frame_or_kernel(x_ref, o_ref, *, frames: int):
@@ -26,7 +26,7 @@ def _frame_or_kernel(x_ref, o_ref, *, frames: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "frames_per_block"))
-def frame_or(x: jnp.ndarray, interpret: bool = True, frames_per_block: int = 8) -> jnp.ndarray:
+def frame_or(x: jnp.ndarray, interpret=None, frames_per_block: int = 8) -> jnp.ndarray:
     """(F*32, 128) -> (F, 128) per-frame, per-lane OR."""
     f = x.shape[0] // FRAME_ROWS
     fpb = min(frames_per_block, f)
@@ -38,5 +38,5 @@ def frame_or(x: jnp.ndarray, interpret: bool = True, frames_per_block: int = 8) 
         in_specs=[pl.BlockSpec((fpb * FRAME_ROWS, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((fpb, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((f, LANES), jnp.uint32),
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(x)

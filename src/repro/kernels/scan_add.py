@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .bitpack import auto_interpret
+
 LANES = 128
 
 
@@ -35,7 +37,7 @@ def _scan_kernel(x_ref, o_ref, carry_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
-def prefix_sum_blocks(x: jnp.ndarray, rows_per_block: int = 256, interpret: bool = True) -> jnp.ndarray:
+def prefix_sum_blocks(x: jnp.ndarray, rows_per_block: int = 256, interpret=None) -> jnp.ndarray:
     """(R, 128) uint32 -> inclusive prefix sum in linear row-major order."""
     rows = x.shape[0]
     rpb = min(rows_per_block, rows)
@@ -48,5 +50,5 @@ def prefix_sum_blocks(x: jnp.ndarray, rows_per_block: int = 256, interpret: bool
         out_specs=pl.BlockSpec((rpb, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.uint32)],
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(x)

@@ -11,12 +11,11 @@ score accumulator next to them:
   * **membership bitmap** — the same (n_queries, words) packed geometry as
     the AND candidate bitmaps: a bit per doc that contributed anything
     (needed because a code can floor to 0 while the float impact is > 0).
-  * ``score_round`` / ``score_round_masked`` — one jitted call per round:
-    every work-list lane scatters its decoded block's codes into its query's
-    accumulator row.  For ``and_scored`` the lanes first probe the AND-result
-    bitmap (``gate``) so only intersection docs accumulate; the fused path
-    arrives with the probe already applied (``hits`` from the segmented
-    Pallas decode) and uses the ``_masked`` form.
+  * ``score_round`` — one jitted call per round: every work-list lane
+    scatters its decoded block's codes into its query's accumulator row.
+    For ``and_scored`` the lanes first probe the AND-result bitmap (``gate``)
+    so only intersection docs accumulate.  Both device placements share it;
+    they differ only in how the docids and codes were decoded.
   * ``topk_threshold`` + ``candidate_bitmap`` — the bounded "heap" as
     iterative threshold-and-compact: the per-query k-th largest accumulated
     code sum is the threshold theta; the compact keeps every member doc with
@@ -40,9 +39,10 @@ score accumulator next to them:
     compacts itself against promoted bounds with zero per-round host syncs.
   * ``unpack_codes`` — the Pallas tile for the score side of the fused
     placement: each grid step DMAs one block's packed (1, 128) score words
-    (slot selected by a scalar-prefetched work-list array, double-buffered
-    like the gap tiles) and shifts/masks them into (4, 128) code tiles —
-    the bw=8 instantiation of the paper's static shift/mask unroll.
+    from the (S, 1, 128) score arena (slot selected by a scalar-prefetched
+    work-list array, double-buffered like the gap tiles) and shifts/masks
+    them into (4, 128) code tiles — the bw=8 instantiation of the paper's
+    static shift/mask unroll.
 
 Correctness does not depend on work-list selection: scattering a superset of
 blocks is exact (codes of docs outside the gate fail the probe), and pruned
@@ -141,15 +141,6 @@ def score_round(acc, member, ids, qslot, codes, ns, gate, ub, theta, iq, *,
         hit = (gate[qslot[:, None], word] >> (ids & 31)) & jnp.uint32(1)
         surv = surv & (hit == 1)
     return _scatter(acc, member, ids, qslot, codes, surv)
-
-
-@jax.jit
-def score_round_masked(acc, member, ids, qslot, codes, hits, ub, theta, iq):
-    """Like :func:`score_round` with the probe already applied — ``hits`` is
-    the per-lane survivor mask the fused Pallas decode produced."""
-    keep = ub > _scale_q16(theta, iq)[qslot]
-    return _scatter(acc, member, ids, qslot, codes,
-                    (hits != 0) & keep[:, None])
 
 
 def _kth_descend(vals, k: int):
@@ -286,32 +277,35 @@ def dense_score_round(acc, member, tiles, words, qslot, w0, ub, theta, iq,
 
 
 def _unpack_kernel(slot_ref, tile_ref, out_ref):
-    del slot_ref
+    del slot_ref                        # consumed by the tile's index map
+    w = tile_ref[...]
     for r in range(BLOCK_ROWS):
-        out_ref[r, :] = (tile_ref[0, :] >> jnp.uint32(8 * r)) & jnp.uint32(0xFF)
+        out_ref[pl.ds(r, 1), :] = (w >> jnp.uint32(8 * r)) & jnp.uint32(0xFF)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def unpack_codes(tiles, slots, interpret=None) -> jnp.ndarray:
     """Unpack a work-list of packed score tiles in one call.
 
-    tiles: (S, 128) uint32 — the score arena (four codes per word).
+    tiles: (S, 1, 128) uint32 — the score arena (four codes per word).
     slots: (W,) int32 — arena row per work-list entry; drives the
            scalar-prefetched DMA index map exactly like the gap tiles.
 
-    Returns (W * 4, 128) uint32 codes; entry j owns rows [4j, 4j + 4) in the
-    linear order of the docid rows it accompanies.
+    Returns (W, 512) uint32 codes in the linear order of the docid rows
+    they accompany.
     """
     w = slots.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(w,),
-        in_specs=[pl.BlockSpec((1, LANES), lambda i, s: (s[i], 0))],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i, s: (i, 0)),
+        in_specs=[pl.BlockSpec((None, 1, LANES), lambda i, s: (s[i], 0, 0))],
+        out_specs=pl.BlockSpec((None, BLOCK_ROWS, LANES),
+                               lambda i, s: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    codes = pl.pallas_call(
         _unpack_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w * BLOCK_ROWS, LANES), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((w, BLOCK_ROWS, LANES), jnp.uint32),
         interpret=auto_interpret(interpret),
     )(slots, tiles)
+    return codes.reshape(w, BLOCK_ROWS * LANES)

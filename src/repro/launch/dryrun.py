@@ -1,5 +1,9 @@
 import os
+# 512 forced host devices stand in for the production meshes; pin the CPU so
+# neither this process nor its --all children (which inherit the env) try to
+# take an attached accelerator that another process holds
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes (16x16 single-pod, 2x16x16 multi-pod) with

@@ -11,17 +11,11 @@ first).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit/auto axis types; older jax has implicit Auto only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    """Mesh kwargs asking for Auto axis types, on jax versions that have them."""
-    if AxisType is None:
-        return {}
+    """Mesh kwargs asking for Auto axis types."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.configs.base import STEP_FNS
 from repro.distributed import sharding as shlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 
 
@@ -133,6 +134,7 @@ def main() -> None:
                          "spans so durations attribute device wall-clock "
                          "to the producing kernel")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.index:
         serve_index(args)

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.distributed import sharding as shlib
@@ -59,8 +58,8 @@ def lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
     ids_spec = PS(baxes if baxes else None, *([None] * (ids.ndim - 1)))
     out_spec = PS(baxes if baxes else None, *([None] * ids.ndim))
-    return shard_map(local, mesh=mesh, in_specs=(PS(raxis, None), ids_spec),
-                     out_specs=out_spec, check_rep=False)(table, ids)
+    return jax.shard_map(local, mesh=mesh, in_specs=(PS(raxis, None), ids_spec),
+                     out_specs=out_spec, check_vma=False)(table, ids)
 
 
 def lookup_stacked(tables: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
@@ -80,8 +79,8 @@ def lookup_stacked(tables: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
     ids_spec = PS(baxes if baxes else None, *([None] * (ids.ndim - 1)))
     out_spec = PS(baxes if baxes else None, *([None] * ids.ndim))
-    return shard_map(local, mesh=mesh, in_specs=(PS(None, raxis, None), ids_spec),
-                     out_specs=out_spec, check_rep=False)(tables, ids)
+    return jax.shard_map(local, mesh=mesh, in_specs=(PS(None, raxis, None), ids_spec),
+                     out_specs=out_spec, check_vma=False)(tables, ids)
 
 
 def bag_sum(table: jnp.ndarray, ids: jnp.ndarray, valid=None) -> jnp.ndarray:

@@ -21,7 +21,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.distributed.collectives import compressed_psum_mean

@@ -29,12 +29,11 @@ def test_compressed_allreduce_matches_pmean():
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh((8,), ('dp',))
     x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8*5000,)).astype(np.float32))
-    from jax.experimental.shard_map import shard_map
     def f(xl):
         red, ef = C.compressed_allreduce_flat(xl.reshape(-1), ('dp',), bits=8)
         return red, ef
-    red, ef = jax.jit(shard_map(f, mesh=mesh, in_specs=PS('dp'),
-                                out_specs=(PS(None), PS('dp')), check_rep=False))(x)
+    red, ef = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=PS('dp'),
+                                    out_specs=(PS(None), PS('dp')), check_vma=False))(x)
     exact = np.mean(np.asarray(x).reshape(8, 5000), axis=0)
     err = np.abs(np.asarray(red)[:5000] - exact)
     assert err.max() < 0.05 * (np.abs(exact).max() + 1e-6), err.max()

@@ -7,7 +7,9 @@ the sync test body — no plugin dependency); every stream is tiny and seeded,
 so the suite stays tier-1 fast."""
 
 import asyncio
+import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -283,6 +285,23 @@ def test_plan_static_fallback_when_baseline_absent():
         assert "static rule" in tiny.note
     finally:
         set_crossover()
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_crossover_baseline_applies_only_on_its_backend(tmp_path, monkeypatch,
+                                                         backend):
+    # a baseline measured on another backend must not decide placement
+    path = tmp_path / "BENCH_query.json"
+    path.write_text(json.dumps({
+        "backend": backend,
+        "host_qps": {"1": 10.0, "16": 20.0},
+        "device_qps": {"1": 15.0, "16": 40.0}}))
+    monkeypatch.setenv("BENCH_QUERY_JSON", str(path))
+    table = engine_mod._load_crossover()
+    if backend == jax.default_backend():
+        assert table is not None and table.host_batch_max == 0
+    else:
+        assert table is None
 
 
 def test_plan_explicit_placement_bypasses_demotion():
