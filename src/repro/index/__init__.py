@@ -234,10 +234,17 @@ above.
     ``engine/plan``, ``engine/execute``, ``and/seed``, ``and/round``,
     ``and/tomb_gate``, ``ranked/round``, ``ranked/tomb_gate``,
     ``ranked/rescore``, ``sharded/merge``, ``decode/<codec>``,
-    ``kernel/extract_ids``, ``kernel/topk`` — go through the process-global
-    tracer (``repro.obs.enable_tracing()``), DISABLED by default so the
-    resident hot paths pay one attribute check; sub-engines stamp their own
-    ``shard<i>`` lane.  ``to_chrome_trace(stats.tracer, get_tracer())``
+    ``decode/fused``, ``kernel/extract_ids``, ``kernel/topk`` — go through
+    the process-global tracer (``repro.obs.enable_tracing()``), DISABLED by
+    default so the resident hot paths pay one attribute check; sub-engines
+    stamp their own ``shard<i>`` lane.  Every resident round splits into
+    host stages: ``round/rows`` (cache lookups, the arena decode, per-row
+    device slicing), ``round/stack`` (the round's row matrix, on a memo
+    miss) and ``round/launch`` (the accumulate / commit kernels), nested
+    under ``and/seed``, ``and/round`` or ``ranked/round``.  While tracing is
+    on, a ``jax.monitoring`` listener records ``jax/trace``, ``jax/lower``
+    and ``jax/compile`` spans under whatever span was open, so compiles
+    inside a round show as such.  ``to_chrome_trace(stats.tracer, get_tracer())``
     exports Chrome trace-event JSON loadable directly at
     https://ui.perfetto.dev (one named track per lane: serve / engine /
     shard<i> / device); ``python -m repro.launch.serve --index --smoke
@@ -246,15 +253,19 @@ above.
     ``enable_tracing(True, fenced=True)``) brackets round spans with
     ``jax.block_until_ready`` so durations attribute device wall-clock to
     the producing kernel — off by default, keeping the zero-sync
-    discipline untouched; ``Tracer.profiler(logdir)`` hooks
-    ``jax.profiler.trace`` for real-TPU runs.
+    discipline untouched.
   * **Typed metrics** (``repro.obs.metrics``): every engine owns a
     ``MetricsRegistry`` of declared counters (labels drawn from the fixed
     ``LABEL_KEYS`` vocabulary: engine / shard / placement / mode / codec /
     tenant / outcome; duplicate registration raises; schema consistency
     across instances is registry-linted via ``lint_metrics``).  The old
     free-form ``engine.dev_stats`` dict survives as a live READ-ONLY view
-    (``DevStatsView``) over the same counters.  Per-call assertions use
+    (``DevStatsView``) over the same counters.  The device arena's counts
+    (``device_calls``, ``blocks_device``, ``blocks_host``, ``fused_calls``,
+    ``fused_blocks``, ``decode_postings``, ``rows_sliced``) are counters of
+    the registry the engine binds to it, so ``dev_stats`` carries them and
+    ``arena.stats`` is a view of them too; ``rows_stacked`` counts rows put
+    one at a time into a round's row matrix.  Per-call assertions use
     scoped sampling — ``with engine.metrics.scoped() as s: ...;
     s.delta("worklist_decodes")`` — instead of hand-rolled before/after
     subtraction.  ``ServerStats`` carries its own registry

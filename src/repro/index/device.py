@@ -38,9 +38,13 @@ On top sit two batched execution paths:
     scalar-prefetched work-list indices; the candidate probe runs in XLA on
     the decoded rows.
 
-``stats`` counts device calls and blocks decoded per path; the engine's
-work-list dedup guarantees <= 1 decode per hot (term, block) per batch, which
-``benchmarks/bench_query.py`` records alongside the qps numbers.
+``stats`` is a read-only view of the arena's counters (``ARENA_COUNTERS``:
+device calls and blocks decoded per path, postings decoded on the device,
+rows sliced out of a decoded matrix).  They live in a ``MetricsRegistry``:
+the arena's own until ``QueryEngine.to_device()`` binds the engine's
+(``bind_metrics``), so an engine's ``dev_stats`` carries them.  The engine's
+work-list dedup guarantees <= 1 decode per hot (term, block) per batch,
+which ``benchmarks/bench_query.py`` records alongside the qps numbers.
 
 Generations (the streaming mutable index): an arena is built from — and
 belongs to — exactly one immutable ``Generation`` (``repro.index.segments``
@@ -63,12 +67,25 @@ import jax.numpy as jnp
 
 from repro.core import codec as codec_lib
 from repro.core.bits import ebw_np
+from repro.obs.metrics import DevStatsView, MetricsRegistry
 from repro.obs.trace import get_tracer
 from repro.kernels import decode_fused, intersect_rounds, topk
 from repro.kernels.bitpack import LANES
 from repro.kernels.intersect import bitmap_build_np
 
 _MIN_WORKLIST = 8             # smallest jit bucket
+
+# the arena's counters; every engine registry declares them too
+ARENA_COUNTERS = (
+    ("device_calls", "jitted arena work-list decode calls"),
+    ("blocks_device", "blocks decoded by the arena work-list decode"),
+    ("blocks_host", "blocks decoded by the numpy fallback"),
+    ("fused_calls", "fused tile kernel calls (one per bit-width bucket)"),
+    ("fused_blocks", "blocks decoded by the fused tile kernel"),
+    ("decode_postings", "real postings decoded on the device (no padding)"),
+    ("rows_sliced", "device rows cut one at a time out of a decoded or "
+                    "uploaded matrix"),
+)
 
 
 def _bucket(k: int) -> int:
@@ -212,8 +229,11 @@ class DeviceArena:
         # docid window they serve; unsharded indexes cover [0, n_docs)
         self.doc_lo = int(getattr(idx, "doc_lo", 0))
         self.doc_hi = int(getattr(idx, "doc_hi", idx.n_docs))
-        self.stats = {"device_calls": 0, "blocks_device": 0, "blocks_host": 0,
-                      "fused_calls": 0, "fused_blocks": 0}
+        self.metrics = None
+        own = MetricsRegistry(namespace="repro_arena")
+        for name, doc in ARENA_COUNTERS:
+            own.counter(name, doc)
+        self.bind_metrics(own)
         self._loc: dict = {}
         self._groups: dict = {}
         self._build_compressed_arenas(idx)
@@ -313,6 +333,14 @@ class DeviceArena:
     def from_index(cls, idx, build_fused: bool = True) -> "DeviceArena":
         return cls(idx, build_fused=build_fused)
 
+    def bind_metrics(self, registry: MetricsRegistry) -> None:
+        """Count into ``registry``, which declares ``ARENA_COUNTERS``: the
+        engine serving from this arena hands it its own registry."""
+        if registry is not self.metrics:
+            self.metrics = registry
+            self.stats = DevStatsView(registry,
+                                      tuple(n for n, _ in ARENA_COUNTERS))
+
     # ---- capability probes -------------------------------------------------- #
 
     def covers(self, key) -> bool:
@@ -338,16 +366,20 @@ class DeviceArena:
                 host.append((j, e))
             else:
                 by_codec.setdefault(name, []).append((j, slot, e))
+        m = self.metrics
         for name, items in by_codec.items():
+            g = self._groups[name]
+            post = int(g.tab["n"][[s for _, s, _ in items]].sum())
             with get_tracer().span(f"decode/{name}", lane="device",
-                                   blocks=len(items)):
-                self._groups[name].decode(items, out)
-            self.stats["device_calls"] += 1
-            self.stats["blocks_device"] += len(items)
+                                   blocks=len(items), postings=post):
+                g.decode(items, out)
+            m.inc("device_calls")
+            m.inc("blocks_device", len(items))
+            m.inc("decode_postings", post)
         for j, (t, bi, field) in host:
             out[j] = (self.idx.decode_block_ids(t, bi) if field == 0
                       else self.idx.decode_block_tfs(t, bi))
-            self.stats["blocks_host"] += 1
+        m.inc("blocks_host", len(host))
         return out
 
     def decode_blocks_device(self, entries: list):
@@ -369,18 +401,24 @@ class DeviceArena:
                 host.append((j, t, bi))
             else:
                 by_codec.setdefault(name, []).append((j, slot))
+        m = self.metrics
         for name, items in by_codec.items():
             g = self._groups[name]
+            slots = np.asarray([s for _, s in items])
+            post = int(g.tab["n"][slots].sum())
             with get_tracer().span(f"decode/{name}", lane="device",
-                                   blocks=len(items), resident=True):
-                res, n_arr = g.decode_rows(np.asarray([s for _, s in items]))
+                                   blocks=len(items), postings=post,
+                                   resident=True):
+                res, n_arr = g.decode_rows(slots)
             if res.shape[1] != codec_lib.ARENA_BLOCK:       # defensive: all
                 res = res[:, :codec_lib.ARENA_BLOCK]        # layouts use 512
             for r, ((j, _), n) in enumerate(zip(items, n_arr)):
                 rows[j] = res[r]
                 ns[j] = int(n)
-            self.stats["device_calls"] += 1
-            self.stats["blocks_device"] += len(items)
+            m.inc("device_calls")
+            m.inc("blocks_device", len(items))
+            m.inc("decode_postings", post)
+            m.inc("rows_sliced", len(items))
         if host:
             batch = np.zeros((len(host), codec_lib.ARENA_BLOCK), np.uint32)
             for k, (j, t, bi) in enumerate(host):
@@ -390,7 +428,8 @@ class DeviceArena:
             up = jnp.asarray(batch)
             for k, (j, _, _) in enumerate(host):
                 rows[j] = up[k]
-            self.stats["blocks_host"] += len(host)
+            m.inc("blocks_host", len(host))
+            m.inc("rows_sliced", len(host))
         return rows, ns
 
     # ---- fused decode + AND ------------------------------------------------ #
@@ -431,8 +470,8 @@ class DeviceArena:
             hits = np.asarray(hits).astype(bool)
             for g, (j, _) in enumerate(items):
                 parts[j] = ids[g][hits[g]]
-            self.stats["fused_calls"] += 1
-            self.stats["fused_blocks"] += len(items)
+            self.metrics.inc("fused_calls")
+            self.metrics.inc("fused_blocks", len(items))
         return np.concatenate(parts)
 
     def _fused_rounds(self, pairs: list, with_scores: bool, ubs=None):
@@ -445,8 +484,11 @@ class DeviceArena:
         bounds the ranked caller threads through to the adaptive-theta
         masking; they ride the same grouping/padding so the returned array
         aligns with the output rows (padded rows have n=0 and scatter
-        nothing, so their ub value is irrelevant)."""
+        nothing, so their ub value is irrelevant).  Each bucket's decode is a
+        ``decode/fused`` span; joining more than one bucket's rows is a
+        ``round/stack`` span."""
         sa = self.ensure_scores().scores if with_scores else None
+        tr, m = get_tracer(), self.metrics
         if ubs is None:
             ubs = [0] * len(pairs)
         groups: dict = {}
@@ -469,20 +511,30 @@ class DeviceArena:
                 cols = [np.concatenate([c, np.repeat(c[:1], pad)]) for c in cols]
                 cols[4][-pad:] = 0
             slots, qs, sslots, firsts, ns, ub = cols
-            parts[0].append(decode_fused.decode_tiles(
-                pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts), bw=bw))
-            if with_scores:
-                parts[1].append(topk.unpack_codes(sa.tiles,
-                                                  jnp.asarray(sslots)))
+            post = int(ns.sum())            # padding rows carry n=0
+            with tr.span("decode/fused", lane="device", bw=bw,
+                         blocks=len(items), postings=post):
+                parts[0].append(decode_fused.decode_tiles(
+                    pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts),
+                    bw=bw))
+                if with_scores:
+                    parts[1].append(topk.unpack_codes(sa.tiles,
+                                                      jnp.asarray(sslots)))
             parts[2].append(qs)
             parts[3].append(ns)
             parts[4].append(ub)
-            self.stats["fused_calls"] += 1
-            self.stats["fused_blocks"] += len(items)
-        cat = (lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs))
+            m.inc("fused_calls")
+            m.inc("fused_blocks", len(items))
+            m.inc("decode_postings", post)
         ncat = (lambda xs: xs[0] if len(xs) == 1 else np.concatenate(xs))
-        return (cat(parts[0]), cat(parts[1]) if with_scores else None,
-                ncat(parts[2]), ncat(parts[3]), ncat(parts[4]))
+        if len(parts[0]) == 1:
+            ids, codes = parts[0][0], parts[1][0] if with_scores else None
+        else:
+            with tr.span("round/stack", lane="device", rows=len(pairs),
+                         bucket=sum(len(q) for q in parts[2])):
+                ids = jnp.concatenate(parts[0])
+                codes = jnp.concatenate(parts[1]) if with_scores else None
+        return ids, codes, ncat(parts[2]), ncat(parts[3]), ncat(parts[4])
 
     def fused_round(self, pairs: list):
         """Fused Pallas decode for one device-resident AND round.

@@ -95,7 +95,7 @@ from repro.core import codec as codec_lib
 from repro.kernels import intersect, intersect_rounds, topk
 from repro.obs.metrics import DevStatsView, MetricsRegistry
 from repro.obs.trace import get_tracer
-from .device import _bucket     # one shared jit-bucket policy with the arena
+from .device import ARENA_COUNTERS, _bucket   # one jit-bucket policy
 from .invindex import InvertedIndex
 from .scores import B, K1, bm25_scores, topk_select  # noqa: F401  (B/K1 re-export)
 
@@ -482,7 +482,8 @@ _DEV_COUNTERS = (
     ("merge_syncs", "sharded ranked top-k merge collectives (one/batch)"),
     ("collective_bytes", "wire bytes moved by the top-k merge collectives"),
     ("shard_final_syncs", "per-shard end-of-batch result downloads"),
-)
+    ("rows_stacked", "rows passed one at a time to a round's row matrix"),
+) + ARENA_COUNTERS
 _ENGINE_SEQ = itertools.count()
 
 
@@ -514,6 +515,11 @@ class QueryEngine:
         #   top-k merges (the ONE collective per batch) and their wire bytes
         # shard_final_syncs: per-shard end-of-batch result downloads under
         #   sharded execution (each shard contributes one, like final_syncs)
+        # rows_stacked: decoded rows put one at a time into a round's row
+        #   matrix (``_stack_worklist_build``)
+        # ARENA_COUNTERS (device_calls ... rows_sliced): counted by the
+        #   arena this engine serves from, which counts into this registry
+        #   (``_arena_ctx`` binds it)
         #
         # The counters live in a typed MetricsRegistry (repro.obs.metrics);
         # ``dev_stats`` is a read-only live view over it, so every existing
@@ -574,11 +580,13 @@ class QueryEngine:
     def _arena_ctx(self, ctx: _ExecCtx):
         """The device arena serving ``ctx``'s generation: the engine's own
         arena when it matches, else the generation's cached arena (how a
-        plan pinned to a pre-compaction generation keeps its blocks)."""
+        plan pinned to a pre-compaction generation keeps its blocks).  It
+        counts into this engine's registry."""
         a = self.arena
-        if a is not None and getattr(a.idx, "gen", a.idx) is ctx.gen:
-            return a
-        return ctx.gen.to_device(build_fused=self._fused)
+        if a is None or getattr(a.idx, "gen", a.idx) is not ctx.gen:
+            a = ctx.gen.to_device(build_fused=self._fused)
+        a.bind_metrics(self.metrics)
+        return a
 
     def to_device(self, fused=None, shards=None, mesh=None,
                   bounds=None) -> "QueryEngine":
@@ -620,6 +628,7 @@ class QueryEngine:
                 or getattr(self.arena.idx, "gen", self.arena.idx)
                 is not getattr(self.idx, "gen", self.idx)):
             self.arena = arena
+        self.arena.bind_metrics(self.metrics)
         return self
 
     # ---- decode through the cache ------------------------------------------ #
@@ -884,26 +893,31 @@ class QueryEngine:
         """Dedupe a round's (term, block) docid work-list against the cache
         and decode the misses in one device-resident arena call; returns
         {(t, bi): (padded_device_row, n)} for every entry, pinned for the
-        round regardless of cache eviction pressure."""
+        round regardless of cache eviction pressure.  A ``round/rows``
+        span covers it, the arena's per-row slicing included."""
         ctx = self._cur()
         gid = ctx.gen.gid
         out: dict = {}
         missing: list = []
-        for e in entries:
-            if e in out:
-                continue
-            v = self.cache.get((e[0], e[1], 2, gid))
-            if v is None:
-                out[e] = None
-                missing.append(e)
-            else:
-                out[e] = v
+        with self.tracer.span("round/rows", lane=self.trace_lane,
+                              entries=len(entries)) as sp:
+            for e in entries:
+                if e in out:
+                    continue
+                v = self.cache.get((e[0], e[1], 2, gid))
+                if v is None:
+                    out[e] = None
+                    missing.append(e)
+                else:
+                    out[e] = v
+            if sp is not None:
+                sp.args["decoded"] = len(missing)
+            if missing:
+                rows, ns = self._arena_ctx(ctx).decode_blocks_device(missing)
+                for e, row, n in zip(missing, rows, ns):
+                    out[e] = (row, n)
+                    self.cache.put((e[0], e[1], 2, gid), (row, n))
         self.metrics.inc("worklist_decodes", len(missing))
-        if missing:
-            rows, ns = self._arena_ctx(ctx).decode_blocks_device(missing)
-            for e, row, n in zip(missing, rows, ns):
-                out[e] = (row, n)
-                self.cache.put((e[0], e[1], 2, gid), (row, n))
         return out
 
     def _round_memo(self, key, build):
@@ -936,17 +950,21 @@ class QueryEngine:
         pairs = [(t, bi) for _, t, bi in entries]
         rows = self._round_rows(pairs)
         ent = list(rows)
-        ent_row = {e: j for j, e in enumerate(ent)}
-        mat = (rows[ent[0]][0][None] if len(ent) == 1
-               else jnp.stack([rows[e][0] for e in ent]))
         p = _bucket(len(entries))
-        sel = np.zeros(p, np.int64)
-        sel[:len(entries)] = [ent_row[e] for e in pairs]
-        qs = np.zeros(p, np.int32)
-        qs[:len(entries)] = [q for q, _, _ in entries]
-        ns = np.zeros(p, np.int32)
-        ns[:len(entries)] = [rows[e][1] for e in pairs]
-        return mat[jnp.asarray(sel)], qs, ns, p
+        with self.tracer.span("round/stack", lane=self.trace_lane,
+                              rows=len(ent), bucket=p):
+            ent_row = {e: j for j, e in enumerate(ent)}
+            mat = (rows[ent[0]][0][None] if len(ent) == 1
+                   else jnp.stack([rows[e][0] for e in ent]))
+            sel = np.zeros(p, np.int64)
+            sel[:len(entries)] = [ent_row[e] for e in pairs]
+            qs = np.zeros(p, np.int32)
+            qs[:len(entries)] = [q for q, _, _ in entries]
+            ns = np.zeros(p, np.int32)
+            ns[:len(entries)] = [rows[e][1] for e in pairs]
+            mat = mat[jnp.asarray(sel)]
+        self.metrics.inc("rows_stacked", len(ent))
+        return mat, qs, ns, p
 
     def _stack_dense(self, entries: list, ubs=None, with_codes: bool = False):
         """Gather a round's dense-bitmap work-list (``repro.core
@@ -964,18 +982,20 @@ class QueryEngine:
         blocks = tuple((t, bi) for _, t, bi in entries)
 
         def build():
-            sel = np.zeros(p, np.int64)
-            sel[:n] = [ar.dense_slot[b] for b in blocks]
-            words = ar.dense_words[jnp.asarray(sel)]
-            tiles = None
-            if with_codes:
-                sa = ar.ensure_scores().scores
-                srows = np.zeros(p, np.int64)
-                srows[:n] = [sa.dense_slot[b] for b in blocks]
-                tiles = sa.dense_tiles[jnp.asarray(srows)]
-            w0 = np.zeros(p, np.int32)
-            w0[:n] = ar.dense_w0[sel[:n]]
-            return words, tiles, jnp.asarray(w0)
+            with self.tracer.span("round/stack", lane=self.trace_lane,
+                                  rows=n, bucket=p):
+                sel = np.zeros(p, np.int64)
+                sel[:n] = [ar.dense_slot[b] for b in blocks]
+                words = ar.dense_words[jnp.asarray(sel)]
+                tiles = None
+                if with_codes:
+                    sa = ar.ensure_scores().scores
+                    srows = np.zeros(p, np.int64)
+                    srows[:n] = [sa.dense_slot[b] for b in blocks]
+                    tiles = sa.dense_tiles[jnp.asarray(srows)]
+                w0 = np.zeros(p, np.int32)
+                w0[:n] = ar.dense_w0[sel[:n]]
+                return words, tiles, jnp.asarray(w0)
 
         key = (ctx.gen.gid, "dense", with_codes, blocks)
         words, tiles, w0 = self._round_memo(key, build)
@@ -992,9 +1012,13 @@ class QueryEngine:
         """Memoized ``ScoreArena.rows`` for a round's (term, block) work
         -list, padded to the jit bucket by repeating entry 0 (padded lanes
         scatter with n=0, so the values are inert)."""
+        def build():
+            with self.tracer.span("round/stack", lane=self.trace_lane,
+                                  rows=len(pairs), bucket=p):
+                return sa.rows(pairs + [pairs[0]] * (p - len(pairs)))
+
         key = (self._cur().gen.gid, "codes", p, tuple(pairs))
-        return self._round_memo(
-            key, lambda: sa.rows(pairs + [pairs[0]] * (p - len(pairs))))
+        return self._round_memo(key, build)
 
     def _and_qterms(self, queries: list, ctx: _ExecCtx) -> list:
         """Per-query known terms sorted rarest-first (df ascending) with the
@@ -1082,25 +1106,32 @@ class QueryEngine:
             — exact because a block is served by exactly one representation,
             so the splits' docid sets are disjoint — then a single commit
             folds active rows forward (empty splits leave active rows
-            empty: with no survivors their intersections are empty)."""
+            empty: with no survivors their intersections are empty).  The
+            splits' rows are built first, then one ``round/launch`` span
+            covers the kernel calls."""
             active = np.zeros(nqp, bool)
             active[active_idx] = True
-            new = jnp.zeros_like(bm)
             if plain:
                 rows, qs, ns, _ = self._stack_worklist(plain)
-                new = intersect_rounds.round_accumulate(
-                    new, rows, jnp.asarray(qs), jnp.asarray(ns), bm,
-                    probe=probe)
             if fused_pairs:
-                ids, qs, ns = ar.fused_round(fused_pairs)
-                new = intersect_rounds.round_accumulate(
-                    new, ids, jnp.asarray(qs), jnp.asarray(ns), bm,
-                    probe=probe)
+                ids, fqs, fns = ar.fused_round(fused_pairs)
             if dense:
                 dw, _, dqs, dw0, dact, _ = self._stack_dense(dense)
-                new = intersect_rounds.dense_round_accumulate(
-                    new, dw, dqs, dw0, dact, bm, probe=probe)
-            return intersect_rounds.round_commit(bm, new, jnp.asarray(active))
+            with self.tracer.span("round/launch", lane=self.trace_lane):
+                new = jnp.zeros_like(bm)
+                if plain:
+                    new = intersect_rounds.round_accumulate(
+                        new, rows, jnp.asarray(qs), jnp.asarray(ns), bm,
+                        probe=probe)
+                if fused_pairs:
+                    new = intersect_rounds.round_accumulate(
+                        new, ids, jnp.asarray(fqs), jnp.asarray(fns), bm,
+                        probe=probe)
+                if dense:
+                    new = intersect_rounds.dense_round_accumulate(
+                        new, dw, dqs, dw0, dact, bm, probe=probe)
+                return intersect_rounds.round_commit(bm, new,
+                                                     jnp.asarray(active))
 
         def split_dense(pairs):
             """Route (qslot, t, bi) entries to their serving representation
@@ -1153,11 +1184,11 @@ class QueryEngine:
                     e = (i, t, int(bi))
                     if (t, int(bi)) in ar.dense_slot:
                         dense.append(e)
-                        self.metrics.inc("blocks_dense")
                     elif f:
                         fused_pairs.append(e)
                     else:
                         plain.append(e)
+            self.metrics.inc("blocks_dense", len(dense))
             with self.tracer.span("and/round", lane=self.trace_lane, r=r,
                                   plain=len(plain), fused=len(fused_pairs),
                                   dense=len(dense)):
@@ -1595,81 +1626,82 @@ class QueryEngine:
         iq_dev = jnp.asarray(iqs.astype(np.uint32))
         nrounds = max((len(ts) for ts in order), default=0)
         for r in range(nrounds):
-            # detached span (begin/end): covers work-list selection +
-            # block-max pruning + the round's kernel calls without
-            # re-indenting the loop body; decode/<codec> child spans nest
-            # under the thread's enclosing CM (engine/execute) instead
-            _rsp = self.tracer.begin("ranked/round", lane=self.trace_lane,
-                                     r=r, mode=mode)
-            plain, fused_pairs, dense = [], [], []
-            plain_ub, fused_ub, dense_ub = [], [], []
-            for i in range(nq):
-                ts = order[i]
-                if len(ts) <= r or (cov is not None and i not in cov):
-                    continue        # done, or AND seed empty -> nothing scores
-                t = ts[r]
-                if mode == "or":
-                    sel, pruned, ubs_i = self._prune_ranked_blocks(
-                        sa, ts, r, theta0[i], int(iqs[i]))
-                else:
-                    sel, pruned, ubs_i = (
-                        self._select_blocks_static(t, *cov[i]), 0, None)
-                self.metrics.inc("blocks_pruned", pruned)
-                self.metrics.inc("blocks_scored", len(sel))
-                f = use_fused and (terms[t].fused if terms is not None
-                                   else ar.has_fused(t, sel))
-                for j, bi in enumerate(sel):
-                    e = (i, t, int(bi))
-                    u = int(ubs_i[j]) if ubs_i is not None else _UB_ALWAYS
-                    if ((t, int(bi)) in ar.dense_slot
-                            and (t, int(bi)) in sa.dense_slot):
-                        dense.append(e)
-                        dense_ub.append(u)
-                        self.metrics.inc("blocks_dense")
-                    elif f:
-                        fused_pairs.append(e)
-                        fused_ub.append(u)
+            with self.tracer.span("ranked/round", lane=self.trace_lane, r=r,
+                                  mode=mode) as rsp:
+                plain, fused_pairs, dense = [], [], []
+                plain_ub, fused_ub, dense_ub = [], [], []
+                for i in range(nq):
+                    ts = order[i]
+                    if len(ts) <= r or (cov is not None and i not in cov):
+                        continue    # done, or AND seed empty -> nothing scores
+                    t = ts[r]
+                    if mode == "or":
+                        sel, pruned, ubs_i = self._prune_ranked_blocks(
+                            sa, ts, r, theta0[i], int(iqs[i]))
                     else:
-                        plain.append(e)
-                        plain_ub.append(u)
-            self.metrics.inc("score_rounds")
-            if plain:
-                rows, qs, ns, p = self._stack_worklist(plain)
-                codes = self._score_rows(sa, [(t, bi) for _, t, bi in plain],
-                                         p)
-                ubp = np.zeros(p, np.int32)
-                ubp[:len(plain)] = plain_ub
-                acc, member = topk.score_round(
-                    acc, member, rows, jnp.asarray(qs), codes,
-                    jnp.asarray(ns),
-                    eff_gate if eff_gate is not None else member,
-                    jnp.asarray(ubp), theta_dev, iq_dev,
-                    gated=eff_gate is not None)
-            if fused_pairs:
-                ids, codes, qs, ns, ubf = ar.fused_round_scored(
-                    fused_pairs, fused_ub)
-                acc, member = topk.score_round(
-                    acc, member, ids, jnp.asarray(qs), codes,
-                    jnp.asarray(ns),
-                    eff_gate if eff_gate is not None else member,
-                    jnp.asarray(ubf), theta_dev, iq_dev,
-                    gated=eff_gate is not None)
-            if dense:
-                dw, dtiles, dqs, dw0, _, dub = self._stack_dense(
-                    dense, dense_ub, with_codes=True)
-                acc, member = topk.dense_score_round(
-                    acc, member, dtiles, dw, dqs, dw0, dub, theta_dev,
-                    iq_dev, eff_gate if eff_gate is not None else member,
-                    gated=eff_gate is not None)
-            if mode == "or" and armed and k <= width // 32 and r + 1 < nrounds:
-                # adaptive promotion: the pooled k-th is a sound, monotone
-                # lower bound on the final k-th sum (sound only with the
-                # full k — fewer pooled groups than k would over-promote)
-                theta_dev = jnp.maximum(theta_dev,
-                                        topk.pooled_threshold(acc, k))
-            self.tracer.fence(acc)
-            self.tracer.end(_rsp, plain=len(plain), fused=len(fused_pairs),
-                            dense=len(dense))
+                        sel, pruned, ubs_i = (
+                            self._select_blocks_static(t, *cov[i]), 0, None)
+                    self.metrics.inc("blocks_pruned", pruned)
+                    self.metrics.inc("blocks_scored", len(sel))
+                    f = use_fused and (terms[t].fused if terms is not None
+                                       else ar.has_fused(t, sel))
+                    for j, bi in enumerate(sel):
+                        e = (i, t, int(bi))
+                        u = (int(ubs_i[j]) if ubs_i is not None
+                             else _UB_ALWAYS)
+                        if ((t, int(bi)) in ar.dense_slot
+                                and (t, int(bi)) in sa.dense_slot):
+                            dense.append(e)
+                            dense_ub.append(u)
+                        elif f:
+                            fused_pairs.append(e)
+                            fused_ub.append(u)
+                        else:
+                            plain.append(e)
+                            plain_ub.append(u)
+                self.metrics.inc("blocks_dense", len(dense))
+                self.metrics.inc("score_rounds")
+                if rsp is not None:
+                    rsp.args.update(plain=len(plain), fused=len(fused_pairs),
+                                    dense=len(dense))
+                if plain:
+                    rows, qs, ns, p = self._stack_worklist(plain)
+                    codes = self._score_rows(
+                        sa, [(t, bi) for _, t, bi in plain], p)
+                    ubp = np.zeros(p, np.int32)
+                    ubp[:len(plain)] = plain_ub
+                if fused_pairs:
+                    ids, fcodes, fqs, fns, ubf = ar.fused_round_scored(
+                        fused_pairs, fused_ub)
+                if dense:
+                    dw, dtiles, dqs, dw0, _, dub = self._stack_dense(
+                        dense, dense_ub, with_codes=True)
+                gated = eff_gate is not None
+                with self.tracer.span("round/launch", lane=self.trace_lane):
+                    if plain:
+                        acc, member = topk.score_round(
+                            acc, member, rows, jnp.asarray(qs), codes,
+                            jnp.asarray(ns), eff_gate if gated else member,
+                            jnp.asarray(ubp), theta_dev, iq_dev, gated=gated)
+                    if fused_pairs:
+                        acc, member = topk.score_round(
+                            acc, member, ids, jnp.asarray(fqs), fcodes,
+                            jnp.asarray(fns), eff_gate if gated else member,
+                            jnp.asarray(ubf), theta_dev, iq_dev, gated=gated)
+                    if dense:
+                        acc, member = topk.dense_score_round(
+                            acc, member, dtiles, dw, dqs, dw0, dub,
+                            theta_dev, iq_dev, eff_gate if gated else member,
+                            gated=gated)
+                    if (mode == "or" and armed and k <= width // 32
+                            and r + 1 < nrounds):
+                        # adaptive promotion: the pooled k-th is a sound,
+                        # monotone lower bound on the final k-th sum (sound
+                        # only with the full k — fewer pooled groups than k
+                        # would over-promote)
+                        theta_dev = jnp.maximum(
+                            theta_dev, topk.pooled_threshold(acc, k))
+                self.tracer.fence(acc)
         return acc, member, margins, iq_dev, width, words
 
     def _ranked_rescore(self, queries: list, cand: list, k: int, mode: str,

@@ -31,8 +31,14 @@ untouched unless tracing is explicitly enabled (``enable_tracing()`` or
 **Fenced device timing** (off by default): ``tracer.fenced = True`` makes
 ``tracer.fence(x)`` call ``jax.block_until_ready`` inside round spans, so a
 span's duration attributes device wall-clock to the kernel that produced it
-instead of to whichever later op happens to force the value.  For real-TPU
-runs, :meth:`Tracer.profiler` brackets a region with ``jax.profiler.trace``.
+instead of to whichever later op happens to force the value.
+
+**Compile spans**: ``enable_tracing(True)`` registers one
+``jax.monitoring`` duration listener (``enable_tracing(False)`` removes
+it; with tracing off none is registered).  Each JAX trace, lowering and
+backend compile becomes a span ``[now - secs, now]`` on the compiling
+thread, parented to the span open there, so time spent compiling inside a
+round reads as ``jax/compile`` and not as the round's own work.
 
 Span taxonomy (the names emitted across the stack):
 
@@ -51,20 +57,36 @@ Span taxonomy (the names emitted across the stack):
 ``ranked/round``       one ranked accumulate round (args: r, splits)
 ``ranked/tomb_gate``   OR-mode live-row gate upload
 ``ranked/rescore``     the exact float tail
+``round/rows``         a round's (term, block) rows: cache lookups, the
+                       arena decode and the per-row device slicing (args:
+                       entries, decoded)
+``round/stack``        a round's row matrix built on a memo miss: stack and
+                       gather, dense windows, score rows (args: rows, bucket)
+``round/launch``       a round's accumulate / commit kernel launches
 ``sharded/merge``      the one top-k merge collective per ranked batch
-``decode/<codec>``     one per-codec arena decode call (work-list group)
+``decode/<codec>``     one per-codec arena decode call (work-list group;
+                       args: blocks, postings)
+``decode/fused``       one fused tile decode per bit-width bucket (args: bw,
+                       blocks, postings)
 ``kernel/extract_ids`` final bitmap -> sorted docid extraction
 ``kernel/topk``        k-th threshold / top-k stats reduction
+``jax/trace``          JAX tracing a function (arg: fun_name)
+``jax/lower``          lowering a jaxpr to an MLIR module (arg: fun_name)
+``jax/compile``        the backend compile of a program (arg: fun_name)
 =====================  =====================================================
 
+``round/*`` spans nest under ``and/seed``, ``and/round`` or
+``ranked/round``; ``jax/*`` spans under whatever span was open where the
+compile happened.
+
 Engine spans carry ``lane="engine"`` (sub-engines: ``shard0``, ``shard1``,
-...), serving spans ``lane="serve"``, arena decodes ``lane="device"`` — the
-exporter gives each lane its own named track.
+...), serving spans ``lane="serve"``, arena decodes ``lane="device"``,
+compile spans their parent's lane (``"jax"`` with none open) — the exporter
+gives each lane its own named track.
 """
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import threading
 import time
@@ -202,6 +224,20 @@ class Tracer:
             sp.args.update(args)
         self._record(sp)
 
+    def record(self, name: str, t0: float, t1: float, **args) -> None:
+        """Record a span ``[t0, t1]`` that finished on this thread, parented
+        to the span open here and on its lane (``"jax"`` with none open).
+        No-op when disabled."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sp = Span(next(self._ids), name,
+                  parent.lane if parent is not None else "jax", t0,
+                  parent.sid if parent is not None else 0, args)
+        sp.t1 = t1
+        self._record(sp)
+
     # ---- device fencing --------------------------------------------------- #
 
     def fence(self, *values) -> None:
@@ -215,14 +251,6 @@ class Tracer:
         for v in values:
             if v is not None:
                 jax.block_until_ready(v)
-
-    def profiler(self, logdir=None):
-        """Context manager bracketing a region with ``jax.profiler.trace``
-        (the real-TPU hook).  Null when disabled or no ``logdir``."""
-        if not self.enabled or logdir is None:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.trace(str(logdir))
 
     # ---- access ----------------------------------------------------------- #
 
@@ -251,10 +279,35 @@ def set_tracer(tracer: Tracer) -> Tracer:
     return tracer
 
 
+# jax.monitoring duration events recorded as spans while tracing is on
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    "/jax/core/compile/backend_compile_duration": "jax/compile",
+}
+_listening = False
+
+
+def _on_compile(event: str, secs: float, fun_name: str = "?", **_) -> None:
+    name = COMPILE_SPANS.get(event)
+    if name is not None:
+        t1 = _now()
+        _TRACER.record(name, t1 - secs, t1, fun_name=fun_name)
+
+
 def enable_tracing(enabled: bool = True, fenced: bool = False) -> Tracer:
-    """Toggle the process-global tracer (engine + kernel spans)."""
+    """Toggle the process-global tracer (engine + kernel spans) and, with
+    it, the ``jax.monitoring`` listener that records compile spans."""
+    global _listening
     _TRACER.enabled = enabled
     _TRACER.fenced = fenced
+    if enabled != _listening:
+        import jax
+        if enabled:
+            jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        else:
+            jax.monitoring.unregister_event_duration_listener(_on_compile)
+        _listening = enabled
     return _TRACER
 
 

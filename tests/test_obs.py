@@ -252,6 +252,212 @@ def test_engine_spans_disabled_by_default():
 
 
 # --------------------------------------------------------------------------- #
+# compile spans, round spans and round counters
+# --------------------------------------------------------------------------- #
+
+def _obs_listeners() -> list:
+    from jax._src import monitoring
+    return [cb for cb in monitoring.get_event_duration_listeners()
+            if getattr(cb, "__module__", "") == "repro.obs.trace"]
+
+
+def _fresh_program(c: int):
+    """A jitted function JAX has not compiled before (a new constant)."""
+    import jax
+    return jax.jit(lambda x: x * c + 1)
+
+
+def test_compile_spans_nest_under_the_open_span():
+    import jax.numpy as jnp
+    tr = enable_tracing(True)
+    try:
+        tr.clear()
+        with tr.span("outer", lane="engine") as outer:
+            _fresh_program(7919)(jnp.arange(5)).block_until_ready()
+        spans = tr.spans()
+    finally:
+        enable_tracing(False)
+        get_tracer().clear()
+    jax_spans = [s for s in spans if s.name.startswith("jax/")]
+    assert {"jax/trace", "jax/lower", "jax/compile"} <= {
+        s.name for s in jax_spans}
+    for s in jax_spans:
+        assert s.parent_sid == outer.sid and s.lane == "engine"
+        assert outer.t0 <= s.t0 <= s.t1 <= outer.t1
+        assert "fun_name" in s.args
+    assert any(s.args["fun_name"] == "<lambda>" for s in jax_spans
+               if s.name == "jax/trace")
+
+
+def test_compile_listener_registered_only_while_tracing():
+    import jax.numpy as jnp
+    assert _obs_listeners() == []
+    get_tracer().clear()
+    _fresh_program(7927)(jnp.arange(5)).block_until_ready()
+    assert get_tracer().spans() == []
+    enable_tracing(True)
+    enable_tracing(True)                # enabling twice registers once
+    try:
+        assert len(_obs_listeners()) == 1
+    finally:
+        enable_tracing(False)
+        get_tracer().clear()
+    assert _obs_listeners() == []
+
+
+def _round_spans(spans) -> dict:
+    by_sid = {s.sid: s for s in spans}
+    out: dict = {}
+    for s in spans:
+        if s.name.startswith("round/"):
+            out.setdefault(s.name, []).append(by_sid[s.parent_sid].name)
+    return out
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_round_spans_and_counters_on_the_and_path(fused):
+    engine = _engine(device=True, fused=fused)
+    idx, ar = engine.idx, engine.arena
+    placement = "fused" if fused else "device"
+    tr = enable_tracing(True)
+    try:
+        # single-term queries run the seed round alone, on the arena decode:
+        # its work-list is every block of the distinct terms
+        tr.clear()
+        with engine.metrics.scoped() as s:
+            engine.execute(engine.plan(QueryBatch([[0], [1], [0], [5], [2]]),
+                                       placement=placement))
+        seed_spans = tr.spans()
+        # probe rounds over sparse blocks only (terms 2 and 3 are dense)
+        tr.clear()
+        with engine.metrics.scoped() as m:
+            engine.execute(engine.plan(
+                QueryBatch([[0, 1, 4], [5, 4], [1, 5]]), placement=placement))
+        spans = tr.spans()
+    finally:
+        enable_tracing(False)
+        get_tracer().clear()
+    sparse = [(t, bi) for t in (0, 1, 5) for bi in range(idx.n_blocks(t))]
+    assert not any(b in ar.dense_slot for b in sparse)
+    assert s.delta("decode_postings") == sum(
+        idx.terms[t].blocks[bi][1].n for t, bi in sparse)
+    assert s.delta("rows_sliced") == len(sparse)
+    assert s.delta("rows_stacked") == len(sparse)
+    rows = [sp for sp in seed_spans if sp.name == "round/rows"]
+    assert [sp.args for sp in rows] == [{"entries": 4, "decoded": 3}]
+    parents = _round_spans(seed_spans)
+    assert parents["round/rows"] == ["and/seed"]
+    assert parents["round/launch"] == ["and/seed"]
+    assert sorted(parents["round/stack"]) == ["and/seed", "and/seed"]
+    # probe rounds: every round span nests under a round, and the counters
+    # add up what the spans say each call handled
+    parents = _round_spans(spans)
+    assert set(parents) == {"round/rows", "round/stack", "round/launch"}
+    assert set(parents["round/launch"]) == {"and/seed", "and/round"}
+    assert set(parents["round/rows"] + parents["round/stack"]) <= {
+        "and/seed", "and/round"}
+    decoded = [sp for sp in spans if sp.name.startswith("decode/")]
+    assert m.delta("decode_postings") == sum(sp.args["postings"]
+                                             for sp in decoded) > 0
+    assert m.delta("rows_sliced") == sum(sp.args["decoded"] for sp in spans
+                                         if sp.name == "round/rows")
+    assert m.delta("rows_stacked") == sum(sp.args["rows"] for sp in spans
+                                          if sp.name == "round/stack")
+    fused_spans = [sp for sp in decoded if sp.name == "decode/fused"]
+    assert bool(fused_spans) == fused
+    # fused rounds decode every entry, repeats included: (1, 0) twice and
+    # (4, 0) in round 1, (4, 0) in round 2
+    if fused:
+        n = {t: idx.terms[t].blocks[0][1].n for t in (1, 4)}
+        assert sum(sp.args["postings"] for sp in fused_spans) == (
+            2 * n[1] + 2 * n[4])
+        assert all(set(sp.args) == {"bw", "blocks", "postings"}
+                   for sp in fused_spans)
+
+
+def test_round_spans_nest_under_ranked_rounds():
+    engine = _engine(device=True)
+    queries = [[0, 1], [2, 3, 4], [5, 1]]
+    tr = enable_tracing(True)
+    try:
+        for mode in ("and_scored", "or"):
+            tr.clear()
+            engine._round_cache.clear()
+            engine.cache = type(engine.cache)(4096)
+            engine.execute(engine.plan(QueryBatch(queries, mode=mode, k=5),
+                                       placement="device"))
+            parents = _round_spans(tr.spans())
+            want = {"ranked/round"} | ({"and/seed", "and/round"}
+                                       if mode == "and_scored" else set())
+            assert set(parents["round/launch"]) == want, mode
+            assert set(parents["round/rows"] + parents["round/stack"]) <= want
+            if mode == "or":        # and_scored reuses the AND rounds' rows
+                assert "ranked/round" in parents["round/rows"]
+                assert "ranked/round" in parents["round/stack"]
+            ranked = [sp for sp in tr.spans() if sp.name == "ranked/round"]
+            assert ranked and all({"r", "mode", "plain", "fused", "dense"}
+                                  <= set(sp.args) for sp in ranked)
+    finally:
+        enable_tracing(False)
+        get_tracer().clear()
+
+
+def test_tracing_off_adds_no_listener_and_no_host_copy(monkeypatch):
+    """The resident AND path copies one array to the host per batch (the
+    final bitmaps), with tracing off as on, and tracing off leaves no
+    ``jax.monitoring`` listener of ``repro.obs`` behind."""
+    import jax
+    from jax._src import array as jax_array
+    from repro.index import device as device_mod
+    from repro.index import engine as engine_mod
+
+    copies = []
+
+    class _CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(a, *k, **kw):
+            copies.append(isinstance(a, jax.Array))
+            return np.asarray(a, *k, **kw)
+
+        @staticmethod
+        def array(a, *k, **kw):
+            copies.append(isinstance(a, jax.Array))
+            return np.array(a, *k, **kw)
+
+    value = jax_array.ArrayImpl._value
+
+    def counted_value(self):
+        copies.append(True)
+        return value.fget(self)
+
+    engine = _engine(device=True, fused=True)
+    batch = QueryBatch([[0, 1, 2], [5, 3], [4, 2], [1]])
+    engine.execute(engine.plan(batch, placement="fused"))     # compile
+    monkeypatch.setattr(engine_mod, "np", _CountingNumpy())
+    monkeypatch.setattr(device_mod, "np", _CountingNumpy())
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value",
+                        property(counted_value))
+    for on in (False, True):
+        enable_tracing(on)
+        try:
+            assert len(_obs_listeners()) == int(on)
+            copies.clear()
+            engine._round_cache.clear()
+            engine.cache = type(engine.cache)(4096)
+            with engine.metrics.scoped() as s:
+                engine.execute(engine.plan(batch, placement="fused"))
+            assert sum(copies) == 1, on
+            assert s.delta("final_syncs") == 1 and s.delta("cand_syncs") == 0
+        finally:
+            enable_tracing(False)
+            get_tracer().clear()
+    assert _obs_listeners() == []
+
+
+# --------------------------------------------------------------------------- #
 # metrics registry
 # --------------------------------------------------------------------------- #
 
