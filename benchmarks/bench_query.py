@@ -20,9 +20,11 @@ counts: qps per mode, plus the collective accounting — merge syncs and
 collective bytes per ranked batch, and the cross-shard round syncs, which
 must be ZERO: doc-wise partitioning keeps every round shard-local).  On the CPU/interpret CI backend the device path's wall-clock is
 not the headline (jitted gathers vs raw numpy); the tracked guarantee there
-is ``decodes_per_hot_block == 1.0``: each hot (term, block) decodes at most
-once per batch, in O(rounds) device calls instead of O(blocks) Python
-iterations.
+is ``decodes_per_hot_block == 1.0``: no (term, block) repeats inside one
+round's decode call, read off the calls themselves, in O(rounds) device
+calls instead of O(blocks) Python iterations.  A block that a later round
+of the same batch needs again is decoded again (no cache spans rounds);
+``cross_round_redecodes`` counts those.
 
 ``--mutate`` (also run as part of the default suite) exercises the streaming
 mutable index: qps on the device placement at 0% / 1% / 10% tombstone
@@ -171,23 +173,31 @@ def run_batched(dataset: str = "gov2", codec: str = "group_simple",
              f"{n_queries / t:.1f}qps,{t_ref / t:.1f}x")
         report["device_qps"][bs] = n_queries / t
 
-    # work-list discipline at the largest batch size: with an eviction-free
-    # cache on a cold engine, the unique hot (term, block) set is exactly the
-    # decoded-block keys left in the cache, counted independently of the
-    # decode counters — a dedup regression shows up as a ratio > 1
-    eng = QueryEngine(idx, cache_blocks=1 << 20).to_device()
+    # work-list discipline at the largest batch size, read off the slots
+    # each decode call is handed: a slot repeated inside one call is a
+    # round's dedup regressing (a ratio > 1); a slot an earlier call of the
+    # batch already decoded is a cross-round redecode, reported on its own
+    eng = QueryEngine(idx).to_device()
+    calls = []
+    for name, g in eng.arena._groups.items():
+        def counted(slots, _name=name, _decode=g.decode_rows):
+            calls.append([(_name, int(s)) for s in slots])
+            return _decode(slots)
+        g.decode_rows = counted
     eng.execute(eng.plan(QueryBatch(queries, mode="and")))
     refs = eng.dev_stats["worklist_refs"]
-    decodes = (eng.dev_stats["worklist_decodes"]
-               + eng.dev_stats["fallback_decodes"])
-    hot = len({k for k in eng.cache.keys() if k[1] >= 0})
+    decodes = sum(len(c) for c in calls)
+    hot = sum(len(set(c)) for c in calls)
+    redecodes = hot - len({s for c in calls for s in c})
+    report["cross_round_redecodes"] = redecodes
     report["worklist_refs"] = refs
     report["worklist_decodes"] = decodes
     report["hot_blocks"] = hot
     report["decodes_per_hot_block"] = decodes / max(hot, 1)
     emit(f"query/{dataset}/{codec}/device_worklist", 0.0,
          f"{refs}refs,{decodes}decodes,{hot}hot,"
-         f"{decodes / max(hot, 1):.2f}per_hot_block")
+         f"{decodes / max(hot, 1):.2f}per_hot_block,"
+         f"{redecodes}cross_round_redecodes")
 
     # candidate residency per placement: rounds executed with candidates
     # device-resident, and candidate downloads per query (the resident

@@ -58,7 +58,10 @@ def run(out_dir: str = "experiments/dryrun") -> None:
 
 def _kernel_cases():
     """The serving hot loop at a canonical gov2-scale shape: 64 queries,
-    128 work-list entries, 512-posting blocks, 25k-doc bitmap geometry."""
+    128 work-list entries, 512-posting blocks, 25k-doc bitmap geometry.
+    Sparse rounds read a fused part's rows or gather them by row index from
+    a decoded matrix; dense rounds gather their windows by row index from
+    arena-sized matrices, as they serve."""
     import jax.numpy as jnp
     from repro.kernels import topk
     from repro.kernels import intersect_rounds as ir
@@ -75,8 +78,10 @@ def _kernel_cases():
     theta = jnp.zeros((q,), jnp.uint32)
     iq = jnp.full((q,), 1 << 16, jnp.uint32)
     margin = jnp.zeros((q,), jnp.int32)
-    dense_words = jnp.zeros((p, 128), jnp.uint32)
-    dense_tiles = jnp.zeros((p, 1024), jnp.uint32)
+    rows = jnp.zeros((p,), jnp.int32)
+    slots = 4 * p                       # dense windows in the arenas
+    dense_words = jnp.zeros((slots, 128), jnp.uint32)
+    dense_tiles = jnp.zeros((slots, 1024), jnp.uint32)
     w0 = jnp.zeros((p,), jnp.int32)
     act = jnp.zeros((p,), bool)
     active = jnp.zeros((q,), bool)
@@ -87,17 +92,22 @@ def _kernel_cases():
         ("score_round_gated", topk.score_round,
          (acc, bm, ids, qslot, codes, ns, bm, ub, theta, iq),
          {"gated": True}),
+        ("score_round_gathered", topk.score_round,
+         (acc, bm, ids, qslot, codes, ns, bm, ub, theta, iq, rows),
+         {"gated": False}),
         ("dense_score_round", topk.dense_score_round,
-         (acc, bm, dense_tiles, dense_words, qslot, w0, ub, theta, iq, bm),
-         {"gated": True}),
+         (acc, bm, dense_tiles, dense_words, qslot, w0, ub, theta, iq, bm,
+          rows, rows), {"gated": True}),
         ("topk_threshold", topk._topk_threshold_jit, (acc,), {"k": 10}),
         ("pooled_threshold", topk.pooled_threshold, (acc,), {"k": 10}),
         ("candidate_bitmap", topk.candidate_bitmap,
          (acc, bm, theta, margin, iq), {}),
         ("round_accumulate", ir.round_accumulate,
          (bm, ids, qslot, ns, bm), {}),
+        ("round_accumulate_gathered", ir.round_accumulate,
+         (bm, ids, qslot, ns, bm, rows), {}),
         ("dense_round_accumulate", ir.dense_round_accumulate,
-         (bm, dense_words, qslot, w0, act, bm), {}),
+         (bm, dense_words, qslot, w0, act, bm, rows), {}),
         ("round_commit", ir.round_commit, (bm, bm, active), {}),
     ]
 

@@ -238,9 +238,9 @@ above.
     the process-global tracer (``repro.obs.enable_tracing()``), DISABLED by
     default so the resident hot paths pay one attribute check; sub-engines
     stamp their own ``shard<i>`` lane.  Every resident round splits into
-    host stages: ``round/rows`` (cache lookups, the arena decode, per-row
-    device slicing), ``round/stack`` (the round's row matrix, on a memo
-    miss) and ``round/launch`` (the accumulate / commit kernels), nested
+    host stages: ``round/rows`` (the dedupe and the arena decode into one
+    matrix per source), ``round/stack`` (the round's index vectors), both on
+    a memo miss, and ``round/launch`` (the accumulate / commit kernels), nested
     under ``and/seed``, ``and/round`` or ``ranked/round``.  While tracing is
     on, a ``jax.monitoring`` listener records ``jax/trace``, ``jax/lower``
     and ``jax/compile`` spans under whatever span was open, so compiles
@@ -262,10 +262,13 @@ above.
     free-form ``engine.dev_stats`` dict survives as a live READ-ONLY view
     (``DevStatsView``) over the same counters.  The device arena's counts
     (``device_calls``, ``blocks_device``, ``blocks_host``, ``fused_calls``,
-    ``fused_blocks``, ``decode_postings``, ``rows_sliced``) are counters of
+    ``fused_blocks``, ``decode_postings``, ``rows_sliced``,
+    ``rows_stacked``, ``rows_gathered``, ``rows_padded``) are counters of
     the registry the engine binds to it, so ``dev_stats`` carries them and
-    ``arena.stats`` is a view of them too; ``rows_stacked`` counts rows put
-    one at a time into a round's row matrix.  Per-call assertions use
+    ``arena.stats`` is a view of them too; the last four say how a round's
+    rows reach the kernels: cut or stacked one at a time (the numpy
+    -fallback blocks), gathered by row index from a decoded matrix, or
+    bucket padding.  Per-call assertions use
     scoped sampling — ``with engine.metrics.scoped() as s: ...;
     s.delta("worklist_decodes")`` — instead of hand-rolled before/after
     subtraction.  ``ServerStats`` carries its own registry

@@ -40,11 +40,12 @@ On top sit two batched execution paths:
 
 ``stats`` is a read-only view of the arena's counters (``ARENA_COUNTERS``:
 device calls and blocks decoded per path, postings decoded on the device,
-rows sliced out of a decoded matrix).  They live in a ``MetricsRegistry``:
-the arena's own until ``QueryEngine.to_device()`` binds the engine's
-(``bind_metrics``), so an engine's ``dev_stats`` carries them.  The engine's
-work-list dedup guarantees <= 1 decode per hot (term, block) per batch,
-which ``benchmarks/bench_query.py`` records alongside the qps numbers.
+and how a round's rows reach the kernels: by row index into a decoded
+matrix, one at a time, or as bucket padding).  They live in a
+``MetricsRegistry``: the arena's own until ``QueryEngine.to_device()`` binds
+the engine's (``bind_metrics``), so an engine's ``dev_stats`` carries them.
+A resident round's work-list decode (``decode_round``) decodes each distinct
+(term, block) once per round.
 
 Generations (the streaming mutable index): an arena is built from — and
 belongs to — exactly one immutable ``Generation`` (``repro.index.segments``
@@ -74,6 +75,7 @@ from repro.kernels.bitpack import LANES
 from repro.kernels.intersect import bitmap_build_np
 
 _MIN_WORKLIST = 8             # smallest jit bucket
+_DECODE_CHUNK = 256           # lanes per step of a longer work-list decode
 
 # the arena's counters; every engine registry declares them too
 ARENA_COUNTERS = (
@@ -85,6 +87,12 @@ ARENA_COUNTERS = (
     ("decode_postings", "real postings decoded on the device (no padding)"),
     ("rows_sliced", "device rows cut one at a time out of a decoded or "
                     "uploaded matrix"),
+    ("rows_stacked", "rows put one at a time into a round's row matrix "
+                     "(numpy-fallback blocks)"),
+    ("rows_gathered", "work-list entries served by row index from a "
+                      "round's decoded device matrix"),
+    ("rows_padded", "bucket lanes of a round's row-index vectors and fused "
+                    "parts that carry n = 0"),
 )
 
 
@@ -127,7 +135,17 @@ def _decode_worklist(arenas, offs, lens, n, first, is_delta, *, decode, widths):
         i = jnp.arange(vals.shape[0], dtype=jnp.int32)
         return jnp.where(dl, jnp.where(i < nn, ids, 0), vals)
 
-    return jax.vmap(one)(offs, lens, n, first, is_delta)
+    cols = (offs, lens, n, first, is_delta)
+    p = n.shape[0]
+    if p <= _DECODE_CHUNK:
+        return jax.vmap(one)(*cols)
+    # one vmapped decode compiles in time linear in its lanes (23 s at
+    # 8,192 for a v5e); a loop over fixed chunks compiles, at any bucket,
+    # in about the time of one chunk.  Buckets are powers of two, so the
+    # chunks divide p.
+    chunks = jax.tree.map(lambda c: c.reshape(p // _DECODE_CHUNK, -1), cols)
+    out = jax.lax.map(lambda c: jax.vmap(one)(*c), chunks)
+    return out.reshape(p, out.shape[-1])
 
 
 class _ArenaGroup:
@@ -199,11 +217,10 @@ class _ArenaGroup:
     def decode_rows(self, slots: np.ndarray):
         """Device-resident decode: padded (bucket, out_width) docid rows
         (prefix sum + first fused, zero past n) kept on device, plus per-slot
-        posting counts.  The round-resident engine consumes the rows without
-        any host copy."""
-        res, ns = self._run(np.asarray(slots, np.int64),
-                            np.ones(len(slots), bool))
-        return res, ns
+        posting counts.  The round-resident engine consumes the matrix
+        without any host copy."""
+        return self._run(np.asarray(slots, np.int64),
+                         np.ones(len(slots), bool))
 
 
 class DeviceArena:
@@ -382,55 +399,67 @@ class DeviceArena:
         m.inc("blocks_host", len(host))
         return out
 
-    def decode_blocks_device(self, entries: list):
-        """Decode a work-list of (term, block) docid entries WITHOUT copying
-        the results to the host: returns (rows, ns) where ``rows[j]`` is a
-        padded (ARENA_BLOCK,) device array of absolute docids (d-gap prefix
-        sum + first fused, zero past ``ns[j]``).  One jitted call per codec
-        present; blocks without an arena capability decode through the numpy
-        oracle and are *uploaded* in one batch — postings may flow host ->
-        device here, but candidates never flow back.
+    def decode_round(self, pairs: list):
+        """Decode a round's (term, block) docid work-list on the device as
+        row-indexed matrices, never cut into rows.
+
+        Each source — one per codec present, then the blocks without an
+        arena capability (decoded by the numpy oracle and uploaded in one
+        batch) — decodes its distinct blocks once into ONE (bucket, 512)
+        device matrix of absolute docids (d-gap prefix sum + first fused,
+        zero past each block's n) whose rows are ``_bucket`` of the distinct
+        blocks, so only bucket shapes reach the compiler.
+
+        Returns (sources, decoded): ``sources`` is [(mat, pos, rows, ns),
+        ...] where ``pos`` lists the work-list positions the source serves
+        and ``rows`` / ``ns`` (int32, padded with 0 to ``_bucket`` of the
+        entries) give each of them its matrix row and posting count;
+        ``decoded`` counts the distinct blocks.  Postings may flow host ->
+        device for fallback blocks, but nothing flows back.
         """
-        rows: list = [None] * len(entries)
-        ns: list = [0] * len(entries)
-        by_codec: dict = {}
-        host: list = []
-        for j, (t, bi) in enumerate(entries):
+        by_src: dict = {}           # codec name (None: fallback) -> lists
+        for j, (t, bi) in enumerate(pairs):
             name, slot = self._loc[(t, bi, 0)]
+            uniq, pos, rows = by_src.setdefault(name, ({}, [], []))
+            pos.append(j)
+            rows.append(uniq.setdefault((t, bi, slot), len(uniq)))
+        m, tr = self.metrics, get_tracer()
+        sources, decoded = [], 0
+        for name, (uniq, pos, rows) in by_src.items():
             if name is None:
-                host.append((j, t, bi))
+                batch = np.zeros((_bucket(len(uniq)), codec_lib.ARENA_BLOCK),
+                                 np.uint32)
+                ns_u = np.zeros(len(uniq), np.int32)
+                for k, (t, bi, _) in enumerate(uniq):
+                    ids = self.idx.decode_block_ids(t, bi)
+                    batch[k, :len(ids)] = ids
+                    ns_u[k] = len(ids)
+                mat = jnp.asarray(batch)
+                m.inc("blocks_host", len(uniq))
+                m.inc("rows_stacked", len(uniq))
             else:
-                by_codec.setdefault(name, []).append((j, slot))
-        m = self.metrics
-        for name, items in by_codec.items():
-            g = self._groups[name]
-            slots = np.asarray([s for _, s in items])
-            post = int(g.tab["n"][slots].sum())
-            with get_tracer().span(f"decode/{name}", lane="device",
-                                   blocks=len(items), postings=post,
-                                   resident=True):
-                res, n_arr = g.decode_rows(slots)
-            if res.shape[1] != codec_lib.ARENA_BLOCK:       # defensive: all
-                res = res[:, :codec_lib.ARENA_BLOCK]        # layouts use 512
-            for r, ((j, _), n) in enumerate(zip(items, n_arr)):
-                rows[j] = res[r]
-                ns[j] = int(n)
-            m.inc("device_calls")
-            m.inc("blocks_device", len(items))
-            m.inc("decode_postings", post)
-            m.inc("rows_sliced", len(items))
-        if host:
-            batch = np.zeros((len(host), codec_lib.ARENA_BLOCK), np.uint32)
-            for k, (j, t, bi) in enumerate(host):
-                ids = self.idx.decode_block_ids(t, bi)
-                batch[k, :len(ids)] = ids
-                ns[j] = len(ids)
-            up = jnp.asarray(batch)
-            for k, (j, _, _) in enumerate(host):
-                rows[j] = up[k]
-            m.inc("blocks_host", len(host))
-            m.inc("rows_sliced", len(host))
-        return rows, ns
+                g = self._groups[name]
+                slots = np.asarray([s for _, _, s in uniq], np.int64)
+                ns_u = g.tab["n"][slots]
+                post = int(ns_u.sum())
+                with tr.span(f"decode/{name}", lane="device",
+                             blocks=len(uniq), postings=post, resident=True):
+                    mat, _ = g.decode_rows(slots)
+                if mat.shape[1] != codec_lib.ARENA_BLOCK:   # defensive: all
+                    mat = mat[:, :codec_lib.ARENA_BLOCK]    # layouts use 512
+                m.inc("device_calls")
+                m.inc("blocks_device", len(uniq))
+                m.inc("decode_postings", post)
+            w = _bucket(len(pos))
+            r = np.zeros(w, np.int32)
+            r[:len(pos)] = rows
+            ns = np.zeros(w, np.int32)
+            ns[:len(pos)] = ns_u[r[:len(pos)]]
+            sources.append((mat, np.asarray(pos, np.int64), r, ns))
+            decoded += len(uniq)
+            m.inc("rows_gathered", len(pos))
+            m.inc("rows_padded", w - len(pos))
+        return sources, decoded
 
     # ---- fused decode + AND ------------------------------------------------ #
 
@@ -482,11 +511,14 @@ class DeviceArena:
         bucket padding, and stats live here exactly once.  ``ubs``
         (optional, aligned with ``pairs``) are per-entry quantized upper
         bounds the ranked caller threads through to the adaptive-theta
-        masking; they ride the same grouping/padding so the returned array
-        aligns with the output rows (padded rows have n=0 and scatter
-        nothing, so their ub value is irrelevant).  Each bucket's decode is a
-        ``decode/fused`` span; joining more than one bucket's rows is a
-        ``round/stack`` span."""
+        masking; they ride the same grouping/padding (padded rows have n=0
+        and scatter nothing, so their ub value is irrelevant).  Each
+        bucket's decode is a ``decode/fused`` span.
+
+        Returns one part per bucket, [(ids, codes, qslots, ns, ubs), ...],
+        each padded to its own ``_bucket`` and never joined: the caller
+        accumulates each part with its own call (the parts' docid sets are
+        disjoint), so only bucket shapes reach the compiler."""
         sa = self.ensure_scores().scores if with_scores else None
         tr, m = get_tracer(), self.metrics
         if ubs is None:
@@ -496,7 +528,7 @@ class DeviceArena:
             bw, row = self._pk_slot[(t, int(bi))]
             groups.setdefault(bw, []).append(
                 (qs, row, sa.slot[(t, int(bi))] if with_scores else 0, ub))
-        parts: list = [[] for _ in range(5)]   # ids, codes, qs, ns, ubs
+        parts = []
         for bw, items in groups.items():
             pk = self._pk[bw]
             rows = np.asarray([r for _, r, _, _ in items], np.int64)
@@ -514,47 +546,39 @@ class DeviceArena:
             post = int(ns.sum())            # padding rows carry n=0
             with tr.span("decode/fused", lane="device", bw=bw,
                          blocks=len(items), postings=post):
-                parts[0].append(decode_fused.decode_tiles(
+                ids = decode_fused.decode_tiles(
                     pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts),
-                    bw=bw))
-                if with_scores:
-                    parts[1].append(topk.unpack_codes(sa.tiles,
-                                                      jnp.asarray(sslots)))
-            parts[2].append(qs)
-            parts[3].append(ns)
-            parts[4].append(ub)
+                    bw=bw)
+                codes = (topk.unpack_codes(sa.tiles, jnp.asarray(sslots))
+                         if with_scores else None)
+            parts.append((ids, codes, qs, ns, ub))
             m.inc("fused_calls")
             m.inc("fused_blocks", len(items))
             m.inc("decode_postings", post)
-        ncat = (lambda xs: xs[0] if len(xs) == 1 else np.concatenate(xs))
-        if len(parts[0]) == 1:
-            ids, codes = parts[0][0], parts[1][0] if with_scores else None
-        else:
-            with tr.span("round/stack", lane="device", rows=len(pairs),
-                         bucket=sum(len(q) for q in parts[2])):
-                ids = jnp.concatenate(parts[0])
-                codes = jnp.concatenate(parts[1]) if with_scores else None
-        return ids, codes, ncat(parts[2]), ncat(parts[3]), ncat(parts[4])
+            m.inc("rows_padded", w - len(items))
+        return parts
 
     def fused_round(self, pairs: list):
         """Fused Pallas decode for one device-resident AND round.
 
         pairs: [(qslot, t, bi), ...] — this round's work-list.
 
-        Returns (ids, qslots, ns): (P, 512) device docid rows plus the
-        aligned owning-query and posting-count columns, ready for the
-        probe-and-scatter of ``intersect_rounds.round_accumulate``.  The
-        decoded ids never touch the host.
+        Returns one part per bit-width bucket, [(ids, qslots, ns), ...]:
+        (P, 512) device docid rows plus the aligned owning-query and
+        posting-count columns, P a bucket, each ready for its own
+        probe-and-scatter call of ``intersect_rounds.round_accumulate``.
+        The decoded ids never touch the host.
         """
-        ids, _, qs, ns, _ = self._fused_rounds(pairs, False)
-        return ids, qs, ns
+        return [(ids, qs, ns)
+                for ids, _, qs, ns, _ in self._fused_rounds(pairs, False)]
 
     def fused_round_scored(self, pairs: list, ubs=None):
         """Fused Pallas decode + score-unpack for one ranked round: like
         :meth:`fused_round` but each work-list entry also runs its block's
         packed score words through the ``kernels/topk`` Pallas unpack tile,
         so the engine can scatter the codes straight into the segmented
-        accumulator with ``topk.score_round``.  Returns (ids, codes, qslots,
-        ns, ubs); the decoded ids and codes never touch the host.
+        accumulator with ``topk.score_round``.  Returns one part per
+        bit-width bucket, [(ids, codes, qslots, ns, ubs), ...]; the decoded
+        ids and codes never touch the host.
         """
         return self._fused_rounds(pairs, True, ubs)

@@ -249,8 +249,8 @@ _KEEP_ALL_MARGIN = 1 << 30
 # must cover the whole intersection, always scatter)
 _UB_ALWAYS = 1 << 30
 
-# stacked-work-list memo entries kept per engine (each holds a round's
-# gathered device arrays; hot repeated batches skip the restacking)
+# round memo entries kept per engine (each holds a round's decoded device
+# matrices and index vectors; hot repeated batches skip the decode)
 _ROUND_CACHE = 32
 
 
@@ -482,7 +482,6 @@ _DEV_COUNTERS = (
     ("merge_syncs", "sharded ranked top-k merge collectives (one/batch)"),
     ("collective_bytes", "wire bytes moved by the top-k merge collectives"),
     ("shard_final_syncs", "per-shard end-of-batch result downloads"),
-    ("rows_stacked", "rows passed one at a time to a round's row matrix"),
 ) + ARENA_COUNTERS
 _ENGINE_SEQ = itertools.count()
 
@@ -515,9 +514,7 @@ class QueryEngine:
         #   top-k merges (the ONE collective per batch) and their wire bytes
         # shard_final_syncs: per-shard end-of-batch result downloads under
         #   sharded execution (each shard contributes one, like final_syncs)
-        # rows_stacked: decoded rows put one at a time into a round's row
-        #   matrix (``_stack_worklist_build``)
-        # ARENA_COUNTERS (device_calls ... rows_sliced): counted by the
+        # ARENA_COUNTERS (device_calls ... rows_padded): counted by the
         #   arena this engine serves from, which counts into this registry
         #   (``_arena_ctx`` binds it)
         #
@@ -539,9 +536,9 @@ class QueryEngine:
         self._shard_cfg = None     # doc-range sharded serving config
         self._sctx_cache: dict = {}  # (skey, lo, hi) -> shard _ExecCtx
         self._last_shard_cands = None  # debug: last ranked per-shard cands
-        # (gid, kind, work-list) -> the round's gathered device arrays
-        # (docid rows / score rows / dense windows), immutable per
-        # generation; see _round_memo
+        # (gid, kind, work-list) -> the round's device arrays (decoded
+        # matrices and index vectors / score rows / dense windows),
+        # immutable per generation; see _round_memo
         self._round_cache: OrderedDict = OrderedDict()
         if device or fused:
             # deprecated: construct with defaults and call to_device() instead
@@ -889,42 +886,11 @@ class QueryEngine:
         jc = np.minimum(j, max(len(cov_f) - 1, 0))
         return np.flatnonzero(hit & (cov_f[jc] <= l))
 
-    def _round_rows(self, entries: list) -> dict:
-        """Dedupe a round's (term, block) docid work-list against the cache
-        and decode the misses in one device-resident arena call; returns
-        {(t, bi): (padded_device_row, n)} for every entry, pinned for the
-        round regardless of cache eviction pressure.  A ``round/rows``
-        span covers it, the arena's per-row slicing included."""
-        ctx = self._cur()
-        gid = ctx.gen.gid
-        out: dict = {}
-        missing: list = []
-        with self.tracer.span("round/rows", lane=self.trace_lane,
-                              entries=len(entries)) as sp:
-            for e in entries:
-                if e in out:
-                    continue
-                v = self.cache.get((e[0], e[1], 2, gid))
-                if v is None:
-                    out[e] = None
-                    missing.append(e)
-                else:
-                    out[e] = v
-            if sp is not None:
-                sp.args["decoded"] = len(missing)
-            if missing:
-                rows, ns = self._arena_ctx(ctx).decode_blocks_device(missing)
-                for e, row, n in zip(missing, rows, ns):
-                    out[e] = (row, n)
-                    self.cache.put((e[0], e[1], 2, gid), (row, n))
-        self.metrics.inc("worklist_decodes", len(missing))
-        return out
-
     def _round_memo(self, key, build):
-        """Bounded memo for a round's stacked device arrays: identical
-        work-lists (the benchmark loop, hot repeated batches) reuse the
-        gathered rows instead of re-walking caches and re-gathering.  Keys
-        carry the gid, so entries are immutable for their lifetime."""
+        """Bounded memo for a round's device arrays: identical work-lists
+        (the benchmark loop, hot repeated batches) reuse the decoded
+        matrices and index vectors instead of decoding again.  Keys carry
+        the gid, so entries are immutable for their lifetime."""
         v = self._round_cache.get(key)
         if v is None:
             v = build()
@@ -937,44 +903,52 @@ class QueryEngine:
 
     def _stack_worklist(self, entries: list):
         """Shared round discipline for the resident AND and ranked paths:
-        dedupe a round's (qslot, term, block) entries, decode the unique
-        (term, block) rows once (``_round_rows``), and fan them out to the
-        entries with one device gather, padded to the jit bucket (padding
-        repeats entry 0 with n=0, which scatters nothing).  Returns
-        (rows, qslots, ns, bucket); memoized per (gid, work-list)."""
+        decode the unique (term, block) rows of a round's (qslot, term,
+        block) entries once per source (``DeviceArena.decode_round``: one
+        matrix per codec present, one for the numpy fallback), and index
+        each matrix with a bucketed row vector, never slicing or stacking a
+        row.  A ``round/rows`` span covers the decode, a ``round/stack``
+        span the index and query-slot vectors.  Returns [(mat, rows,
+        qslots, ns, pos), ...], one per source: ``rows`` / ``qslots`` /
+        ``ns`` are (P,) device vectors for the P-row ``mat`` (P a bucket;
+        padding carries n=0, which scatters nothing) and ``pos`` the
+        work-list positions the source serves.  Memoized per (gid,
+        work-list)."""
         key = (self._cur().gen.gid, "ids", tuple(entries))
         return self._round_memo(key,
                                 lambda: self._stack_worklist_build(entries))
 
     def _stack_worklist_build(self, entries: list):
-        pairs = [(t, bi) for _, t, bi in entries]
-        rows = self._round_rows(pairs)
-        ent = list(rows)
-        p = _bucket(len(entries))
+        ar = self._arena_ctx(self._cur())
+        with self.tracer.span("round/rows", lane=self.trace_lane,
+                              entries=len(entries)) as sp:
+            sources, decoded = ar.decode_round(
+                [(t, bi) for _, t, bi in entries])
+            if sp is not None:
+                sp.args["decoded"] = decoded
+        self.metrics.inc("worklist_decodes", decoded)
         with self.tracer.span("round/stack", lane=self.trace_lane,
-                              rows=len(ent), bucket=p):
-            ent_row = {e: j for j, e in enumerate(ent)}
-            mat = (rows[ent[0]][0][None] if len(ent) == 1
-                   else jnp.stack([rows[e][0] for e in ent]))
-            sel = np.zeros(p, np.int64)
-            sel[:len(entries)] = [ent_row[e] for e in pairs]
-            qs = np.zeros(p, np.int32)
-            qs[:len(entries)] = [q for q, _, _ in entries]
-            ns = np.zeros(p, np.int32)
-            ns[:len(entries)] = [rows[e][1] for e in pairs]
-            mat = mat[jnp.asarray(sel)]
-        self.metrics.inc("rows_stacked", len(ent))
-        return mat, qs, ns, p
+                              rows=len(entries),
+                              bucket=sum(len(r) for _, _, r, _ in sources)):
+            qslot = np.asarray([q for q, _, _ in entries], np.int32)
+            out = []
+            for mat, pos, rows, ns in sources:
+                qs = np.zeros(len(rows), np.int32)
+                qs[:len(pos)] = qslot[pos]
+                out.append((mat, jnp.asarray(rows), jnp.asarray(qs),
+                            jnp.asarray(ns), pos))
+        return out
 
     def _stack_dense(self, entries: list, ubs=None, with_codes: bool = False):
-        """Gather a round's dense-bitmap work-list (``repro.core
+        """Index a round's dense-bitmap work-list (``repro.core
         .dense_bitmap`` blocks, selected per block through the arena's
-        ``dense_slot`` capability table): the entries' 128-word posting
-        windows — and, ``with_codes``, their window-aligned score tiles —
-        in one device gather each, padded to the jit bucket.  Returns
-        (words, tiles, qslots, w0, act, ub); padding carries act=False and
-        ub=0, which every dense kernel treats as inert.  The device gathers
-        are memoized per (gid, block-list)."""
+        ``dense_slot`` capability table): each entry's row of the arena's
+        128-word window matrix ``dense_words`` — and, ``with_codes``, of the
+        score arena's window-aligned ``dense_tiles`` — as bucketed row
+        vectors that the dense kernels gather on the device.  Returns
+        (rows, tile_rows, qslots, w0, act, ub); padding carries act=False
+        and ub=0, which every dense kernel treats as inert.  The index
+        vectors are memoized per (gid, block-list)."""
         ctx = self._cur()
         ar = self._arena_ctx(ctx)
         n = len(entries)
@@ -984,28 +958,27 @@ class QueryEngine:
         def build():
             with self.tracer.span("round/stack", lane=self.trace_lane,
                                   rows=n, bucket=p):
-                sel = np.zeros(p, np.int64)
+                sel = np.zeros(p, np.int32)
                 sel[:n] = [ar.dense_slot[b] for b in blocks]
-                words = ar.dense_words[jnp.asarray(sel)]
-                tiles = None
+                tile_rows = None
                 if with_codes:
                     sa = ar.ensure_scores().scores
-                    srows = np.zeros(p, np.int64)
+                    srows = np.zeros(p, np.int32)
                     srows[:n] = [sa.dense_slot[b] for b in blocks]
-                    tiles = sa.dense_tiles[jnp.asarray(srows)]
+                    tile_rows = jnp.asarray(srows)
                 w0 = np.zeros(p, np.int32)
                 w0[:n] = ar.dense_w0[sel[:n]]
-                return words, tiles, jnp.asarray(w0)
+                return jnp.asarray(sel), tile_rows, jnp.asarray(w0)
 
         key = (ctx.gen.gid, "dense", with_codes, blocks)
-        words, tiles, w0 = self._round_memo(key, build)
+        rows, tile_rows, w0 = self._round_memo(key, build)
         qs = np.zeros(p, np.int32)
         qs[:n] = [q for q, _, _ in entries]
         act = np.zeros(p, bool)
         act[:n] = True
         ub = np.zeros(p, np.int32)
         ub[:n] = ubs if ubs is not None else _UB_ALWAYS
-        return (words, tiles, jnp.asarray(qs), w0, jnp.asarray(act),
+        return (rows, tile_rows, jnp.asarray(qs), w0, jnp.asarray(act),
                 jnp.asarray(ub))
 
     def _score_rows(self, sa, pairs: list, p: int):
@@ -1101,35 +1074,34 @@ class QueryEngine:
 
         def run_round(bm, plain, fused_pairs, dense, active_idx, probe):
             """One committed AND round: every representation split (sparse
-            arena decode, fused Pallas decode, dense bitmap windows) probes
-            the same OLD bitmap and ORs survivors into ONE shared new bitmap
-            — exact because a block is served by exactly one representation,
-            so the splits' docid sets are disjoint — then a single commit
+            arena decode, one call per source; fused Pallas decode, one call
+            per bit-width bucket; dense bitmap windows) probes the same OLD
+            bitmap and ORs survivors into ONE shared new bitmap — exact
+            because a block is served by exactly one call, so the calls'
+            docid sets are disjoint — then a single commit
             folds active rows forward (empty splits leave active rows
             empty: with no survivors their intersections are empty).  The
             splits' rows are built first, then one ``round/launch`` span
             covers the kernel calls."""
             active = np.zeros(nqp, bool)
             active[active_idx] = True
-            if plain:
-                rows, qs, ns, _ = self._stack_worklist(plain)
-            if fused_pairs:
-                ids, fqs, fns = ar.fused_round(fused_pairs)
+            sources = self._stack_worklist(plain) if plain else []
+            parts = ar.fused_round(fused_pairs) if fused_pairs else []
             if dense:
-                dw, _, dqs, dw0, dact, _ = self._stack_dense(dense)
+                drows, _, dqs, dw0, dact, _ = self._stack_dense(dense)
             with self.tracer.span("round/launch", lane=self.trace_lane):
                 new = jnp.zeros_like(bm)
-                if plain:
+                for mat, rows, qs, ns, _ in sources:
                     new = intersect_rounds.round_accumulate(
-                        new, rows, jnp.asarray(qs), jnp.asarray(ns), bm,
-                        probe=probe)
-                if fused_pairs:
+                        new, mat, qs, ns, bm, rows, probe=probe)
+                for ids, qs, ns in parts:
                     new = intersect_rounds.round_accumulate(
-                        new, ids, jnp.asarray(fqs), jnp.asarray(fns), bm,
+                        new, ids, jnp.asarray(qs), jnp.asarray(ns), bm,
                         probe=probe)
                 if dense:
                     new = intersect_rounds.dense_round_accumulate(
-                        new, dw, dqs, dw0, dact, bm, probe=probe)
+                        new, ar.dense_words, dqs, dw0, dact, bm, drows,
+                        probe=probe)
                 return intersect_rounds.round_commit(bm, new,
                                                      jnp.asarray(active))
 
@@ -1664,34 +1636,39 @@ class QueryEngine:
                 if rsp is not None:
                     rsp.args.update(plain=len(plain), fused=len(fused_pairs),
                                     dense=len(dense))
+                # one score call per sparse source and per fused bucket:
+                # integer adds sum and bit adds OR, whichever call order
+                calls = []
                 if plain:
-                    rows, qs, ns, p = self._stack_worklist(plain)
-                    codes = self._score_rows(
-                        sa, [(t, bi) for _, t, bi in plain], p)
-                    ubp = np.zeros(p, np.int32)
-                    ubp[:len(plain)] = plain_ub
+                    pairs = [(t, bi) for _, t, bi in plain]
+                    ubs = np.asarray(plain_ub, np.int32)
+                    for mat, rows, qs, ns, pos in self._stack_worklist(plain):
+                        p = len(rows)
+                        ubp = np.zeros(p, np.int32)
+                        ubp[:len(pos)] = ubs[pos]
+                        codes = self._score_rows(
+                            sa, [pairs[j] for j in pos], p)
+                        calls.append((mat, qs, codes, ns, ubp, rows))
                 if fused_pairs:
-                    ids, fcodes, fqs, fns, ubf = ar.fused_round_scored(
-                        fused_pairs, fused_ub)
+                    calls += [(ids, jnp.asarray(fqs), fcodes,
+                               jnp.asarray(fns), ubf, None)
+                              for ids, fcodes, fqs, fns, ubf
+                              in ar.fused_round_scored(fused_pairs, fused_ub)]
                 if dense:
-                    dw, dtiles, dqs, dw0, _, dub = self._stack_dense(
+                    drows, dtrows, dqs, dw0, _, dub = self._stack_dense(
                         dense, dense_ub, with_codes=True)
                 gated = eff_gate is not None
                 with self.tracer.span("round/launch", lane=self.trace_lane):
-                    if plain:
+                    for ids, qs, codes, ns, ub, rows in calls:
                         acc, member = topk.score_round(
-                            acc, member, rows, jnp.asarray(qs), codes,
-                            jnp.asarray(ns), eff_gate if gated else member,
-                            jnp.asarray(ubp), theta_dev, iq_dev, gated=gated)
-                    if fused_pairs:
-                        acc, member = topk.score_round(
-                            acc, member, ids, jnp.asarray(fqs), fcodes,
-                            jnp.asarray(fns), eff_gate if gated else member,
-                            jnp.asarray(ubf), theta_dev, iq_dev, gated=gated)
+                            acc, member, ids, qs, codes, ns,
+                            eff_gate if gated else member, jnp.asarray(ub),
+                            theta_dev, iq_dev, rows, gated=gated)
                     if dense:
                         acc, member = topk.dense_score_round(
-                            acc, member, dtiles, dw, dqs, dw0, dub,
-                            theta_dev, iq_dev, eff_gate if gated else member,
+                            acc, member, sa.dense_tiles, ar.dense_words, dqs,
+                            dw0, dub, theta_dev, iq_dev,
+                            eff_gate if gated else member, drows, dtrows,
                             gated=gated)
                     if (mode == "or" and armed and k <= width // 32
                             and r + 1 < nrounds):

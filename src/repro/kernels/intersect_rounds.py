@@ -105,15 +105,23 @@ def _scatter_survivors(bm, ids, qslot, surv):
 
 
 @functools.partial(jax.jit, static_argnames=("probe",))
-def round_accumulate(new, ids, qslot, ns, bm_old, *, probe: bool = True):
+def round_accumulate(new, ids, qslot, ns, bm_old, rows=None, *,
+                     probe: bool = True):
     """Probe ``bm_old``, OR survivors into the shared ``new`` bitmap.
 
     One AND round may split across several accumulate calls (sparse arena
-    decode, fused Pallas decode, dense windows) — every call probes the same
-    *old* state and adds into the same *new* state, and the calls' docid
-    sets are disjoint, so the adds compose into an exact OR regardless of
-    call order.  ``round_commit`` folds the result back per query.
+    decode per source, fused Pallas decode per bit-width bucket, dense
+    windows) — every call probes the same *old* state and adds into the
+    same *new* state, and the calls' docid sets are disjoint, so the adds
+    compose into an exact OR regardless of call order.  ``round_commit``
+    folds the result back per query.
+
+    ``rows`` (optional, (P,) int32) picks each work-list entry's row of
+    ``ids`` on the device: a round's distinct decoded blocks arrive once,
+    as one matrix, however many entries share them.
     """
+    if rows is not None:
+        ids = ids[rows]
     lane = jnp.arange(ids.shape[1], dtype=jnp.int32)
     surv = lane[None, :] < ns[:, None]
     if probe:
@@ -125,19 +133,21 @@ def round_accumulate(new, ids, qslot, ns, bm_old, *, probe: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("probe",))
-def dense_round_accumulate(new, words, qslot, w0, act, bm_old, *,
+def dense_round_accumulate(new, words, qslot, w0, act, bm_old, rows, *,
                            probe: bool = True):
     """Dense-bitmap blocks' AND round: pure word-parallel bitmap algebra.
 
-    words: (P, 128) uint32 — each entry's posting window
+    words: (S, 128) uint32 — the arena's posting windows
            (``repro.core.dense_bitmap`` words at the arena's 4-word phase).
     w0:    (P,) int32 — the window's first word in the bitmap geometry.
     act:   (P,) bool — live entries (False for jit padding).
+    rows:  (P,) int32 — each entry's row of ``words``, gathered on the
+           device.
 
     The probe is 128 word ANDs against the query's old-bitmap window — no
     unpack, no prefix-sum, no per-posting lanes.
     """
-    surv = words
+    surv = words[rows]
     if probe:
         surv = surv & accumulate.dense_window_gather(bm_old, qslot, w0)
     return accumulate.dense_window_add(new, surv, qslot, w0, act)

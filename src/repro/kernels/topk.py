@@ -110,8 +110,8 @@ def _scatter(acc, member, ids, qslot, codes, surv):
 
 
 @functools.partial(jax.jit, static_argnames=("gated",))
-def score_round(acc, member, ids, qslot, codes, ns, gate, ub, theta, iq, *,
-                gated: bool):
+def score_round(acc, member, ids, qslot, codes, ns, gate, ub, theta, iq,
+                rows=None, *, gated: bool):
     """One ranked round over the whole batch.
 
     acc:    (Q, width) uint32 — segmented score accumulator (old state).
@@ -128,11 +128,16 @@ def score_round(acc, member, ids, qslot, codes, ns, gate, ub, theta, iq, *,
             Entries that must always run carry a huge ub.
     theta:  (Q,) uint32 — promoted per-query threshold (0 before promotion).
     iq:     (Q,) uint32 — Q16.16 idf-ratio deflation (65536 = identity).
+    rows:   optional (P,) int32 — each entry's row of ``ids``, gathered on
+            the device (a round's distinct decoded blocks, indexed);
+            ``codes`` stays one row per entry.
 
     Returns (acc, member), both still on device.  Dropping an entry with
     ``ub <= scaled theta`` is sound: every doc in it ends below
     theta_final - margin, outside the candidate superset.
     """
+    if rows is not None:
+        ids = ids[rows]
     ns = jnp.where(ub > _scale_q16(theta, iq)[qslot], ns, 0)
     lane = jnp.arange(ids.shape[1], dtype=jnp.int32)
     surv = lane[None, :] < ns[:, None]
@@ -236,16 +241,18 @@ def candidate_bitmap(acc, member, theta, margin, iq):
 
 @functools.partial(jax.jit, static_argnames=("gated",))
 def dense_score_round(acc, member, tiles, words, qslot, w0, ub, theta, iq,
-                      gate, *, gated: bool):
+                      gate, rows, tile_rows, *, gated: bool):
     """One ranked round over the batch's dense-bitmap work-list entries.
 
-    tiles: (P, 1024) uint32 — packed code windows, four u8 codes per word in
-           window-position order (position p lives in word p >> 2, byte
-           p & 3); positions with no posting carry code 0.
-    words: (P, 128) uint32 — the entry's posting bitmap window
+    tiles: (S', 1024) uint32 — the score arena's packed code windows, four
+           u8 codes per word in window-position order (position p lives in
+           word p >> 2, byte p & 3); positions with no posting carry code 0.
+    words: (S, 128) uint32 — the arena's posting bitmap windows
            (``dense_bitmap`` words, realigned to the arena's 4-word phase).
     w0:    (P,) int32 — first word of the entry's window in the bitmap
            geometry; 4-word aligned, so column w0 * 32 is lane-tile aligned.
+    rows / tile_rows: (P,) int32 — each entry's row of ``words`` /
+           ``tiles``, gathered on the device.
 
     No unpack/prefix-sum: codes add as one contiguous 4096-column window
     (:func:`repro.kernels.accumulate.dense_add`) and membership/gating stay
@@ -253,6 +260,7 @@ def dense_score_round(acc, member, tiles, words, qslot, w0, ub, theta, iq,
     :func:`score_round` of the same round — integer adds sum and the bit
     adds OR, whichever call order.
     """
+    words, tiles = words[rows], tiles[tile_rows]
     act = ub > _scale_q16(theta, iq)[qslot]
     p = tiles.shape[0]
     codes = ((tiles[:, :, None] >> (jnp.uint32(8) *

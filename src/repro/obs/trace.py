@@ -57,11 +57,12 @@ Span taxonomy (the names emitted across the stack):
 ``ranked/round``       one ranked accumulate round (args: r, splits)
 ``ranked/tomb_gate``   OR-mode live-row gate upload
 ``ranked/rescore``     the exact float tail
-``round/rows``         a round's (term, block) rows: cache lookups, the
-                       arena decode and the per-row device slicing (args:
-                       entries, decoded)
-``round/stack``        a round's row matrix built on a memo miss: stack and
-                       gather, dense windows, score rows (args: rows, bucket)
+``round/rows``         a round's (term, block) rows on a memo miss: the
+                       dedupe and the arena decode into one matrix per
+                       source (args: entries, decoded)
+``round/stack``        a round's index vectors on a memo miss: row and
+                       query-slot vectors, dense windows, score rows (args:
+                       rows, bucket)
 ``round/launch``       a round's accumulate / commit kernel launches
 ``sharded/merge``      the one top-k merge collective per ranked batch
 ``decode/<codec>``     one per-codec arena decode call (work-list group;

@@ -67,6 +67,26 @@ def test_decode_tiles_compiles(one_chip, bw):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("lanes", [64, 8 * WORKLIST])
+def test_decode_worklist_compiles(one_chip, lanes):
+    """The arena work-list decode, in one vmap and, past one chunk of
+    lanes, as a loop over chunks."""
+    import numpy as np
+    from repro.core import codec
+    from repro.index import device
+    lay = codec.get("group_simple").arena
+    widths = tuple(col.width for col in lay.columns)
+    per_e = _sds(one_chip, (lanes,), jnp.int32)
+    _compiled_text(
+        device._decode_worklist,
+        tuple(_sds(one_chip, (ARENA_SLOTS * 512,), np.dtype(col.dtype))
+              for col in lay.columns),
+        (per_e,) * len(widths), (per_e,) * len(widths), per_e,
+        _sds(one_chip, (lanes,), jnp.uint32),
+        _sds(one_chip, (lanes,), jnp.bool_),
+        decode=lay.decode_block, widths=widths)
+
+
 @pytest.mark.parametrize("bw", decode_fused.BW_BUCKETS)
 def test_fused_decode_and_compiles(one_chip, bw):
     words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
@@ -89,21 +109,25 @@ def test_unpack_codes_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("gathered", [False, True])
 @pytest.mark.parametrize("probe", [False, True])
-def test_round_accumulate_compiles(one_chip, probe):
-    """The AND round both device placements share: XLA probe + scatter."""
+def test_round_accumulate_compiles(one_chip, probe, gathered):
+    """The AND round both device placements share: XLA probe + scatter,
+    over a fused part's rows or gathered by row index from a decoded
+    matrix."""
     words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
     bm = _sds(one_chip, (NQ, words), jnp.uint32)
+    per_e = _sds(one_chip, (WORKLIST,), jnp.int32)
     hlo = _compiled_text(
         intersect_rounds.round_accumulate, bm,
         _sds(one_chip, (WORKLIST, decode_fused.BLOCK), jnp.uint32),
-        _sds(one_chip, (WORKLIST,), jnp.int32),
-        _sds(one_chip, (WORKLIST,), jnp.int32), bm, probe=probe)
+        per_e, per_e, bm, per_e if gathered else None, probe=probe)
     assert "tpu_custom_call" not in hlo     # no Pallas scatter on this path
 
 
+@pytest.mark.parametrize("gathered", [False, True])
 @pytest.mark.parametrize("gated", [False, True])
-def test_score_round_compiles(one_chip, gated):
+def test_score_round_compiles(one_chip, gated, gathered):
     """The ranked round both device placements share, on the 6.3M-wide
     accumulator."""
     words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
@@ -114,7 +138,39 @@ def test_score_round_compiles(one_chip, gated):
     per_e = _sds(one_chip, (WORKLIST,), jnp.int32)
     hlo = _compiled_text(
         topk.score_round, _sds(one_chip, (NQ, width), jnp.uint32), bm,
-        rows, per_e, rows, per_e, bm, per_e, per_q, per_q, gated=gated)
+        rows, per_e, rows, per_e, bm, per_e, per_q, per_q,
+        per_e if gathered else None, gated=gated)
+    assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("probe", [False, True])
+def test_dense_round_accumulate_compiles(one_chip, probe):
+    """The dense-window AND round, gathering its windows by row index from
+    the arena's whole window matrix."""
+    words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
+    bm = _sds(one_chip, (NQ, words), jnp.uint32)
+    per_e = _sds(one_chip, (WORKLIST,), jnp.int32)
+    hlo = _compiled_text(
+        intersect_rounds.dense_round_accumulate, bm,
+        _sds(one_chip, (ARENA_SLOTS, 128), jnp.uint32), per_e, per_e,
+        _sds(one_chip, (WORKLIST,), jnp.bool_), bm, per_e, probe=probe)
+    assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_dense_score_round_compiles(one_chip, gated):
+    """The dense-window ranked round, gathering windows and score tiles by
+    row index from the arenas' whole matrices."""
+    words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
+    width = topk.accum_width(SHARD_DOCS)
+    bm = _sds(one_chip, (NQ, words), jnp.uint32)
+    per_q = _sds(one_chip, (NQ,), jnp.uint32)
+    per_e = _sds(one_chip, (WORKLIST,), jnp.int32)
+    hlo = _compiled_text(
+        topk.dense_score_round, _sds(one_chip, (NQ, width), jnp.uint32), bm,
+        _sds(one_chip, (ARENA_SLOTS, 1024), jnp.uint32),
+        _sds(one_chip, (ARENA_SLOTS, 128), jnp.uint32), per_e, per_e, per_e,
+        per_q, per_q, bm, per_e, per_e, gated=gated)
     assert "tpu_custom_call" not in hlo
 
 

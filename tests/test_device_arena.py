@@ -2,8 +2,8 @@
 device-placed engine must be bit-identical to the numpy engine across every
 registered group codec, including block-boundary (df == 512/513/1024) and
 empty-intersection edge cases; the fused decode+AND kernel must match the
-host intersection exactly; and the work-list discipline (<= 1 decode per hot
-(term, block) per batch) must hold.
+host intersection exactly; and the work-list discipline (<= 1 decode per
+distinct (term, block) per round) must hold.
 
 The native-decode sweep derives its codec list from the registry's *declared*
 arena capabilities (``codec.get(name).arena``), so a codec gaining an
@@ -86,12 +86,15 @@ def test_fused_decode_and_matches_host_engine(name):
     assert dev.arena.stats["fused_calls"] > 0   # the kernel actually ran
 
 
+@pytest.mark.parametrize("repeat", [1, 40])
 @pytest.mark.parametrize("name", ARENA_CODECS)
-def test_arena_block_decode_matches_numpy_oracle(name):
+def test_arena_block_decode_matches_numpy_oracle(name, repeat):
+    """``repeat`` 40 makes each codec's work-list longer than one decode
+    chunk, so the chunked decode loop runs too."""
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec=name)
     arena = DeviceArena.from_index(idx, build_fused=False)
     entries = [(t, bi, f) for t in idx.terms
-               for bi in range(idx.n_blocks(t)) for f in (0, 1)]
+               for bi in range(idx.n_blocks(t)) for f in (0, 1)] * repeat
     got = arena.decode_blocks(entries)
     for (t, bi, f), a in zip(entries, got):
         want = idx.decode_block_ids(t, bi) if f == 0 else idx.decode_block_tfs(t, bi)
@@ -206,24 +209,39 @@ def test_deprecated_constructor_flags_still_work():
 
 
 def test_device_worklist_decodes_each_hot_block_once():
+    """Each distinct (term, block) of a resident round decodes once in that
+    round, however many of the batch's queries share it, and a repeated
+    batch is served by the round memo without decoding again.  The decodes
+    are read off the slots each codec's decode call is handed, not off the
+    counters the decode path keeps itself."""
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
-    eng = QueryEngine(idx, cache_blocks=1 << 20).to_device()
-    eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
-    # cold eviction-free cache: every decode is a distinct hot (term, block),
-    # and the hot set is counted independently of the decode counters
-    hot = {k for k in eng.cache.keys() if k[1] >= 0}
-    decodes = (eng.dev_stats["worklist_decodes"]
-               + eng.dev_stats["fallback_decodes"])
-    assert decodes == len(hot)
-    assert eng.dev_stats["fallback_decodes"] == 0
-    assert eng.dev_stats["worklist_refs"] >= eng.dev_stats["worklist_decodes"]
-    # a second pass over the same batch is fully cache-served
-    with eng.metrics.scoped() as sample:
+    eng = QueryEngine(idx).to_device()
+    calls = []
+    for name, g in eng.arena._groups.items():
+        def counted(slots, _name=name, _decode=g.decode_rows):
+            calls.append([(_name, int(s)) for s in slots])
+            return _decode(slots)
+        g.decode_rows = counted
+    with eng.metrics.scoped() as first:
+        r0 = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
+    assert calls
+    for c in calls:                     # no slot twice inside one call
+        assert len(c) == len(set(c)), c
+    lanes = sum(len(c) for c in calls)
+    assert first.delta("worklist_decodes") == lanes
+    assert first.delta("blocks_device") == lanes
+    assert first.delta("blocks_host") == first.delta("fallback_decodes") == 0
+    # queries share blocks: more entries are served than blocks decoded
+    assert first.delta("rows_gathered") > lanes
+    assert first.delta("worklist_refs") >= lanes
+    n_calls = len(calls)
+    with eng.metrics.scoped() as again:
         r1 = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
-    assert sample.delta("worklist_decodes") == 0
-    r0 = QueryEngine(idx).execute(QueryBatch(QUERIES, mode="and"))
-    for a, b in zip(r0, r1):
-        np.testing.assert_array_equal(a, b)
+    assert again.delta("worklist_decodes") == 0 and len(calls) == n_calls
+    want = QueryEngine(idx).execute(QueryBatch(QUERIES, mode="and"))
+    for w, a, b in zip(want, r0, r1):
+        np.testing.assert_array_equal(w, a)
+        np.testing.assert_array_equal(w, b)
 
 
 def test_device_engine_eviction_pressure_stays_exact():
@@ -244,9 +262,14 @@ def test_device_engine_eviction_pressure_stays_exact():
     queries = [[0, 1], [1, 2], [2, 3], [0, 3], [1, 3], [0, 2], [0, 1, 2]]
     want = host.execute(QueryBatch(queries, mode="and"))
     got = tiny.execute(tiny.plan(QueryBatch(queries, mode="and")))
+    # the resident rounds decode into per-round matrices and leave the block
+    # cache alone; the host-candidate device loop goes through it and evicts
+    assert len(tiny.cache) == 0
+    legacy = tiny.and_many(queries)
     assert tiny.cache.evictions > 0
-    for a, b in zip(want, got):
+    for a, b, c in zip(want, got, legacy):
         np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
 
 
 def test_zero_posting_term_and_empty_results_on_device():
@@ -384,9 +407,12 @@ def test_exception_codecs_eviction_and_block_boundary_parity(name):
     tiny = QueryEngine(idx, cache_blocks=2, cache_score_terms=1).to_device()
     want = host.execute(QueryBatch(HQUERIES, mode="and"))
     got = tiny.execute(tiny.plan(QueryBatch(HQUERIES, mode="and")))
+    assert len(tiny.cache) == 0         # resident rounds skip the block cache
+    legacy = tiny.and_many(HQUERIES)
     assert tiny.cache.evictions > 0
-    for q, a, b in zip(HQUERIES, want, got):
+    for q, a, b, c in zip(HQUERIES, want, got, legacy):
         np.testing.assert_array_equal(a, b, err_msg=f"{name}/{q}")
+        np.testing.assert_array_equal(a, c, err_msg=f"{name}/{q}")
 
 
 def test_multi_round_device_and_is_resident_with_zero_cand_syncs():

@@ -14,6 +14,7 @@ import math
 import numpy as np
 import pytest
 
+from repro.index.device import _bucket
 from repro.index.engine import QueryBatch, QueryEngine
 from repro.index.invindex import InvertedIndex
 from repro.index.serve import (Request, ServeConfig, ServerStats, TraceRecord,
@@ -341,8 +342,15 @@ def test_round_spans_and_counters_on_the_and_path(fused):
     assert not any(b in ar.dense_slot for b in sparse)
     assert s.delta("decode_postings") == sum(
         idx.terms[t].blocks[bi][1].n for t, bi in sparse)
-    assert s.delta("rows_sliced") == len(sparse)
-    assert s.delta("rows_stacked") == len(sparse)
+    # the seed's four sparse entries reach the kernel by row index into one
+    # decoded matrix per codec (term 0 is a short list, the others are not):
+    # nothing is sliced or stacked one row at a time, and each matrix's
+    # bucket of 8 pads six lanes
+    assert s.delta("device_calls") == 2
+    assert s.delta("rows_sliced") == 0
+    assert s.delta("rows_stacked") == 0
+    assert s.delta("rows_gathered") == 4
+    assert s.delta("rows_padded") == 12
     rows = [sp for sp in seed_spans if sp.name == "round/rows"]
     assert [sp.args for sp in rows] == [{"entries": 4, "decoded": 3}]
     parents = _round_spans(seed_spans)
@@ -359,11 +367,16 @@ def test_round_spans_and_counters_on_the_and_path(fused):
     decoded = [sp for sp in spans if sp.name.startswith("decode/")]
     assert m.delta("decode_postings") == sum(sp.args["postings"]
                                              for sp in decoded) > 0
-    assert m.delta("rows_sliced") == sum(sp.args["decoded"] for sp in spans
-                                         if sp.name == "round/rows")
-    assert m.delta("rows_stacked") == sum(sp.args["rows"] for sp in spans
-                                          if sp.name == "round/stack")
+    assert m.delta("rows_sliced") == 0 and m.delta("rows_stacked") == 0
+    stacks = [sp for sp in spans if sp.name == "round/stack"]
+    assert m.delta("rows_gathered") == sum(sp.args["rows"] for sp in stacks)
     fused_spans = [sp for sp in decoded if sp.name == "decode/fused"]
+    # padding: the sparse sources' buckets, then each fused bit-width part
+    # padded to its own bucket
+    assert m.delta("rows_padded") == (
+        sum(sp.args["bucket"] - sp.args["rows"] for sp in stacks)
+        + sum(_bucket(sp.args["blocks"]) - sp.args["blocks"]
+              for sp in fused_spans))
     assert bool(fused_spans) == fused
     # fused rounds decode every entry, repeats included: (1, 0) twice and
     # (4, 0) in round 1, (4, 0) in round 2
