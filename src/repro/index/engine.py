@@ -473,6 +473,9 @@ _DEV_COUNTERS = (
     ("resident_rounds", "AND rounds run with candidates device-resident"),
     ("cand_syncs", "per-round candidate downloads (0 on resident paths)"),
     ("final_syncs", "end-of-batch result downloads (one per batch)"),
+    ("extract_words_nonzero", "nonzero bitmap words the final extraction "
+                              "expanded"),
+    ("extract_ids", "docids the final extraction returned"),
     ("score_rounds", "ranked accumulate rounds run device-resident"),
     ("score_syncs", "per-round score downloads (always 0 when resident)"),
     ("blocks_pruned", "ranked work-list entries dropped by block-max"),
@@ -501,6 +504,9 @@ class QueryEngine:
         # cand_syncs: per-round candidate downloads (legacy device loop only;
         #   the resident path never syncs between rounds)
         # final_syncs: end-of-batch result downloads (one per resident batch)
+        # extract_words_nonzero / extract_ids: the nonzero words the final
+        #   bitmap -> docid extraction expanded, and the ids it returned
+        #   (every final and per-shard download counts into both)
         # score_rounds / score_syncs: ranked accumulate rounds executed
         #   device-resident / per-round score downloads (always 0 on the
         #   resident ranked path — only the final candidate bitmap syncs)
@@ -1026,7 +1032,8 @@ class QueryEngine:
                                              qterms=qterms)
         self.metrics.inc("final_syncs")
         return intersect_rounds.extract_ids(np.asarray(bm)[:len(queries)],
-                                            self._cur().gen.n_docs)
+                                            self._cur().gen.n_docs,
+                                            self.metrics)
 
     def _and_bitmap_resident(self, queries: list,
                              terms: Mapping[int, TermCaps] | None = None,
@@ -1506,7 +1513,7 @@ class QueryEngine:
         # the single host copy: candidate bitmaps -> exact float rescore
         self.metrics.inc("final_syncs")
         cand = intersect_rounds.extract_ids(np.asarray(cand_bm)[:nq],
-                                            idx.n_docs)
+                                            idx.n_docs, self.metrics)
         return self._ranked_rescore(queries, cand, k, mode, known, ctx)
 
     def _ranked_params(self, queries: list, k: int, ctx: _ExecCtx):
@@ -1954,7 +1961,7 @@ class QueryEngine:
                                            jnp.asarray(margins), iq_dev)
                 self.metrics.inc("shard_final_syncs")
                 ids = intersect_rounds.extract_ids(np.asarray(bm)[:nq],
-                                                   hi - lo)
+                                                   hi - lo, self.metrics)
             shard_cands.append(ids)
             for i, a in enumerate(ids):
                 if len(a):

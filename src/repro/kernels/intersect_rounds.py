@@ -28,7 +28,10 @@ vectorized; this module is that pipeline's state + kernels:
     window, is ANDed word-parallel against the query's old-bitmap window and
     committed back — no unpack, no prefix-sum, no per-posting lanes at all.
   * ``extract_ids`` — the single final host copy: bitmap rows back to sorted
-    uint32 docid arrays, once per batch, after the last round.
+    uint32 docid arrays, once per batch, after the last round.  It expands
+    only the batch's nonzero words, in one vectorised numpy pass, so a
+    sparse answer costs its words and not the bits of ``n_docs``; a row
+    mostly of nonzero words unpacks whole, bound by its output either way.
 
 Correctness does not depend on block selection: decoding a superset of the
 blocks that could hold candidates is sound, because ids outside the current
@@ -165,11 +168,55 @@ def round_commit(bm_old, new, active):
 # --------------------------------------------------------------------------- #
 
 
-def extract_ids(bm_np: np.ndarray, n_docs: int) -> list:
-    """Bitmap rows -> sorted uint32 docid arrays (fresh, caller-owned)."""
+def extract_ids(bm_np: np.ndarray, n_docs: int, metrics=None) -> list:
+    """Bitmap rows -> sorted uint32 docid arrays (fresh, caller-owned).
+
+    The work is proportional to the batch's nonzero words: only those are
+    unpacked, in one vectorised pass over the whole ``(rows, words)``
+    matrix, and each set bit k of word w maps back to ``w << 5 | k``.  A
+    row whose nonzero words are more than half its words is output-bound
+    either way and unpacks whole, which is cheaper there than the gather.
+    Bits at ``n_docs`` and past it (row padding, or a shard's narrower
+    ``hi - lo``) are dropped.  The arrays are disjoint and writable; an
+    empty row gives an empty array.
+
+    ``metrics`` (an engine's registry) counts ``extract_words_nonzero``
+    (nonzero words of the rows below ``n_docs``) and ``extract_ids`` (ids
+    returned); the ``kernel/extract_ids`` span carries both as args.
+    """
     from repro.obs.trace import get_tracer
     with get_tracer().span("kernel/extract_ids", lane="device",
-                           rows=int(bm_np.shape[0]), n_docs=n_docs):
-        bits = np.unpackbits(np.ascontiguousarray(bm_np).view(np.uint8),
-                             axis=1, bitorder="little")[:, :n_docs]
-        return [np.flatnonzero(b).astype(np.uint32) for b in bits]
+                           rows=int(bm_np.shape[0]), n_docs=n_docs) as sp:
+        nw = -(-n_docs // 32)
+        bm = np.asarray(bm_np, np.uint32)[:, :nw]
+        rows = bm.shape[0]
+        r, w = np.divmod(np.flatnonzero(bm != 0), nw)
+        per_row = np.bincount(r, minlength=rows)
+        dense = per_row * 2 > nw
+        if dense.any():
+            keep = ~dense[r]
+            r, w = r[keep], w[keep]
+        vals = bm[r, w]
+        if n_docs % 32:
+            vals[w == nw - 1] &= np.uint32((1 << (n_docs % 32)) - 1)
+        pc = np.bitwise_count(vals)
+        bits = np.unpackbits(vals.view(np.uint8), bitorder="little")
+        pos = np.flatnonzero(bits.view(np.bool_))
+        # pos indexes the bits of the expanded words; shift each word's
+        # bits from its slot j to its place w in the row
+        pos += np.repeat((w - np.arange(len(w))) << 5, pc)
+        ids = pos.astype(np.uint32)
+        counts = np.bincount(r, weights=pc, minlength=rows).astype(np.int64)
+        out = np.split(ids, np.cumsum(counts)[:-1]) if rows else []
+        for i in np.flatnonzero(dense):
+            row = np.ascontiguousarray(bm[i]).view(np.uint8)
+            bits = np.unpackbits(row, bitorder="little")[:n_docs]
+            out[i] = np.flatnonzero(bits.view(np.bool_)).astype(np.uint32)
+        nz = int(per_row.sum())
+        n_ids = sum(map(len, out))
+        if sp is not None:
+            sp.args.update(words_nonzero=nz, ids=n_ids)
+        if metrics is not None:
+            metrics.inc("extract_words_nonzero", nz)
+            metrics.inc("extract_ids", n_ids)
+        return out

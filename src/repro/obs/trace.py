@@ -69,7 +69,8 @@ Span taxonomy (the names emitted across the stack):
                        args: blocks, postings)
 ``decode/fused``       one fused tile decode per bit-width bucket (args: bw,
                        blocks, postings)
-``kernel/extract_ids`` final bitmap -> sorted docid extraction
+``kernel/extract_ids`` final bitmap -> sorted docid extraction, nonzero
+                       words only (args: rows, n_docs, words_nonzero, ids)
 ``kernel/topk``        k-th threshold / top-k stats reduction
 ``jax/trace``          JAX tracing a function (arg: fun_name)
 ``jax/lower``          lowering a jaxpr to an MLIR module (arg: fun_name)
