@@ -2,7 +2,8 @@
 the compressed inverted index (AND + OR BM25 top-10, warm cache), plus the
 batched-engine mode: queries/sec at batch sizes {1, 16, 256} for the host
 numpy path AND the device-arena path (``QueryEngine.to_device()``), against
-the seed per-query ``np.isin`` loop (``and_query_ref``).
+the seed per-query ``np.isin`` loop (``and_query_ref``, kept here as the
+scalar baseline).
 
 The batched run also records the device work-list discipline — raw (term,
 block) references per batch vs deduped decodes actually issued — plus the
@@ -11,18 +12,19 @@ block-max top-k: qps per placement, ``blocks_pruned`` / ``blocks_scored``,
 and per-round host syncs, which must be zero on the resident ranked path) —
 and writes the whole thing to ``BENCH_query.json`` (override the path with
 the ``BENCH_QUERY_JSON`` env var) so CI can track the perf trajectory as an
-artifact.  Two more report sections feed the serving stack: ``mode_qps``
-(host-vs-device qps per batch size, per query MODE, with the placement
-pinned — ``CrossoverTable.from_bench`` derives one demotion cell per mode
-from these, so ranked modes demote independently of plain AND) and
-``sharded`` (doc-range sharded serving scaling curves over ``--shards``
-counts: qps per mode, plus the collective accounting — merge syncs and
-collective bytes per ranked batch, and the cross-shard round syncs, which
-must be ZERO: doc-wise partitioning keeps every round shard-local).  On the CPU/interpret CI backend the device path's wall-clock is
-not the headline (jitted gathers vs raw numpy); the tracked guarantee there
-is ``decodes_per_hot_block == 1.0``: no (term, block) repeats inside one
-round's decode call, read off the calls themselves, in O(rounds) device
-calls instead of O(blocks) Python iterations.  A block that a later round
+artifact.  Two more report sections: ``mode_qps`` (host-vs-device qps per
+batch size, per query MODE, with the placement pinned; the engine's planner
+reads no benchmark file, so these curves only inform a change of
+``engine.HOST_BATCH_MAX``) and ``sharded`` (doc-range sharded serving
+scaling curves over ``--shards`` counts: qps per mode, plus the collective
+accounting — merge syncs and collective bytes per ranked batch, and the
+cross-shard round syncs, which must be ZERO: doc-wise partitioning keeps
+every round shard-local).  On the CPU/interpret CI backend the device
+path's wall-clock is not the headline (jitted gathers vs raw numpy); the
+tracked guarantee there is ``decodes_per_hot_block == 1.0``: no (term,
+block) repeats inside one round's decode call, read off the calls
+themselves, in O(rounds) device calls instead of O(blocks) Python
+iterations.  A block that a later round
 of the same batch needs again is decoded again (no cache spans rounds);
 ``cross_round_redecodes`` counts those.
 
@@ -59,7 +61,6 @@ import jax
 from repro.data import synth
 from repro.index.invindex import InvertedIndex
 from repro.index.engine import QueryBatch, QueryEngine
-from repro.index import query as Q
 from .util import emit, timeit
 
 CODECS = ["group_simple", "group_scheme_8-IU", "group_pfd", "bp128",
@@ -78,6 +79,28 @@ def git_sha() -> str:
         ).stdout.strip() or "unknown"
     except (OSError, subprocess.SubprocessError):
         return "unknown"
+
+
+def one_shot(idx: InvertedIndex, terms: list, mode: str, k: int = 10):
+    """One query through a fresh uncached engine: the per-query cost with no
+    decoded block or score vector carried over from an earlier query."""
+    eng = QueryEngine(idx, cache_blocks=0, cache_score_terms=0)
+    return eng.execute(eng.plan(QueryBatch([list(terms)], mode=mode, k=k)))[0]
+
+
+def and_query_ref(idx: InvertedIndex, terms: list) -> np.ndarray:
+    """Seed baseline: full decode per term + scalar ``np.isin`` intersection."""
+    terms = sorted((t for t in terms if t in idx.terms),
+                   key=lambda t: idx.terms[t].df)
+    if not terms:
+        return np.zeros(0, np.uint32)
+    ids, _ = idx.decode_term(terms[0])
+    for t in terms[1:]:
+        if len(ids) == 0:
+            break
+        cand, _ = idx.decode_term(t, min_docid=int(ids[0]))
+        ids = ids[np.isin(ids, cand, assume_unique=True)]
+    return ids
 
 
 def make_queries(postings: dict, n_queries: int, seed: int = 3) -> list:
@@ -109,11 +132,11 @@ def run(n_queries: int = 100, dataset: str = "gov2", seed: int = 0,
 
         def run_and():
             for q in queries:
-                Q.and_query_scored(idx, q, k=10)
+                one_shot(idx, q, "and_scored")
 
         def run_or():
             for q in queries[: n_queries // 4]:
-                Q.or_query(idx, q, k=10)
+                one_shot(idx, q, "or")
 
         t = timeit(run_and, repeats=3, warmup=1)
         emit(f"query/{dataset}/{name}/and", t * 1e6, f"{n_queries / t:.1f}qps")
@@ -142,7 +165,7 @@ def run_batched(dataset: str = "gov2", codec: str = "group_simple",
 
     def seed_loop():
         for q in queries:
-            Q.and_query_ref(idx, q)
+            and_query_ref(idx, q)
 
     t_ref = timeit(seed_loop, repeats=3, warmup=1)
     emit(f"query/{dataset}/{codec}/and_seed_loop", t_ref * 1e6,
@@ -256,11 +279,10 @@ def run_batched(dataset: str = "gov2", codec: str = "group_simple",
              f"{entry['blocks_pruned']}pruned,{entry['blocks_scored']}scored,"
              f"{entry['host_syncs_per_query']:.3f}syncs_per_query")
 
-    # per-mode placement crossover curves, placement PINNED (the auto-placed
-    # curves above fold the planner's own demotion into the measurement):
-    # CrossoverTable.from_bench derives one demotion cell per mode from
-    # "mode_qps", so ranked modes — which amortize score uploads and the
-    # final-merge sync over the batch — demote independently of plain AND
+    # per-mode host-vs-device curves, placement PINNED (the auto-placed
+    # curves above fold the planner's own demotion into the measurement);
+    # ranked modes amortize score uploads and the final-merge sync over the
+    # batch, so their curves can cross earlier than plain AND's
     report["mode_qps"] = {"and": {"host": dict(report["host_qps"]),
                                   "device": dict(report["device_qps"])}}
     for mode in ("or", "and_scored"):

@@ -23,7 +23,6 @@ from repro.core.dgap import dgap_encode_np
 from repro.data import synth
 from repro.index.invindex import InvertedIndex
 from repro.index.engine import QueryBatch, QueryEngine
-from repro.index import query as Q
 from repro.kernels import ops
 
 
@@ -62,7 +61,8 @@ def main() -> None:
     # compressed inverted index + queries
     doclen, postings = synth.make_corpus("gov2")
     idx = InvertedIndex.build(doclen, postings, codec="group_simple")
-    hits = Q.and_query_scored(idx, [1, 5], k=5)
+    one = QueryEngine(idx)
+    hits = one.execute(one.plan(QueryBatch([[1, 5]], mode="and_scored", k=5)))[0]
     print(f"\nindex: {idx.size_bytes()/1e6:.2f} MB (group_simple); "
           f"AND(1,5) top hit doc={hits[0][0]} bm25={hits[0][1]:.2f}")
 

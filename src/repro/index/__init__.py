@@ -6,16 +6,11 @@ Layers (bottom up):
     with any codec from ``repro.core.codec.REGISTRY``; lists shorter than 64
     use the Stream VByte short-list fast path.  Every 512-posting block keeps
     its first docid as a skip pointer and decodes independently.
-  * ``query`` — stateless one-shot AND/OR/BM25 helpers (deprecation shims
-    over single-query plans).
   * ``engine`` — the batched query engine: ``engine.plan(batch)`` resolves a
     ``QueryBatch`` into a typed ``ExecutionPlan`` — placement (host / device
-    / fused, with small batches auto-placed on the host per the measured
-    ``CrossoverTable`` from the committed ``BENCH_query.json``, falling back
-    to the static ``HOST_BATCH_MAX`` rule when the baseline is absent or
-    shows no true host->device crossing; the deciding source is recorded in
-    the plan's ``note``) plus every referenced term's
-    codec capabilities, read once from the registry — and
+    / fused, with batches of at most ``HOST_BATCH_MAX`` queries auto-placed
+    on the host, recorded in the plan's ``note``) plus every referenced
+    term's codec capabilities, read once from the registry — and
     ``engine.execute(plan)`` runs it: AND queries fuse skip-table block
     pruning with the vectorized intersection kernels
     (``repro.kernels.intersect``), and hot decoded blocks live in an LRU
@@ -78,8 +73,8 @@ Q16.16 deflation ``iq = floor(2**16 / Rmax)`` applied to every threshold
 comparison keeps the generation-time upper bounds sound against live scores
 (the full derivation is the re-arm note in ``index/scores.py``; theta0 is
 re-derived from the tombstone-filtered top-code tables via
-``ScoreArena.theta0_live``, and ``BENCH_mutation.json`` tracks
-``ranked_tomb_1pct.blocks_pruned > 0`` as the CI guarantee);
+``ScoreArena.theta0_live``, and ``benchmarks/bench_query.py --mutate``
+tracks ``ranked_tomb_1pct.blocks_pruned > 0`` as the CI guarantee);
 ``compact()`` fully re-arms pruning: it merge-sorts generation-minus-tombstones
 with the delta per term, re-encodes through the codec registry into
 generation ``gid + 1``, and swaps it in atomically — in-flight plans keep
@@ -88,8 +83,9 @@ keyed by gid / epoch, so nothing stale survives the swap).  The governing
 **rebuild-parity contract**: at any epoch, every mode on every placement is
 bitwise identical to ``InvertedIndex.build(doclen_now(), live_postings)``
 (enforced by the stateful differential harness in ``tests/test_mutation.py``
-and the segment-consistency registry lint; ``BENCH_mutation.json`` tracks
-qps per tombstone density, compaction pause, and delta-scan overhead).
+and the segment-consistency registry lint; ``benchmarks/bench_query.py
+--mutate`` tracks qps per tombstone density, compaction pause, and
+delta-scan overhead).
 
 Ranked retrieval (score columns, quantization contract, block-max pruning):
 ``ScoreArena`` quantizes with a single global scale ``delta = max impact /
@@ -114,9 +110,10 @@ round's kernels re-test against every entry's staged upper bound so the
 work-list compacts itself with zero per-round host syncs.  Pruned blocks
 only lose contributions of docs provably outside the true top-k.
 ``and_scored`` reuses the AND machinery — the intersection bitmap gates the
-score scatter on device and is never downloaded.  ``BENCH_query.json``
-tracks ``blocks_pruned`` / ``blocks_scored`` / ``blocks_dense`` and
-per-round host syncs (zero on the resident ranked path) per mode.
+score scatter on device and is never downloaded.
+``benchmarks/bench_query.py`` tracks ``blocks_pruned`` / ``blocks_scored``
+/ ``blocks_dense`` and per-round host syncs (zero on the resident ranked
+path) per mode.
 
 Density-adaptive bitmap blocks (word-parallel dense postings):
 posting blocks whose docids are dense — average gap (span / count) at most
@@ -146,8 +143,8 @@ declares ``bitmap_words`` / ``is_bitmap`` alongside the ordinary two-column
     (``intersect_rounds.dense_round_accumulate``,
     ``topk.dense_score_round``) while sparse blocks of the same query take
     the decode path in the same round — exact composition, since each block
-    owns disjoint docids (``BENCH_query.json`` counts the dense-served
-    entries as ``blocks_dense``).
+    owns disjoint docids (``benchmarks/bench_query.py`` counts the
+    dense-served entries as ``blocks_dense``).
 
 Mixed dense/sparse lists therefore fall out of the registry machinery with
 zero engine special cases, and a new density policy is one codec swap.
@@ -178,9 +175,9 @@ execute <= done — monotonicity is registry-linted) and every batch a
 replayable ``BatchRecord`` in ``ServerStats``; ``snapshot()`` derives
 p50/p99/p999 latency, goodput, shed rate, and the per-placement batch-size
 histogram.  ``benchmarks/bench_serving.py`` drives seeded Poisson and
-bursty (Gamma) open-loop streams through all of this into
-``BENCH_serving.json`` (committed baseline at the repo root; the smoke run
-asserts zero shed under Poisson and bitwise oracle parity), and
+bursty (Gamma) open-loop streams through all of this into a committed
+baseline at the repo root (the smoke run asserts zero shed under Poisson
+and bitwise oracle parity), and
 ``python -m repro.launch.serve --index --smoke`` is the end-to-end entry
 point.
 
@@ -215,9 +212,9 @@ boundaries (``intersect_rounds.pack_live_words_range``); mutation epochs pin
 per-shard generation sets atomically — the shard set is cached ON the
 generation, so a racing ``compact()`` builds a fresh set for gid+1 while
 in-flight plans keep serving the old one; ``plan.note`` records the shard
-topology.  ``BENCH_query.json`` tracks the scaling curves per shard count
-(qps per mode, merge syncs and collective bytes per ranked batch, and
-cross-shard round syncs — ZERO by construction).
+topology.  ``benchmarks/bench_query.py`` tracks the scaling curves per
+shard count (qps per mode, merge syncs and collective bytes per ranked
+batch, and cross-shard round syncs — ZERO by construction).
 
 Observability (spans, typed metrics, the perf-regression gate): the
 ``repro.obs`` package is the one instrumentation layer over everything
@@ -278,11 +275,12 @@ above.
     nearest-rank rule (``repro.obs.metrics.nearest_rank``) so tiny-n
     snapshots are reproducible observed values, monotone in q.
   * **Perf-regression gate** (``repro.obs.regress`` +
-    ``tools/bench_gate.py``): the committed ``BENCH_query/mutation/
-    serving.json`` baselines are enforced contracts — CI regenerates them
-    at the smoke workload, then every shared ``*qps*`` leaf must hold
-    ``fresh >= baseline * min_ratio`` (floors in ``BENCH_tolerances.json``,
-    default 0.55) and the deterministic invariants are re-checked hard:
+    ``tools/bench_gate.py``): the query, mutation and serving baselines
+    that ``benchmarks/`` commits at the repo root are enforced contracts —
+    CI regenerates them at the smoke workload, then every shared ``*qps*``
+    leaf must hold ``fresh >= baseline * min_ratio`` (floors in the
+    committed tolerances file, default 0.55) and the deterministic
+    invariants are re-checked hard:
     ``cand_syncs == 0`` / ``score_syncs == 0`` on the resident paths,
     ``blocks_pruned > 0`` under 1% tombstones, decode dedup <= 1 per hot
     block, zero cross-shard round syncs, zero Poisson shed, bitwise serving
@@ -321,19 +319,11 @@ heavy-tailed probe through every arena codec and fails any that stores
 exceptions without declaring such a column, so a forgotten column is caught
 in CI rather than as silently-unpatched decodes.
 
-Migration note (deprecated v1 surface, kept as delegating shims):
-
-  * ``engine.execute(QueryBatch(...))`` -> ``engine.execute(engine.plan(
-    QueryBatch(...)))``; results are bit-identical.
-  * ``QueryEngine(idx, device=True, fused=True)`` -> ``QueryEngine(idx)
-    .to_device(fused=True)`` (the constructor flags warn ``DeprecationWarning``).
-  * ``repro.index.query.and_query/or_query/and_query_scored`` -> build an
-    engine and execute plans; the helpers now delegate to single-query plans.
-  * ``CodecSpec`` and its ``decode`` / ``jax_args`` / ``decode_jax_scalar`` /
-    ``decode_jax_vec`` attributes -> ``Codec`` with ``decode_np`` and the
-    ``jax`` / ``arena`` capability objects (old attributes remain as
-    read-only aliases).
+Migration note (v1 codec surface): ``CodecSpec`` and its ``decode`` /
+``jax_args`` / ``decode_jax_scalar`` / ``decode_jax_vec`` attributes ->
+``Codec`` with ``decode_np`` and the ``jax`` / ``arena`` capability objects
+(old attributes remain as read-only aliases).
 """
 
-from . import (device, engine, invindex, query, scores, serve,  # noqa: F401
+from . import (device, engine, invindex, scores, serve,  # noqa: F401
                shards)

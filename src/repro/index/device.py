@@ -31,12 +31,12 @@ On top sit two batched execution paths:
     power-of-two buckets so jit variants stay bounded.  Blocks whose codec
     declares no arena capability (and empty blocks) fall back to the numpy
     decoder per block, preserving exact results for every registered codec.
-  * ``fused_and`` / ``fused_round`` — the ``kernels/decode_fused`` Pallas
-    path: block gaps re-packed into fixed (rows, 128) tiles at the block's
-    own bit width rounded up to ``decode_fused.BW_BUCKETS``, decoded in
-    VMEM with the next work-list block's DMA double-buffered via
-    scalar-prefetched work-list indices; the candidate probe runs in XLA on
-    the decoded rows.
+  * ``fused_round`` / ``fused_round_scored`` — the ``kernels/decode_fused``
+    Pallas path: block gaps re-packed into fixed (rows, 128) tiles at the
+    block's own bit width rounded up to ``decode_fused.BW_BUCKETS``, decoded
+    in VMEM with the next work-list block's DMA double-buffered via
+    scalar-prefetched work-list indices; the resident rounds probe the
+    decoded rows in XLA.
 
 ``stats`` is a read-only view of the arena's counters (``ARENA_COUNTERS``:
 device calls and blocks decoded per path, postings decoded on the device,
@@ -72,7 +72,6 @@ from repro.obs.metrics import DevStatsView, MetricsRegistry
 from repro.obs.trace import get_tracer
 from repro.kernels import decode_fused, intersect_rounds, topk
 from repro.kernels.bitpack import LANES
-from repro.kernels.intersect import bitmap_build_np
 
 _MIN_WORKLIST = 8             # smallest jit bucket
 _DECODE_CHUNK = 256           # lanes per step of a longer work-list decode
@@ -228,11 +227,11 @@ class DeviceArena:
 
     Build once via ``DeviceArena.from_index(idx)`` (or ``idx.to_device()`` /
     ``QueryEngine.to_device()``); decode any work-list of (term, block, field)
-    entries with ``decode_blocks`` (field 0 = docids, 1 = TFs), or intersect a
-    term's skip-selected blocks against a candidate set on device with
-    ``fused_and``.  Coverage is capability-driven: every codec declaring an
-    ``ArenaLayout`` in the registry decodes natively; the rest fall back to
-    the numpy oracle per block.
+    entries with ``decode_blocks`` (field 0 = docids, 1 = TFs), or a resident
+    round's work-list with ``decode_round`` / ``fused_round``.  Coverage is
+    capability-driven: every codec declaring an ``ArenaLayout`` in the
+    registry decodes natively; the rest fall back to the numpy oracle per
+    block.
     """
 
     # kept as a class attribute for callers that sized things off the arena;
@@ -461,47 +460,11 @@ class DeviceArena:
             m.inc("rows_padded", w - len(pos))
         return sources, decoded
 
-    # ---- fused decode + AND ------------------------------------------------ #
+    # ---- fused tile decode ------------------------------------------------ #
 
     def has_fused(self, t, blocks) -> bool:
         return (self._pk is not None
                 and all((t, int(bi)) in self._pk_slot for bi in blocks))
-
-    def fused_and(self, t, blocks, cand: np.ndarray) -> np.ndarray:
-        """Intersect sorted candidates with term t's skip-selected blocks
-        through the fused tile decode + probe (one call per bit-width bucket
-        present in the work-list); exact ``intersect_sorted`` parity."""
-        k = len(blocks)
-        if k == 0 or len(cand) == 0:
-            return np.zeros(0, np.uint32)
-        groups: dict = {}
-        for j, bi in enumerate(blocks):
-            bw, row = self._pk_slot[(t, int(bi))]
-            groups.setdefault(bw, []).append((j, row))
-        words = intersect_rounds.bitmap_geometry(self.n_docs)[0]
-        cand_words = jnp.asarray(bitmap_build_np(cand, 0, words * 32))
-        parts: list = [None] * k
-        for bw, items in groups.items():
-            pk = self._pk[bw]
-            rows = np.asarray([r for _, r in items], np.int64)
-            slots = rows.astype(np.int32)
-            firsts = pk["first"][rows]
-            ns = pk["n"][rows]
-            w = _bucket(len(items))
-            if len(items) < w:   # pad: repeated entries with n=0 hit nothing
-                slots = np.concatenate([slots, np.repeat(slots[:1], w - len(items))])
-                firsts = np.concatenate([firsts, np.repeat(firsts[:1], w - len(items))])
-                ns = np.concatenate([ns, np.zeros(w - len(items), np.int32)])
-            ids, hits = decode_fused.fused_decode_and(
-                pk["tiles"], jnp.asarray(slots), jnp.asarray(firsts),
-                jnp.asarray(ns), cand_words, bw=bw)
-            ids = np.asarray(ids)
-            hits = np.asarray(hits).astype(bool)
-            for g, (j, _) in enumerate(items):
-                parts[j] = ids[g][hits[g]]
-            self.metrics.inc("fused_calls")
-            self.metrics.inc("fused_blocks", len(items))
-        return np.concatenate(parts)
 
     def _fused_rounds(self, pairs: list, with_scores: bool, ubs=None):
         """One ``decode_fused.decode_tiles`` call per bit-width bucket

@@ -1,7 +1,7 @@
 """Batched query engine: fused decode-and-intersect over the compressed index.
 
-The seed path (`repro.index.query`) decoded every term's full posting list per
-query and intersected with ``np.isin``.  This engine makes the serving path
+The seed path decoded every term's full posting list per query and
+intersected with ``np.isin``.  This engine makes the serving path
 hardware-speed along three axes:
 
   1. **Vectorized intersection** — per-block candidates are intersected with
@@ -69,10 +69,10 @@ Typical use::
     engine.to_device()                       # device arenas from here on
     results = engine.execute(engine.plan(QueryBatch([[1, 5]], mode="and")))
 
-Deprecated shims (see the migration note in ``repro/index/__init__.py``):
-``execute(QueryBatch)`` plans implicitly; ``QueryEngine(idx, device=True,
-fused=True)`` maps to ``to_device(fused=True)``; the one-shot helpers in
-``repro.index.query`` delegate to plans.
+``plan`` places a batch by its size alone: at most ``HOST_BATCH_MAX``
+queries on the host, larger batches on the engine's arenas.  There is one
+AND path per placement: the host placement intersects in numpy
+(``and_query``), and the device / fused placements run the resident rounds.
 """
 
 from __future__ import annotations
@@ -81,9 +81,6 @@ import contextlib
 import dataclasses
 import difflib
 import itertools
-import json
-import os
-import warnings
 from collections import OrderedDict
 from typing import Mapping, Optional
 
@@ -99,137 +96,15 @@ from .device import ARENA_COUNTERS, _bucket   # one jit-bucket policy
 from .invindex import InvertedIndex
 from .scores import B, K1, bm25_scores, topk_select  # noqa: F401  (B/K1 re-export)
 
-# plan-time auto-placement, static fallback: below this batch size the host
-# numpy path beats the device round machinery on every backend measured so
-# far, so tiny batches are planned onto the host even when arenas exist.
-# When a committed BENCH_query.json baseline is present, ``plan()`` instead
-# derives a :class:`CrossoverTable` from its measured host/device qps curves
-# and only falls back to this constant when the curves show no true
-# host->device crossing (see ``CrossoverTable.from_bench``).
+# plan-time auto-placement: a batch of at most this many queries runs on the
+# host even when arenas exist, and every larger batch runs the resident
+# rounds.  A round pays fixed costs (work-list staging, one launch per
+# source, the final bitmap copy) that one query cannot amortize, while the
+# host numpy path pays only for the blocks it touches.  Measured on the CPU
+# only (``benchmarks/bench_query.py``: the host path won at every batch
+# size, in every mode); where a TPU's crossing lies is not measured.
 HOST_BATCH_MAX = 1
 
-
-@dataclasses.dataclass(frozen=True)
-class CrossoverTable:
-    """Host-vs-device placement crossover derived from a measured
-    ``BENCH_query.json`` baseline.
-
-    ``host_batch_max`` is the demotion threshold ``plan()`` uses: batches of
-    at most this many queries are auto-placed on the host.  It is derived
-    conservatively — the largest measured batch size where the host wins
-    (host_qps >= device_qps) AND the device wins at *every* larger measured
-    size.  That second clause matters: a backend where the host wins at the
-    largest measured size (true of CPU-emulated device backends) has no
-    real crossing, and extrapolating one would demote production-sized
-    batches off the arenas.  In that case ``host_batch_max`` is None and
-    ``plan()`` falls back to the static ``HOST_BATCH_MAX`` rule.  A backend
-    where the device wins everywhere yields 0 (never demote).
-
-    ``mode_cuts`` refines the single cell per query mode: a baseline whose
-    report carries per-mode qps curves (``mode_qps``: mode -> {"host"/
-    "device": {batch: qps}}) yields one cell per measured mode, derived with
-    the same conservative rule.  Ranked modes amortize quantized-score
-    uploads and the final-merge sync over the batch, so they typically cross
-    to the device EARLIER than plain AND — one blended cell would demote
-    ranked batches the device already wins.  ``cut_for(mode)`` resolves the
-    cell ``plan()`` applies: the mode's own cell when measured (even a
-    no-crossing None — then the static rule decides), else the blended
-    ``host_batch_max``."""
-    host_batch_max: Optional[int]
-    sizes: tuple = ()
-    source: str = "BENCH_query.json"
-    mode_cuts: tuple = ()       # ((mode, cut_or_None), ...) measured cells
-
-    def cut_for(self, mode: str) -> Optional[int]:
-        """The demotion threshold for one query mode (see class docstring)."""
-        for m, c in self.mode_cuts:
-            if m == mode:
-                return c
-        return self.host_batch_max
-
-    @staticmethod
-    def _derive(host: Mapping, dev: Mapping):
-        """The conservative crossover rule over one pair of qps curves:
-        (cut, common sizes) — cut None when there is no true crossing."""
-        sizes = sorted(set(host) & set(dev))
-        if not sizes:
-            return None, ()
-        if all(dev[b] > host[b] for b in sizes):
-            return 0, tuple(sizes)
-        cut = None
-        for b in sizes:
-            larger = [s for s in sizes if s > b]
-            if (host[b] >= dev[b] and larger
-                    and all(dev[s] > host[s] for s in larger)):
-                cut = b
-        return cut, tuple(sizes)
-
-    @classmethod
-    def from_bench(cls, report: Mapping, source: str = "BENCH_query.json"
-                   ) -> "CrossoverTable":
-        host = {int(b): float(q)
-                for b, q in (report.get("host_qps") or {}).items()}
-        dev = {int(b): float(q)
-               for b, q in (report.get("device_qps") or {}).items()}
-        cut, sizes = cls._derive(host, dev)
-        mode_cuts = []
-        for m in sorted(report.get("mode_qps") or {}):
-            curves = report["mode_qps"][m] or {}
-            mh = {int(b): float(q)
-                  for b, q in (curves.get("host") or {}).items()}
-            md = {int(b): float(q)
-                  for b, q in (curves.get("device") or {}).items()}
-            mc, msz = cls._derive(mh, md)
-            if msz:
-                mode_cuts.append((m, mc))
-        return cls(cut, sizes, source, tuple(mode_cuts))
-
-
-def _repo_root() -> str:
-    here = os.path.abspath(__file__)            # src/repro/index/engine.py
-    for _ in range(4):
-        here = os.path.dirname(here)
-    return here
-
-
-def _load_crossover() -> Optional[CrossoverTable]:
-    """The crossover table from the committed benchmark baseline
-    (``BENCH_QUERY_JSON`` env override, else ``BENCH_query.json`` at the
-    repo root), or None when the file is absent/unreadable or was measured
-    on another backend than the one running (a CPU curve says nothing about
-    where a TPU batch should go) — ``plan()`` then applies the static
-    ``HOST_BATCH_MAX`` rule."""
-    path = (os.environ.get("BENCH_QUERY_JSON")
-            or os.path.join(_repo_root(), "BENCH_query.json"))
-    try:
-        with open(path) as f:
-            report = json.load(f)
-        if report.get("backend") != jax.default_backend():
-            return None
-        return CrossoverTable.from_bench(report, source=os.path.basename(path))
-    except (OSError, ValueError, TypeError, AttributeError):
-        return None
-
-
-_CROSSOVER_UNSET = object()
-_crossover = _CROSSOVER_UNSET
-
-
-def get_crossover() -> Optional[CrossoverTable]:
-    """The cached placement crossover table (loaded once per process)."""
-    global _crossover
-    if _crossover is _CROSSOVER_UNSET:
-        _crossover = _load_crossover()
-    return _crossover
-
-
-def set_crossover(table=_CROSSOVER_UNSET) -> None:
-    """Override the cached crossover table.  Pass a :class:`CrossoverTable`
-    to force one, ``None`` to simulate an absent baseline (static-rule
-    fallback), or no argument to drop the override and reload from disk on
-    next use.  Test hook — production code never calls this."""
-    global _crossover
-    _crossover = table
 
 _EMPTY_U32 = np.zeros(0, np.uint32)
 _EMPTY_U32.setflags(write=False)
@@ -471,7 +346,8 @@ _DEV_COUNTERS = (
     ("worklist_decodes", "deduped batched arena decodes actually issued"),
     ("fallback_decodes", "per-block arena decodes outside the work-list"),
     ("resident_rounds", "AND rounds run with candidates device-resident"),
-    ("cand_syncs", "per-round candidate downloads (0 on resident paths)"),
+    ("cand_syncs", "per-round candidate downloads: always 0, kept for the "
+                   "CPU benchmark gate"),
     ("final_syncs", "end-of-batch result downloads (one per batch)"),
     ("extract_words_nonzero", "nonzero bitmap words the final extraction "
                               "expanded"),
@@ -491,18 +367,18 @@ _ENGINE_SEQ = itertools.count()
 
 class QueryEngine:
     def __init__(self, idx: InvertedIndex, cache_blocks: int = 4096,
-                 cache_score_terms: int = 512, device: bool = False,
-                 fused: bool = False):
+                 cache_score_terms: int = 512):
         self.idx = idx
         self.cache = BlockCache(cache_blocks)
         self.score_cache = BlockCache(cache_score_terms)
         self.arena = None
-        self._fused = fused
+        self._fused = False
         self._ctx = None           # pinned ctx while executing a plan
         self._ctx_cache = None     # (epoch, _ExecCtx) for the live handle
         # resident_rounds: AND rounds executed with candidates device-resident
-        # cand_syncs: per-round candidate downloads (legacy device loop only;
-        #   the resident path never syncs between rounds)
+        # cand_syncs: never incremented (no AND path downloads candidates
+        #   between rounds); registered only for the CPU benchmark gate
+        #   (``repro.obs.regress``), which still reads it
         # final_syncs: end-of-batch result downloads (one per resident batch)
         # extract_words_nonzero / extract_ids: the nonzero words the final
         #   bitmap -> docid extraction expanded, and the ids it returned
@@ -546,15 +422,6 @@ class QueryEngine:
         # matrices and index vectors / score rows / dense windows),
         # immutable per generation; see _round_memo
         self._round_cache: OrderedDict = OrderedDict()
-        if device or fused:
-            # deprecated: construct with defaults and call to_device() instead
-            warnings.warn(
-                "QueryEngine(device=..., fused=...) is deprecated; use "
-                "QueryEngine(idx).to_device(fused=...) and execute plans "
-                "(engine.execute(engine.plan(batch)))",
-                DeprecationWarning, stacklevel=2)
-        if device:
-            self.to_device(fused=fused)
 
     # ---- mutation-epoch resolution ------------------------------------------ #
 
@@ -779,101 +646,24 @@ class QueryEngine:
             ids, tfs = ids[order], tfs[order]
         return ids, tfs
 
-    # ---- fused decode-and-intersect ---------------------------------------- #
+    # ---- host decode-and-intersect ----------------------------------------- #
 
-    def _block_plan(self, t: int, cand: np.ndarray):
-        """Skip-table pruning: candidate cut points per block of term t and
-        the indices of blocks whose docid range contains a candidate."""
+    def _intersect_term(self, t: int, cand: np.ndarray) -> np.ndarray:
+        """Intersect sorted candidates with term t, decoding only the blocks
+        whose docid range [first_i, first_{i+1}) contains a candidate (the
+        skip table's first docids cut the candidates per block)."""
         gen = self._cur().gen
         firsts = gen.block_firsts(t).astype(cand.dtype)  # avoid a cast copy
         cut = np.empty(len(firsts) + 1, np.int64)
         cut[:-1] = np.searchsorted(cand, firsts)
         cut[-1] = len(cand)
-        return cut, np.flatnonzero(cut[1:] > cut[:-1])
-
-    def _term_fused(self, t: int, sel) -> bool:
-        """Fallback capability probe for un-planned calls (``and_query`` and
-        friends); plans resolve this once per term instead."""
-        return (self._fused and self.arena is not None
-                and self.arena.has_fused(t, sel))
-
-    def _intersect_plan(self, t: int, cut: np.ndarray, sel: np.ndarray,
-                        cand: np.ndarray, fused: bool | None = None) -> np.ndarray:
+        sel = np.flatnonzero(cut[1:] > cut[:-1])
         if len(sel) == 0:
             return np.zeros(0, np.uint32)
-        if self._term_fused(t, sel) if fused is None else fused:
-            return self.arena.fused_and(t, sel, cand)
-        out = [intersect.intersect_sorted(self.decode_block_ids(t, int(bi)),
-                                          cand[cut[bi]:cut[bi + 1]])
-               for bi in sel]
-        return np.concatenate(out)
-
-    def _intersect_term(self, t: int, cand: np.ndarray) -> np.ndarray:
-        """Intersect sorted candidates with term t, decoding only the blocks
-        whose docid range [first_i, first_{i+1}) contains a candidate."""
-        cut, sel = self._block_plan(t, cand)
-        return self._intersect_plan(t, cut, sel, cand)
-
-    def and_many(self, queries: list,
-                 terms: Mapping[int, TermCaps] | None = None) -> list:
-        """AND all queries together, round-batched for the device arenas —
-        the legacy loop that syncs every query's candidates to the host
-        between rounds (planned execution now runs the device-resident
-        ``_and_many_resident`` instead; this stays for direct callers and as
-        the host-candidate reference).  Serves the current generation only —
-        planned execution layers tombstones and the delta on top.
-
-        Round r intersects every still-active query with its (r+1)-th rarest
-        term; the round's (term, block) needs across the WHOLE batch are
-        deduped and decoded in one arena call, so each hot block decodes at
-        most once per batch and the Python-loop count drops from O(total
-        selected blocks) to O(rounds).  Results are bit-identical to
-        ``and_query`` per query.
-
-        ``terms`` is the plan's resolved per-term capability map; when absent
-        (direct calls) capabilities are probed on the fly.
-        """
-        def term_fused(t, sel):
-            return (terms[t].fused if terms is not None
-                    else self._term_fused(t, sel))
-
-        gen = self._cur().gen
-        qterms = [sorted((t for t in q if t in gen.terms),
-                         key=lambda t: gen.terms[t].df) for q in queries]
-        for ts in qterms:               # raw seed-term block references,
-            if ts:                      # pre-dedup (work-list metric)
-                self.metrics.inc("worklist_refs", gen.n_blocks(ts[0]))
-        if self.arena is not None:
-            self._prefetch_terms({ts[0] for ts in qterms if ts}, fields=(0,))
-        cands = [self.term_ids(ts[0]) if ts else _EMPTY_U32 for ts in qterms]
-        owned = [False] * len(queries)
-        r = 1
-        while True:
-            active = [i for i, ts in enumerate(qterms)
-                      if len(ts) > r and len(cands[i])]
-            if not active:
-                break
-            plans, worklist = {}, []
-            for i in active:
-                t = qterms[i][r]
-                cut, sel = self._block_plan(t, cands[i])
-                fused = term_fused(t, sel)
-                plans[i] = (t, cut, sel, fused)
-                self.metrics.inc("worklist_refs", len(sel))
-                if self.arena is not None and not fused:
-                    worklist.extend((t, int(bi), 0) for bi in sel)
-            if self.arena is not None:
-                self._prefetch_blocks(worklist)
-            for i in active:
-                t, cut, sel, fused = plans[i]
-                cands[i] = self._intersect_plan(t, cut, sel, cands[i], fused)
-                owned[i] = True
-            if self.arena is not None:
-                # every active query's surviving candidates just landed on
-                # the host for the next round's block plan
-                self.metrics.inc("cand_syncs", len(active))
-            r += 1
-        return [c if o else c.copy() for c, o in zip(cands, owned)]
+        return np.concatenate([
+            intersect.intersect_sorted(self.decode_block_ids(t, int(bi)),
+                                       cand[cut[bi]:cut[bi + 1]])
+            for bi in sel])
 
     # ---- device-resident AND rounds ---------------------------------------- #
 
@@ -1020,10 +810,10 @@ class QueryEngine:
                 qterms.append(sorted(known, key=lambda t: idx.terms[t].df))
         return qterms
 
-    def _and_many_resident(self, queries: list,
-                           terms: Mapping[int, TermCaps] | None = None,
-                           use_fused: bool = False,
-                           qterms: list | None = None) -> list:
+    def _and_resident(self, queries: list,
+                      terms: Mapping[int, TermCaps] | None = None,
+                      use_fused: bool = False,
+                      qterms: list | None = None) -> list:
         """AND the batch device-resident; the single host copy turns the
         final bitmaps into sorted docid arrays (``_and_bitmap_resident``
         keeps everything before that copy on device — the ``and_scored``
@@ -1284,62 +1074,21 @@ class QueryEngine:
             scores += np.where(hit, sc[pos], 0.0)
         return topk_select(docs, scores, k)
 
-    def _score_docs_blockwise(self, terms: list, docs: np.ndarray,
-                              k: int) -> list:
-        """Exact float rescore touching only the blocks that hold ``docs``
-        (the ranked device path's final stage: candidates are few, so whole
-        -term decodes would waste the pruning win).  Bitwise identical to
-        :meth:`_score_docs` — same float formula (``bm25_scores``), same
-        per-doc term accumulation order, same tie rule.  Generation-only
-        (the mutated ranked path rescores with :meth:`_score_docs`, whose
-        score vectors carry the live stats)."""
-        if len(docs) == 0:
-            return []
-        ctx = self._cur()
-        idx = ctx.gen
-        scores = np.zeros(len(docs))
-        plans = []
-        prefetch = []
-        for t in terms:
-            if t not in idx.terms or not idx.terms[t].blocks:
-                continue            # unknown or zero-posting term scores 0
-            firsts = idx.block_firsts(t)
-            bi = np.searchsorted(firsts, docs, side="right") - 1
-            bi = np.where(idx.block_lasts(t)[np.maximum(bi, 0)] >=
-                          docs.astype(np.int64), bi, -1)
-            plans.append((t, bi))
-            if self.arena is not None:
-                prefetch.extend((t, int(b), f)
-                                for b in np.unique(bi[bi >= 0]) for f in (0, 1))
-        if prefetch:
-            self._prefetch_blocks(prefetch)
-        for t, bi in plans:
-            df = idx.terms[t].df
-            for b in np.unique(bi[bi >= 0]):
-                sel = np.flatnonzero(bi == b)
-                ids, tfs = self.decode_block(t, int(b))
-                pos = np.searchsorted(ids, docs[sel])
-                pos = np.clip(pos, 0, len(ids) - 1)
-                hit = ids[pos] == docs[sel]
-                sub = sel[hit]
-                sc = bm25_scores(tfs[pos[hit]], ctx.doclen[docs[sub]], df,
-                                 ctx.n_docs, ctx.avdl)
-                scores[sub] += sc
-        return topk_select(docs, scores, k)
-
     def _rescore_batch_blockwise(self, queries: list, cand: list,
                                  k: int) -> list:
-        """Batch form of :meth:`_score_docs_blockwise`: the per-(term, block)
-        decode + score work is amortized over the WHOLE batch — each term
-        scores the union of its queries' candidates once, then every query
-        accumulates its own docs in query-term order from the shared
-        per-term vectors.  Bitwise identical to mapping
-        :meth:`_score_docs_blockwise` over the batch: same elementwise
-        ``bm25_scores`` values, same per-doc term accumulation order, and a
-        candidate a term doesn't hold adds +0.0 exactly as the host oracle's
-        ``np.where`` does (contributions are strictly positive, so no -0.0
-        can ever sit in an accumulator).  Generation-only, like the
-        per-query form."""
+        """Exact float rescore of a ranked batch's candidates, touching only
+        the blocks that hold them (candidates are few, so whole-term decodes
+        would waste the pruning win): the per-(term, block) decode + score
+        work is amortized over the WHOLE batch — each term scores the union
+        of its queries' candidates once, then every query accumulates its
+        own docs in query-term order from the shared per-term vectors.
+        Bitwise identical to mapping :meth:`_score_docs` over the batch:
+        same elementwise ``bm25_scores`` values, same per-doc term
+        accumulation order, and a candidate a term doesn't hold adds +0.0
+        exactly as the host oracle's ``np.where`` does (contributions are
+        strictly positive, so no -0.0 can ever sit in an accumulator).
+        Generation-only (the mutated ranked path rescores with
+        :meth:`_score_docs`, whose score vectors carry the live stats)."""
         union = {}
         for q, c in zip(queries, cand):
             if len(c) == 0:
@@ -1863,8 +1612,7 @@ class QueryEngine:
         for lo, hi, eng, sctx in parts:
             sub_q = [self._shard_qterms(ts, eng.idx) for ts in qterms]
             with self._pinned(eng, sctx):
-                ids = eng._and_many_resident(queries, None, fused,
-                                             qterms=sub_q)
+                ids = eng._and_resident(queries, None, fused, qterms=sub_q)
             self.metrics.inc("shard_final_syncs")
             for i, a in enumerate(ids):
                 if len(a):
@@ -1989,13 +1737,13 @@ class QueryEngine:
         codec registry's declarations.  ``execute(plan)`` then runs with no
         per-codec or per-flag branching.
 
-        Auto-placement (``placement=None``) demotes small batches to the
-        host using the measured :class:`CrossoverTable` from the committed
-        ``BENCH_query.json`` when one exists, else the static
-        ``HOST_BATCH_MAX`` rule; ``plan.note`` records which source decided.
-        An explicit ``placement`` skips the demotion entirely (the serving
-        path and benchmarks use this to pin a placement per run) and is
-        validated against the engine's arena state up front.
+        Auto-placement (``placement=None``) goes by the batch's size alone:
+        at most ``HOST_BATCH_MAX`` queries run on the host (``plan.note``
+        says so), larger batches on the engine's arenas — ``fused`` when it
+        has tiles, else ``device``.  An explicit ``placement`` skips the
+        demotion entirely (the serving path and benchmarks use this to pin a
+        placement per run) and is validated against the engine's arena state
+        up front.
 
         The plan also pins the current mutation epoch (:class:`_ExecCtx`):
         its generation, a frozen delta snapshot, and the tombstone set.
@@ -2021,26 +1769,11 @@ class QueryEngine:
         else:
             placement = ("fused" if resident and self._fused
                          else "device" if resident else "host")
-            if placement != "host":
-                n = len(batch.queries)
-                xo = get_crossover()
-                cut = xo.cut_for(batch.mode) if xo is not None else None
-                if cut is not None:
-                    if n <= cut:
-                        note = (f"auto-placed host: batch={n} <= "
-                                f"host_batch_max={cut} for "
-                                f"mode={batch.mode!r} "
-                                f"(measured crossover, {xo.source}, "
-                                f"sizes={list(xo.sizes)})")
-                        placement = "host"
-                elif n <= HOST_BATCH_MAX:
-                    reason = ("no BENCH_query.json baseline" if xo is None
-                              else f"{xo.source}: no host->device crossover "
-                                   f"measured for mode={batch.mode!r}")
-                    note = (f"auto-placed host: batch={n} <= "
-                            f"HOST_BATCH_MAX={HOST_BATCH_MAX} "
-                            f"(static rule; {reason})")
-                    placement = "host"
+            n = len(batch.queries)
+            if placement != "host" and n <= HOST_BATCH_MAX:
+                note = (f"auto-placed host: batch={n} <= "
+                        f"HOST_BATCH_MAX={HOST_BATCH_MAX}")
+                placement = "host"
         if self._shard_cfg is not None and placement != "host":
             spec, _, mesh = self._shard_engines(ctx)
             snote = (f"sharded x{spec.n_shards} bounds={list(spec.bounds)} "
@@ -2076,20 +1809,21 @@ class QueryEngine:
                              queries=tuple(tuple(q) for q in batch.queries),
                              terms=terms, note=note, ctx=ctx)
 
-    def execute(self, work) -> list:
+    def execute(self, plan: ExecutionPlan) -> list:
         """Run an :class:`ExecutionPlan` (span ``engine/execute``); see
         :meth:`_execute_impl` for the full contract."""
-        if isinstance(work, QueryBatch):
-            work = self.plan(work)
+        if not isinstance(plan, ExecutionPlan):
+            raise TypeError(
+                f"execute() takes an ExecutionPlan, not "
+                f"{type(plan).__name__}; pass engine.plan(batch)")
         with self.tracer.span("engine/execute", lane=self.trace_lane,
-                              mode=work.mode, placement=work.placement,
-                              nq=len(work.queries)):
-            return self._execute_impl(work)
+                              mode=plan.mode, placement=plan.placement,
+                              nq=len(plan.queries)):
+            return self._execute_impl(plan)
 
-    def _execute_impl(self, work) -> list:
+    def _execute_impl(self, plan: ExecutionPlan) -> list:
         """Run an :class:`ExecutionPlan`; results align with the planned
-        queries.  Passing a ``QueryBatch`` is a deprecated shim that plans
-        implicitly (bit-identical results).
+        queries.
 
         Execution happens under the plan's pinned ctx: the generation, delta
         snapshot, and tombstone set resolved at plan time — so a
@@ -2099,13 +1833,10 @@ class QueryEngine:
         On the host placement queries are processed grouped by sorted term
         signature so queries sharing terms hit the decoded-block/score caches
         back to back.  On the device/fused placements AND semantics run
-        round-batched through ``_and_many_resident`` — one deduped arena
+        round-batched through ``_and_resident`` — one deduped arena
         decode per round across the whole batch — and OR/scored modes run the
         resident ranked accumulator.
         """
-        if isinstance(work, QueryBatch):
-            work = self.plan(work)
-        plan: ExecutionPlan = work
         _check_mode(plan.mode)
         ctx: _ExecCtx = plan.ctx if plan.ctx is not None else self._cur()
         if plan.placement != "host":
@@ -2139,27 +1870,25 @@ class QueryEngine:
         order = sorted(range(len(plan.queries)),
                        key=lambda i: tuple(sorted(plan.queries[i])))
         results = [None] * len(plan.queries)
-        # a host plan stays pinned to host intersection AND host block
-        # decodes even on an engine that has arenas — placement is the
-        # plan's contract, not a hint (and per-block arena calls would be
-        # strictly slower than the numpy oracle for the tiny batches the
-        # auto-placement sends here); the bits are identical either way.
+        # a host plan stays pinned to host block decodes even on an engine
+        # that has arenas — placement is the plan's contract, not a hint
+        # (and per-block arena calls would be strictly slower than the numpy
+        # oracle for the tiny batches the auto-placement sends here); the
+        # bits are identical either way.
         prev_ctx, self._ctx = self._ctx, ctx
-        prev_fused, self._fused = self._fused, False
         prev_arena, self.arena = self.arena, None
         try:
             for i in order:
                 results[i] = fn(list(plan.queries[i]))
         finally:
-            self._ctx = prev_ctx
-            self._fused, self.arena = prev_fused, prev_arena
+            self._ctx, self.arena = prev_ctx, prev_arena
         return results
 
     def _execute_device(self, plan: ExecutionPlan, ctx: _ExecCtx) -> list:
         queries = [list(q) for q in plan.queries]
         fused = plan.placement == "fused"
         if plan.mode == "and":
-            base = self._and_many_resident(queries, plan.terms, fused)
+            base = self._and_resident(queries, plan.terms, fused)
             if not ctx.mutated:
                 return base
             out = []

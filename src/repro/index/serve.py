@@ -336,7 +336,8 @@ class ServeConfig:
         absent from the map weigh 1.0.  Empty map = no per-tenant split
         (only the global bound applies).
     placement: force every batch's plan placement ("host" / "device" /
-        "fused"); None lets ``engine.plan()`` auto-place (crossover table).
+        "fused"); None lets ``engine.plan()`` auto-place by batch size
+        (``HOST_BATCH_MAX``).
     warm_terms: warm this many hottest (highest-df) terms' block + score
         caches at ``start()``.
     warm_modes: prime the jit caches by executing one priming batch per

@@ -19,9 +19,9 @@ entry
 
 The candidate probe is NOT in the kernel: a per-lane lookup into a bitmap of
 ``crows * 128`` words is a general gather, which the TPU kernel compiler
-refuses ("Only 2D gather is supported").  It runs in XLA on the decoded
-rows — :func:`fused_decode_and` here, ``intersect_rounds.round_accumulate``
-and ``topk.score_round`` on the resident rounds.
+refuses ("Only 2D gather is supported").  The resident rounds run it in XLA
+on the decoded rows (``intersect_rounds.round_accumulate`` and
+``topk.score_round``).
 
 Layout: a block of up to 512 postings is one (rows_per_block, 128) uint32
 tile.  Value ``i`` of the block lives at row ``i // 128``, lane ``i % 128``
@@ -136,23 +136,3 @@ def decode_tiles(tiles: jnp.ndarray, slots: jnp.ndarray, firsts: jnp.ndarray,
         interpret=auto_interpret(interpret),
     )(slots, firsts, tiles)
     return ids.reshape(w, BLOCK)
-
-
-@functools.partial(jax.jit, static_argnames=("bw", "interpret"))
-def fused_decode_and(tiles: jnp.ndarray, slots: jnp.ndarray,
-                     firsts: jnp.ndarray, ns: jnp.ndarray,
-                     cand: jnp.ndarray, bw: int,
-                     interpret=None) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Decode + intersect a work-list of packed block tiles in one jitted
-    call: :func:`decode_tiles`, then every docid probes the packed
-    candidate bitmap ``cand`` ((words,) uint32 over [0, words * 32)).
-
-    ns: (W,) int32 posting count per entry.  Returns (docids, hits), each
-    (W, 512) uint32; entry j's intersection is ``docids[j][hits[j] == 1]``
-    in linear order.
-    """
-    ids = decode_tiles(tiles, slots, firsts, bw=bw, interpret=interpret)
-    word = cand[jnp.minimum(ids >> 5, cand.shape[0] - 1).astype(jnp.int32)]
-    hit = (word >> (ids & 31)) & jnp.uint32(1)
-    valid = jnp.arange(BLOCK, dtype=jnp.int32)[None, :] < ns[:, None]
-    return ids, jnp.where(valid, hit, jnp.uint32(0))

@@ -87,20 +87,6 @@ def test_decode_worklist_compiles(one_chip, lanes):
         decode=lay.decode_block, widths=widths)
 
 
-@pytest.mark.parametrize("bw", decode_fused.BW_BUCKETS)
-def test_fused_decode_and_compiles(one_chip, bw):
-    words, _ = intersect_rounds.bitmap_geometry(SHARD_DOCS)
-    rpb = decode_fused.rows_per_block(bw)
-    hlo = _compiled_text(
-        decode_fused.fused_decode_and,
-        _sds(one_chip, (ARENA_SLOTS, rpb, 128), jnp.uint32),
-        _sds(one_chip, (WORKLIST,), jnp.int32),
-        _sds(one_chip, (WORKLIST,), jnp.uint32),
-        _sds(one_chip, (WORKLIST,), jnp.int32),
-        _sds(one_chip, (words,), jnp.uint32), bw=bw, interpret=False)
-    assert "tpu_custom_call" in hlo
-
-
 def test_unpack_codes_compiles(one_chip):
     hlo = _compiled_text(
         topk.unpack_codes,

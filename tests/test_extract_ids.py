@@ -108,7 +108,8 @@ def dense_index():
 def test_dense_rows_match_host_oracle(dense_index, placement, mode):
     queries = [[0], [1], [0, 2], [4], [3, 1], [0, 4]]
     batch = QueryBatch(queries, mode=mode, k=7)
-    want = QueryEngine(dense_index).execute(batch)
+    ref = QueryEngine(dense_index)
+    want = ref.execute(ref.plan(batch))
     eng = QueryEngine(dense_index).to_device(fused=placement == "fused")
     with eng.metrics.scoped() as s:
         got = eng.execute(eng.plan(batch, placement=placement))

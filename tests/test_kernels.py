@@ -122,37 +122,6 @@ def test_decode_tiles_matches_numpy(bw):
         np.testing.assert_array_equal(got[j, len(g):], want[-1])
 
 
-@pytest.mark.parametrize("n_docs", [25_000, 77_824])   # 896 / 2432 words
-def test_fused_decode_and_matches_numpy(n_docs):
-    """Probe against a bitmap whose width is not a multiple of 2048 words."""
-    words, _ = intersect_rounds.bitmap_geometry(n_docs)
-    assert words % 2048
-    bw = 16
-    blocks, firsts = [], []
-    for n in (512, 200, 1):
-        ids = np.sort(RNG.choice(n_docs, n, replace=False)).astype(np.uint32)
-        g = np.zeros(n, np.uint32)
-        g[1:] = np.diff(ids)
-        blocks.append((ids, g))
-        firsts.append(ids[0])
-    tiles = jnp.asarray(np.stack([decode_fused.pack_gaps(g, bw)
-                                  for _, g in blocks]))
-    cand = np.sort(RNG.choice(n_docs, n_docs // 3, replace=False))
-    bits = np.zeros(words * 32, np.uint8)
-    bits[cand] = 1
-    cand_words = np.packbits(bits, bitorder="little").view(np.uint32)
-    ids, hits = decode_fused.fused_decode_and(
-        tiles, jnp.arange(len(blocks), dtype=jnp.int32),
-        jnp.asarray(np.asarray(firsts, np.uint32)),
-        jnp.asarray(np.asarray([len(i) for i, _ in blocks], np.int32)),
-        jnp.asarray(cand_words), bw=bw, interpret=True)
-    ids, hits = np.asarray(ids), np.asarray(hits).astype(bool)
-    for j, (want_ids, _) in enumerate(blocks):
-        np.testing.assert_array_equal(ids[j, :len(want_ids)], want_ids)
-        np.testing.assert_array_equal(ids[j][hits[j]],
-                                      want_ids[np.isin(want_ids, cand)])
-
-
 def _disjoint_entries(n_docs: int, q: int, p: int):
     """p work-list entries over q queries whose docids never repeat (the
     exactness contract of the accumulate step)."""

@@ -112,9 +112,9 @@ class MutationModel:
         ora = self.oracle()
         for mode in MODES:
             batch = QueryBatch(queries, mode=mode, k=K)
-            want = ora.execute(batch)
+            want = ora.execute(ora.plan(batch))
             for name, eng in self.engines:
-                got = eng.execute(QueryBatch(queries, mode=mode, k=K))
+                got = eng.execute(eng.plan(batch))
                 for q, w, g in zip(queries, want, got):
                     where = f"{name}/{mode}/{q} @step {self.steps}"
                     if mode == "and":
@@ -131,7 +131,6 @@ class MutationModel:
         for name, eng in self.engines:
             if name == "host":
                 continue
-            assert eng.dev_stats["cand_syncs"] == 0, name
             assert eng.dev_stats["score_syncs"] == 0, name
             assert eng.dev_stats["final_syncs"] > 0, name
             assert eng.dev_stats["tomb_gates"] > 0, name
@@ -274,7 +273,8 @@ def test_plan_pins_generation_across_compact(fused):
     # a fresh plan sees the new generation and the post-compact truth
     fresh = eng.plan(QueryBatch(queries, mode="and"))
     assert fresh.ctx.gen.gid == old_gid + 1
-    want = QueryEngine(idx).execute(QueryBatch(queries, mode="and"))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="and")))
     for w, g in zip(want, eng.execute(fresh)):
         np.testing.assert_array_equal(g, w)
 
@@ -324,8 +324,8 @@ def test_tombstone_only_ranked_superset_contract(fused):
             postings[t] = (ids[keep], tfs[keep])
     ora = QueryEngine(InvertedIndex.build(doclen, postings, codec="group_pfd"))
     for mode in ("or", "and_scored"):
-        want = ora.execute(QueryBatch(queries, mode=mode, k=K))
-        got = eng.execute(QueryBatch(queries, mode=mode, k=K))
+        want = ora.execute(ora.plan(QueryBatch(queries, mode=mode, k=K)))
+        got = eng.execute(eng.plan(QueryBatch(queries, mode=mode, k=K)))
         assert got == want, mode
         for res in got:
             assert not any(d in deadset for d, _ in res)
@@ -347,8 +347,8 @@ def test_caches_keyed_by_generation_not_stale_after_compact():
     rng, idx = _pin_fixture()
     eng = QueryEngine(idx)
     queries = [[0, 1], [0], [1, 2]]
-    eng.execute(QueryBatch(queries, mode="and"))        # warm block cache
-    eng.execute(QueryBatch(queries, mode="or", k=K))    # warm score cache
+    eng.execute(eng.plan(QueryBatch(queries, mode="and")))      # block cache
+    eng.execute(eng.plan(QueryBatch(queries, mode="or", k=K)))  # score cache
     gid0 = idx.gen.gid
     keys0 = set(eng.cache.keys())
     assert keys0 and all(k[-1] == gid0 for k in keys0)
@@ -359,13 +359,14 @@ def test_caches_keyed_by_generation_not_stale_after_compact():
         idx.delete(int(d))
     idx.insert(500, {0: 3, 1: 1}, 40)
     idx.compact()
-    want = QueryEngine(idx).execute(QueryBatch(queries, mode="and"))
-    got = eng.execute(QueryBatch(queries, mode="and"))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="and")))
+    got = eng.execute(eng.plan(QueryBatch(queries, mode="and")))
     for w, g in zip(want, got):
         np.testing.assert_array_equal(g, w)     # stale gen-0 blocks would differ
     assert any(k[-1] == gid0 + 1 for k in eng.cache.keys())
-    want = QueryEngine(idx).execute(QueryBatch(queries, mode="or", k=K))
-    assert eng.execute(QueryBatch(queries, mode="or", k=K)) == want
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="or", k=K)))
+    assert eng.execute(eng.plan(QueryBatch(queries, mode="or", k=K))) == want
     # score-cache entries carry the full epoch key (term, gid, tomb_v, delta_v)
     assert any(k[1] == gid0 + 1 for k in eng.score_cache.keys())
 

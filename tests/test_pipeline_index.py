@@ -6,8 +6,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.data import synth
 from repro.data.pipeline import AdjacencyStore, BagStore, TokenStore, lm_batch_iter
+from repro.index.engine import QueryBatch, QueryEngine
 from repro.index.invindex import InvertedIndex
-from repro.index import query as Q
 from repro.models.sampler import CSRGraph
 
 
@@ -51,10 +51,11 @@ def test_index_and_query_vs_bruteforce():
     doclen, postings = synth.make_corpus("wikipedia")
     idx = InvertedIndex.build(doclen, postings, codec="group_simple")
     t1, t2 = sorted(postings)[:2]
-    got = Q.and_query(idx, [t1, t2])
+    eng = QueryEngine(idx)
+    got, = eng.execute(eng.plan(QueryBatch([[t1, t2]], mode="and")))
     want = np.intersect1d(postings[t1][0], postings[t2][0])
     np.testing.assert_array_equal(np.sort(got), want)
-    top = Q.or_query(idx, [t1, t2], k=5)
+    top, = eng.execute(eng.plan(QueryBatch([[t1, t2]], mode="or", k=5)))
     assert len(top) == 5
     assert top[0][1] >= top[-1][1]
 

@@ -10,7 +10,6 @@ import pytest
 from repro.core import codec
 from repro.index.invindex import SHORT, SHORT_CODEC, InvertedIndex
 from repro.index.engine import B, K1, BlockCache, QueryBatch, QueryEngine
-from repro.index import query as Q
 from repro.kernels import intersect
 
 RNG = np.random.default_rng(11)
@@ -61,12 +60,12 @@ def brute_or_topk(doclen, postings, n_docs, terms, k):
 def test_batched_and_or_match_bruteforce(name):
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec=name)
     eng = QueryEngine(idx)
-    got = eng.execute(QueryBatch(QUERIES, mode="and"))
+    got = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
     for q, res in zip(QUERIES, got):
         np.testing.assert_array_equal(res, brute_and(POSTINGS, q),
                                       err_msg=f"{name}/{q}")
         assert res.dtype == np.uint32
-    top = eng.execute(QueryBatch(QUERIES[:6], mode="or", k=8))
+    top = eng.execute(eng.plan(QueryBatch(QUERIES[:6], mode="or", k=8)))
     for q, res in zip(QUERIES[:6], top):
         want = brute_or_topk(DOCLEN, POSTINGS, N_DOCS, q, 8)
         assert len(res) == len(want)
@@ -97,15 +96,20 @@ def test_short_lists_use_stream_vbyte():
 
 
 def test_one_shot_helpers_match_seed_reference():
+    """Single-query plans on an uncached engine match brute force."""
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_pfd")
+
+    def one(q, mode, k=10):
+        eng = QueryEngine(idx, cache_blocks=0, cache_score_terms=0)
+        return eng.execute(eng.plan(QueryBatch([q], mode=mode, k=k)))[0]
+
     for q in QUERIES:
-        np.testing.assert_array_equal(Q.and_query(idx, q), Q.and_query_ref(idx, q))
-        scored = Q.and_query_scored(idx, q, k=5)
-        docs = Q.and_query(idx, q)
-        assert len(scored) == min(5, len(docs))
+        docs = one(q, "and")
+        np.testing.assert_array_equal(docs, brute_and(POSTINGS, q))
+        assert len(one(q, "and_scored", k=5)) == min(5, len(docs))
     # unknown terms are ignored, all-unknown -> empty
-    assert len(Q.and_query(idx, [999])) == 0
-    assert Q.or_query(idx, [999]) == []
+    assert len(one([999], "and")) == 0
+    assert one([999], "or") == []
 
 
 def test_block_cache_hit_and_eviction_paths():
@@ -127,15 +131,15 @@ def test_block_cache_hit_and_eviction_paths():
 def test_engine_cache_reuse_and_eviction_correctness():
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
     eng = QueryEngine(idx)
-    r1 = eng.execute(QueryBatch(QUERIES, mode="and"))
+    r1 = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
     h1 = eng.cache.hits
-    r2 = eng.execute(QueryBatch(QUERIES, mode="and"))
+    r2 = eng.execute(eng.plan(QueryBatch(QUERIES, mode="and")))
     assert eng.cache.hits > h1              # second pass served from cache
     for a, b in zip(r1, r2):
         np.testing.assert_array_equal(a, b)
     # a pathologically small cache must evict constantly yet stay exact
     tiny = QueryEngine(idx, cache_blocks=2, cache_score_terms=1)
-    r3 = tiny.execute(QueryBatch(QUERIES, mode="and"))
+    r3 = tiny.execute(tiny.plan(QueryBatch(QUERIES, mode="and")))
     assert tiny.cache.evictions > 0
     for a, b in zip(r1, r3):
         np.testing.assert_array_equal(a, b)
@@ -149,8 +153,8 @@ def test_score_cache_eviction_and_recompute_on_miss():
     ref = QueryEngine(idx)
     eng = QueryEngine(idx, cache_score_terms=1)
     queries = [[5, 9, 7], [9, 5], [7, 9, 5]] * 3
-    want = ref.execute(QueryBatch(queries, mode="or", k=8))
-    got = eng.execute(QueryBatch(queries, mode="or", k=8))
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="or", k=8)))
+    got = eng.execute(eng.plan(QueryBatch(queries, mode="or", k=8)))
     assert want == got
     assert eng.score_cache.evictions > 0
     assert eng.score_cache.cost_used <= eng.score_cache.capacity
@@ -203,7 +207,7 @@ def test_batch_results_align_with_input_order():
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
     eng = QueryEngine(idx)
     queries = [[9, 8], [0, 1], [9, 8], [5, 6, 7], [0, 1]]
-    got = eng.execute(QueryBatch(queries, mode="and"))
+    got = eng.execute(eng.plan(QueryBatch(queries, mode="and")))
     for q, res in zip(queries, got):
         np.testing.assert_array_equal(res, brute_and(POSTINGS, q))
 

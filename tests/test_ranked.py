@@ -84,12 +84,13 @@ def test_ranked_placement_parity_and_float_oracle(name):
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec=name)
     host = QueryEngine(idx)
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(QUERIES, mode=mode, k=7))
+        want = host.execute(host.plan(QueryBatch(QUERIES, mode=mode, k=7)))
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(QUERIES, mode=mode, k=7)))
             assert want == got, (name, mode, fused)
-    for q, res in zip(QUERIES, host.execute(QueryBatch(QUERIES, mode="or", k=7))):
+    top = host.execute(host.plan(QueryBatch(QUERIES, mode="or", k=7)))
+    for q, res in zip(QUERIES, top):
         oracle = brute_or_topk(DOCLEN, POSTINGS, N_DOCS, q, 7)
         assert [(d, pytest.approx(s, rel=1e-12)) for d, s in oracle] == res, q
 
@@ -105,7 +106,7 @@ def test_ranked_heavy_tail_exception_corpus(name):
                    for _, encg, _ in tp.blocks), "corpus exercises no exceptions"
     host = QueryEngine(idx)
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(QUERIES, mode=mode, k=9))
+        want = host.execute(host.plan(QueryBatch(QUERIES, mode=mode, k=9)))
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(QUERIES, mode=mode, k=9)))
@@ -119,7 +120,7 @@ def test_ranked_quantization_ties_docid_tiebreak():
     idx = InvertedIndex.build(TDOCLEN, TPOSTINGS, codec="group_simple")
     host = QueryEngine(idx)
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(QUERIES, mode=mode, k=11))
+        want = host.execute(host.plan(QueryBatch(QUERIES, mode=mode, k=11)))
         eng = QueryEngine(idx).to_device()
         got = eng.execute(eng.plan(QueryBatch(QUERIES, mode=mode, k=11)))
         assert want == got, mode
@@ -137,7 +138,6 @@ def test_ranked_device_path_zero_per_round_syncs():
             eng.execute(eng.plan(QueryBatch(queries, mode=mode, k=5)))
             assert eng.dev_stats["score_rounds"] >= 2
             assert eng.dev_stats["score_syncs"] == 0
-            assert eng.dev_stats["cand_syncs"] == 0
             assert eng.dev_stats["final_syncs"] == final, (mode, fused)
             assert eng.dev_stats["blocks_scored"] > 0
             if fused:
@@ -150,7 +150,8 @@ def test_or_blockmax_pruning_fires_and_stays_exact():
     exact."""
     queries = [[10, 7], [10, 3], [10, 7, 5]] * 4
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
-    want = QueryEngine(idx).execute(QueryBatch(queries, mode="or", k=5))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="or", k=5)))
     eng = QueryEngine(idx).to_device()
     got = eng.execute(eng.plan(QueryBatch(queries, mode="or", k=5)))
     assert want == got
@@ -168,12 +169,12 @@ def test_zero_posting_term_in_ranked_queries_on_device():
     host = QueryEngine(idx)
     queries = [[99, 3, 7], [99], [3, 99, 5]]
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(queries, mode=mode, k=5))
+        want = host.execute(host.plan(QueryBatch(queries, mode=mode, k=5)))
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(queries, mode=mode, k=5)))
             assert want == got, (mode, fused)
-    assert host.execute(QueryBatch([[99]], mode="or", k=5)) == [[]]
+    assert host.execute(host.plan(QueryBatch([[99]], mode="or", k=5))) == [[]]
 
 
 def test_ranked_eviction_pressure_stays_exact():
@@ -181,7 +182,7 @@ def test_ranked_eviction_pressure_stays_exact():
     host = QueryEngine(idx)
     tiny = QueryEngine(idx, cache_blocks=2, cache_score_terms=1).to_device()
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(QUERIES, mode=mode, k=6))
+        want = host.execute(host.plan(QueryBatch(QUERIES, mode=mode, k=6)))
         got = tiny.execute(tiny.plan(QueryBatch(QUERIES, mode=mode, k=6)))
         assert want == got, mode
     assert tiny.cache.evictions > 0
@@ -346,7 +347,7 @@ def test_dense_bitmap_corpus_ranked_parity(name):
                for _, encg, _ in tp.blocks), "corpus stores no dense blocks"
     host = QueryEngine(idx)
     for mode in ("or", "and_scored"):
-        want = host.execute(QueryBatch(DENSE_QUERIES, mode=mode, k=7))
+        want = host.execute(host.plan(QueryBatch(DENSE_QUERIES, mode=mode, k=7)))
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(DENSE_QUERIES, mode=mode,
@@ -355,7 +356,7 @@ def test_dense_bitmap_corpus_ranked_parity(name):
             assert eng.dev_stats["blocks_dense"] > 0, (name, mode, fused)
         oracle_q = [q for q in DENSE_QUERIES]
         for q, res in zip(oracle_q, host.execute(
-                QueryBatch(oracle_q, mode="or", k=7))):
+                host.plan(QueryBatch(oracle_q, mode="or", k=7)))):
             oracle = brute_or_topk(doclen, postings, len(doclen), q, 7)
             assert [(d, pytest.approx(s, rel=1e-12)) for d, s in oracle] == res
 
@@ -366,7 +367,8 @@ def test_adaptive_theta_corpus_parity_and_pruning():
     while the static prune still drops blocks."""
     queries = [[10, 7, 5], [10, 3, 8], [10, 7], [10, 1, 4, 6]] * 4
     idx = InvertedIndex.build(DOCLEN, POSTINGS, codec="group_simple")
-    want = QueryEngine(idx).execute(QueryBatch(queries, mode="or", k=10))
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(QueryBatch(queries, mode="or", k=10)))
     for fused in (False, True):
         eng = QueryEngine(idx).to_device(fused=fused)
         got = eng.execute(eng.plan(QueryBatch(queries, mode="or", k=10)))
@@ -394,10 +396,10 @@ def test_tombstone_only_epoch_keeps_pruning_armed_and_exact():
     rebuilt = InvertedIndex.build(DOCLEN, live, codec="group_simple")
     queries = [[10, 7], [10, 3], [10, 7, 5], [0, 7], [3, 5, 8]] * 3
     for mode in ("or", "and_scored"):
-        want = QueryEngine(rebuilt).execute(QueryBatch(queries, mode=mode,
-                                                       k=6))
-        assert QueryEngine(idx).execute(QueryBatch(queries, mode=mode,
-                                                   k=6)) == want, mode
+        batch = QueryBatch(queries, mode=mode, k=6)
+        ref, host = QueryEngine(rebuilt), QueryEngine(idx)
+        want = ref.execute(ref.plan(batch))
+        assert host.execute(host.plan(batch)) == want, mode
         for fused in (False, True):
             eng = QueryEngine(idx).to_device(fused=fused)
             got = eng.execute(eng.plan(QueryBatch(queries, mode=mode, k=6)))

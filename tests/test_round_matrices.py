@@ -128,7 +128,8 @@ def test_round_matrices_match_host_oracle(indexes, case):
     codec, placement, mode, queries, want_deltas, n_sources = CASES[case]
     idx = _mutated() if codec == "mutated" else indexes[codec]
     batch = QueryBatch(queries, mode=mode, k=7)
-    want = QueryEngine(idx).execute(batch)
+    ref = QueryEngine(idx)
+    want = ref.execute(ref.plan(batch))
     if placement == "sharded":
         eng = QueryEngine(idx).to_device(shards=2)
         plan = eng.plan(batch, placement="device")

